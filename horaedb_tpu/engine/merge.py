@@ -386,7 +386,7 @@ def merge_read(
     # merge iterator in the reference, row_iter/merge.rs:134-181 — here
     # it's one device sort instead of a BinaryHeap). Above the threshold
     # an adaptive per-row-rate router picks device vs host: merge inputs
-    # are NOT device-resident, so on a low-bandwidth (tunneled) backend
+    # are NOT device-resident, so on a low-bandwidth host-device link
     # the upload dominates and the host lexsort wins — measured, not
     # assumed (same policy as query path routing).
     tsid_idx = out_schema.tsid_index
@@ -402,8 +402,8 @@ def merge_read(
         else:
             route = _MERGE_ROUTER.choose(_MERGE_KEY)
             if route == "device" and not merge_dedup_ready(n):
-                # kernel still compiling in the background (minutes on a
-                # remote backend) — host path for now, sample unrecorded
+                # kernel still compiling in the background (minutes for a
+                # TPU) — host path for now, sample unrecorded
                 route = None
 
     import time as _time

@@ -22,12 +22,8 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax >= 0.4.31 re-exports it at top level
-    from jax import shard_map
-except ImportError:  # older jax: experimental module only
-    from jax.experimental.shard_map import shard_map
 
 from ..ops.encoding import PaddedBatch
 from ..ops.scan_agg import (

@@ -30,7 +30,7 @@ def dist_merge_dedup(
     desc), keep the newest row per (tsid, ts) key."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..ops.encoding import next_pow2, split_u64

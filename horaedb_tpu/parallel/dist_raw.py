@@ -26,12 +26,8 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax >= 0.4.31 re-exports it at top level
-    from jax import shard_map
-except ImportError:  # older jax: experimental module only
-    from jax.experimental.shard_map import shard_map
 
 from ..ops.scan_agg import encode_filter_ops
 from ..ops.scan_topk import _I32_MIN, RawScanSpec, raw_select_body, raw_topk_body
@@ -76,7 +72,7 @@ def make_dist_raw_topk(mesh: Mesh, spec: RawScanSpec) -> Callable:
                 out_specs=(P(SHARD_AXIS), P(SHARD_AXIS)),
                 # the bisection while_loop has no replication rule; every
                 # output is explicitly sharded, so the check adds nothing
-                check_rep=False,
+                check_vma=False,
             )
         )
 

@@ -2985,6 +2985,9 @@ def main() -> None:
             logging.FileHandler(args.log_file),
         ]
     logging.basicConfig(level=args.log_level.upper(), handlers=handlers)
+    from ..utils.compile_cache import enable_compile_cache
+
+    logger.info("jax compilation cache: %s", enable_compile_cache())
     cfg = Config.load(args.config)
     # CLI flags override config file + env.
     if args.data_dir is not None:

@@ -466,7 +466,7 @@ class InjectedFaultError(OSError):
 
 class FaultInjectingStore(ObjectStore):
     """Deterministic fault-injection wrapper over any store — the shared
-    chaos layer for bench (ingest A/B), chipbench, and the tenant-scale
+    chaos layer for bench (ingest A/B) and the tenant-scale
     production simulator (tools/tenantsim), promoted from bench.py's
     ad-hoc latency-injected SST store.
 

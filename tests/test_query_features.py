@@ -937,7 +937,7 @@ class TestAdaptivePathRouting:
         r.record(key, "device", 0.010)
         r.record(key, "host", 0.050)
         assert r.choose(key) == "device"
-        r.record(key, "device", 1.0)  # single GC pause / tunnel hiccup
+        r.record(key, "device", 1.0)  # single GC pause / dispatch hiccup
         assert r.choose(key) == "device"  # 10% creep, not a flip
 
     def test_adaptive_routing_serves_host_when_device_slow(self, db, monkeypatch):

@@ -1,0 +1,273 @@
+"""The served path's jitted entry points, compiled for a DESCRIBED TPU v5e
+at chip_smoke.py's real shapes (4000 hosts x 1 h: 1,440,000 rows padded to
+2^21, the layouts ScanCache builds for that data).
+
+Nothing runs and no chip is attached: the TPU compiler installed beside
+JAX refuses here what the chip would refuse (a program that does not fit
+HBM, a shape it cannot tile), so these guard every later PR at no chip
+time. A compile that passes is not a chip run.
+
+The topology is described inside the module-scoped fixture below — never
+at import, in a skipif, in parametrize or in conftest.py — so every xdist
+worker collects the same tests and only the worker that runs this file
+loads the TPU library. Keep all such tests in THIS file.
+"""
+
+import numpy as np
+import pytest
+
+N = 1 << 21  # padded rows of the 1.44M-row cache entry
+S = 4000  # series
+# the encoded streams ScanCache built for this data (CPU rehearsal, PR 23)
+SERIES_PARTS = (((65537,), "uint32"), ((16384,), "int32"))  # ("delta", 1)
+TS_PARTS = (((589825,), "uint32"), ((512,), "int32"))  # ("dict", 9)
+LAYOUTS = dict(ts_layout=("dict", 9), series_layout=("delta", 1))
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler here / library held elsewhere
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def cache_off():
+    """The persistent compilation cache is off around these compiles: an
+    entry written for a described device cannot be read back without a
+    chip."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def compile_for(topo, cache_off):
+    """-> compile(fn, arg_specs, **static) for one described chip; arg
+    specs are (shape, dtype) leaves."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def compile_(fn, arg_specs, **static):
+        args = jax.tree_util.tree_map(
+            lambda leaf: jax.ShapeDtypeStruct(
+                leaf[0], jnp.dtype(leaf[1]), sharding=one_chip
+            ),
+            arg_specs, is_leaf=_is_spec,
+        )
+        return fn.lower(*args, **static).compile()
+
+    return compile_
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo, cache_off):
+    """(mesh, spec(shape, dtype, *partition)) over the four described
+    chips, rows on the "shard" axis like parallel/mesh.py lays them."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(topo.devices), ("shard",))
+
+    def spec(shape, dtype, *partition):
+        return jax.ShapeDtypeStruct(
+            shape, jnp.dtype(dtype), sharding=NamedSharding(mesh, P(*partition))
+        )
+
+    return mesh, spec
+
+
+def _is_spec(x):
+    return isinstance(x, tuple) and len(x) == 2 and isinstance(x[1], str)
+
+
+def _packed_args(n_fields, n_filters=0, selective_rows=0):
+    return (
+        SERIES_PARTS, TS_PARTS, ((n_fields, N), "float32"),
+        ((2 * (S + 1),), "int32"),
+        ((n_filters + 4 + selective_rows,), "int32"),
+    )
+
+
+def _packed_static(n_groups, n_buckets, n_fields, need_minmax, impl,
+                   filters=(), selective=False):
+    return dict(
+        n_groups=n_groups, n_buckets=n_buckets, n_agg_fields=n_fields,
+        numeric_filters=filters, need_minmax=need_minmax, segment_impl=impl,
+        hash_slots=0, selective=selective,
+        value_layouts=(("raw",),) * n_fields, **LAYOUTS,
+    )
+
+
+# statement -> (args, static) of cached_scan_agg_packed with the impl the
+# static policy resolves to on a TPU
+PACKED = {
+    # S1 single-groupby-5-8-1: 8 hosts' 4096 gathered rows, 64 minutes
+    "S1-selective-mxu": (
+        _packed_args(5, selective_rows=4096),
+        _packed_static(1, 64, 5, True, "mxu", selective=True),
+    ),
+    # S2 double-groupby-all: 4000 hosts -> 4096 segments, avg only
+    "S2-4096seg-mxu": (
+        _packed_args(10), _packed_static(4096, 1, 10, False, "mxu"),
+    ),
+    # S3 high-cpu-all: one filter, global aggregate
+    "S3-single": (
+        _packed_args(1, n_filters=1),
+        _packed_static(1, 1, 1, True, "single", filters=((0, 4),)),
+    ),
+    # the shape the chip's compiler REFUSED before PR 23 chunked the MXU
+    # impl's min/max: pred[8192, 2^21] = 16 GB of HBM
+    "minmax-8192seg-mxu": (
+        _packed_args(5), _packed_static(8192, 1, 5, True, "mxu"),
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PACKED))
+def test_cached_packed_compiles(compile_for, case):
+    from horaedb_tpu.ops.scan_agg import cached_scan_agg_packed
+
+    args, static = PACKED[case]
+    mem = compile_for(cached_scan_agg_packed, args, **static).memory_analysis()
+    # HBM beyond the resident columns stays a small share of the 16 GB
+    assert mem.temp_size_in_bytes < (1 << 30), mem
+
+
+def test_raw_topk_compiles(compile_for):
+    """S4: ORDER BY ts DESC LIMIT 100 -> k=128 over the encoded streams."""
+    from horaedb_tpu.ops.scan_topk import raw_topk_packed
+
+    compile_for(
+        raw_topk_packed,
+        (SERIES_PARTS, TS_PARTS, ((0, N), "float32"),
+         ((S + 1,), "int32"), ((4,), "int32")),
+        k=128, descending=True, key_is_ts=True, key_field=0,
+        numeric_filters=(), value_layouts=(), **LAYOUTS,
+    )
+
+
+def test_livewindow_fold_and_gather_compile(compile_for):
+    from horaedb_tpu.ops.livewindow import _fold_body, _gather_body
+
+    depth, cap, rows = 128, 4096, 1024  # ring_depth() x max_groups()
+    rings = (((depth, cap), "int32"),) + (((depth, cap), "float32"),) * 4
+    batch = (((rows,), "int32"), ((rows,), "int32"), ((rows,), "float32"))
+    compile_for(_fold_body, (*rings, ((depth,), "bool"), *batch, *batch))
+    compile_for(_gather_body, (*rings, ((64,), "int32")))
+
+
+def test_merge_sort_kernel_compiles(compile_for):
+    """The compaction/merge-read sort at one 2^21-row bucket."""
+    from horaedb_tpu.ops.merge_dedup import _ranked_kernel
+
+    compile_for(
+        _ranked_kernel,
+        (((N,), "uint32"), ((N,), "uint32"), ((), "uint32"), ((), "uint32"),
+         ((), "int32")),
+        dedup=True,
+    )
+
+
+def test_sharded_steps_compile_on_four_chips(mesh4):
+    """The shard_map steps a four-chip host serves S1 and S4 with: rows
+    split four ways, partials combined by psum/pmin/pmax."""
+    from horaedb_tpu.ops.scan_agg import ScanAggSpec
+    from horaedb_tpu.ops.scan_topk import RawScanSpec
+    from horaedb_tpu.parallel.dist_agg import make_cached_dist_scan_agg
+    from horaedb_tpu.parallel.dist_raw import make_dist_raw_topk
+
+    mesh, spec = mesh4
+    scalars = [spec((), "int32")] * 4
+    agg = make_cached_dist_scan_agg(
+        mesh, ScanAggSpec(n_groups=1, n_buckets=64, n_agg_fields=5,
+                          segment_impl="mxu"),
+    )
+    compiled = agg.lower(
+        spec((N,), "int32", "shard"), spec((N,), "int32", "shard"),
+        spec((5, N), "float32", None, "shard"),
+        spec((S + 1,), "int32"), spec((S + 1,), "bool"),
+        spec((0,), "float32"), *scalars,
+    ).compile()
+    assert "all-reduce" in compiled.as_text()
+    topk = make_dist_raw_topk(
+        mesh, RawScanSpec(k=128, descending=True, key_is_ts=True)
+    )
+    topk.lower(
+        spec((N,), "int32", "shard"), spec((N,), "int32", "shard"),
+        spec((0, N), "float32", None, "shard"),
+        spec((S + 1,), "bool"), spec((0,), "float32"), *scalars,
+    ).compile()
+
+
+# ---- CPU tests: the policy and the repair the compiles above rest on ------
+
+# Step 2's outcome on this tree at N = 2^21 with min/max wanted, per
+# (impl, n_seg): True compiled for the described v5e, False refused.
+# Before PR 23 ("mxu", 8192) and ("mxu", 32768) were False.
+STEP2_AT_2M_ROWS = {
+    ("mxu", 64): True, ("scatter", 64): True, ("hash", 64): True,
+    ("mxu", 4096): True, ("scatter", 4096): True, ("hash", 4096): True,
+    ("mxu", 8192): True, ("scatter", 8192): True, ("hash", 8192): True,
+    ("mxu", 32768): True,
+}
+
+
+def test_tpu_policy_offers_no_refused_impl(monkeypatch):
+    """Told the backend is a TPU, the three places that choose a segment
+    impl never offer one whose program the chip's compiler refused at
+    that (rows, segments)."""
+    import jax
+
+    from horaedb_tpu.ops.scan_agg import resolve_segment_impl
+    from horaedb_tpu.query.path_router import candidate_kernels, seed_kernel
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("HORAEDB_SEGMENT_IMPL", raising=False)
+    monkeypatch.delenv("HORAEDB_MXU_MAX_SEGMENTS", raising=False)
+    assert resolve_segment_impl(64) == "mxu"  # the TPU branch is live
+    for n_seg in sorted({n for _, n in STEP2_AT_2M_ROWS}):
+        offered = {resolve_segment_impl(n_seg)}
+        for est in (None, 8, n_seg):
+            offered.add(seed_kernel(n_seg, est, "tpu"))
+            offered.update(candidate_kernels(n_seg, N, est))
+        for impl in offered:
+            assert STEP2_AT_2M_ROWS.get((impl, n_seg), True), (impl, n_seg)
+
+
+def test_mxu_minmax_chunks_are_exact(monkeypatch):
+    """The row-chunked min/max (what keeps the match mask inside HBM on
+    the chip) equals the one-shot reduce and the scatter impl, pad rows
+    and masked rows included."""
+    import jax.numpy as jnp
+
+    from horaedb_tpu.ops import scan_agg
+
+    rng = np.random.default_rng(7)
+    n, n_fields, n_seg = 5000, 3, 37  # 5000 rows: the last chunk is padded
+    seg = jnp.asarray(rng.integers(0, n_seg, n).astype(np.int32))
+    mask = jnp.asarray(rng.random(n) > 0.3)
+    vals = jnp.asarray(rng.normal(size=(n_fields, n)).astype(np.float32))
+    whole = scan_agg._mxu_segment_agg(seg, mask, vals, n_seg, True)
+    monkeypatch.setattr(scan_agg, "_MINMAX_MASK_BYTES", 128 * n_seg)
+    chunked = scan_agg._mxu_segment_agg(seg, mask, vals, n_seg, True)
+    scatter = scan_agg._scatter_segment_agg(seg, mask, vals, n_seg, True)
+    for a, b, c in zip(whole[2:], chunked[2:], scatter[2:]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(c))
+    np.testing.assert_array_equal(np.asarray(whole[0]), np.asarray(chunked[0]))
