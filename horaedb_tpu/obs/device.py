@@ -21,13 +21,12 @@ Four legs:
    eviction counts.
 2. **Kernel timing** — ``timed_dispatch(kind, fn)`` wraps every device
    dispatch point (cached agg packed/dist/cohort, raw top-k/selection,
-   the fused direct/partial kernel). Timing is SAMPLED (default 1-in-N,
-   ``HORAEDB_DEVICE_SAMPLE``): a sampled dispatch pays one
-   ``block_until_ready`` so the measured wall is honest on-device time,
-   an unsampled one stays fully async. Slow-log candidates (elapsed so
-   far over ``HORAEDB_DEVICE_SLOW_MS``) and EXPLAIN ANALYZE runs are
-   always timed — diagnostics want the number, not the pipeline.
-   Results land in the ledger (``device_ms``, ``device_dispatches``)
+   the fused direct/partial kernel). EVERY dispatch is timed: each
+   dispatch point fetches its result in the next statement, so one
+   ``block_until_ready`` serialises nothing that was not serial. The
+   launch and the wait are the spans ``dispatch`` and ``device_wait``
+   (utils/tracectx, so they are on the profiler's clock too); launch ->
+   ready lands in the ledger (``device_ms``, ``device_dispatches``)
    and the per-kernel ``horaedb_device_dispatch_seconds`` histograms.
 3. **Compile accounting** — ``utils/querystats.note_kernel_dispatch``
    routes first-seen static shapes here: a typed ``kernel_compile``
@@ -48,13 +47,12 @@ the groupby/rawscan benches (``BENCH_CONFIG=devicetel`` gates it).
 
 from __future__ import annotations
 
-import threading
 import time
 import weakref
 from typing import Any, Callable, Optional
 
-from ..utils.env import env_float, env_int
 from ..utils.metrics import REGISTRY
+from ..utils.tracectx import span
 
 # Every device-dispatch point declares its kernel kind here — the label
 # set of the horaedb_device_* families (eagerly registered, lint-pinned
@@ -109,7 +107,7 @@ _M_DISPATCH = {
 _M_DISPATCH_SECONDS = {
     k: REGISTRY.histogram(
         "horaedb_device_dispatch_seconds",
-        "sampled on-device dispatch wall seconds (block_until_ready)",
+        "device dispatch wall seconds, launch -> ready (block_until_ready)",
         buckets=_DISPATCH_BUCKETS,
         labels={"kernel": k},
     )
@@ -157,90 +155,36 @@ def device_telemetry_enabled() -> bool:
     return os.environ.get("HORAEDB_DEVICE_TELEMETRY", "1") != "0"
 
 
-def sample_every() -> int:
-    """Time 1 in N dispatches (HORAEDB_DEVICE_SAMPLE, default 8; <=1
-    times every dispatch). Sampling exists so the async dispatch
-    pipeline is not serialized: a timed dispatch blocks until the device
-    answers, an untimed one overlaps host work as before."""
-    return max(1, env_int("HORAEDB_DEVICE_SAMPLE", 8))
-
-
-# The proxy's live slow-log threshold overrides the env default (see
-# set_slow_candidate_s): a query that will be slow-logged must carry a
-# device_ms whatever threshold the operator dialed in at runtime.
-_slow_override: Optional[float] = None
-
-
-def set_slow_candidate_s(seconds: float) -> None:
-    """Couple the always-time threshold to the slow-log threshold — the
-    proxy calls this whenever ``slow_threshold_s`` changes (init and the
-    PUT /debug/slow_threshold endpoint), so a slow-logged query's
-    dispatches are always timed. Process-global like the slow log's
-    candidate set itself; with several proxies the last setter wins."""
-    global _slow_override
-    _slow_override = max(0.0, float(seconds))
-
-
-def _slow_candidate_s() -> float:
-    """Queries already slower than this are timed ALWAYS — their
-    slow-log row must say where the time went. The MIN of the env knob
-    (HORAEDB_DEVICE_SLOW_MS, default 1s) and the proxy's live slow-log
-    threshold: min, not override, so the documented knob keeps working
-    in server deployments (Proxy.__init__ sets the override at
-    construction) and a lowered threshold from either side only ever
-    times MORE, never less."""
-    env_s = env_float("HORAEDB_DEVICE_SLOW_MS", 1000.0) / 1000.0
-    if _slow_override is not None:
-        return min(_slow_override, env_s)
-    return env_s
-
-
 # ---- kernel timing ---------------------------------------------------------
 
-# per-kind dispatch counters driving the 1-in-N sample choice (first
-# dispatch of each kind is always sampled — compiles mostly get timed)
-_sample_counts: dict[str, int] = {}
-_sample_lock = threading.Lock()
 
+def timed_dispatch(kind: str, fn: Callable[[], Any], **attrs: Any) -> Any:
+    """Run one device dispatch and wait for its result; returns ``fn()``'s
+    result unchanged.
 
-def _should_time(kind: str) -> bool:
-    from ..utils.querystats import current_ledger
-
-    ledger = current_ledger()
-    if ledger is not None:
-        # slow-log candidate: the query has already blown the slow
-        # threshold — its diagnosis needs the device number
-        if time.time() - ledger.started_at >= _slow_candidate_s():
-            return True
-        # EXPLAIN ANALYZE is a diagnostic run: always time it so the
-        # rendered ledger carries device_ms (serializing it is fine)
-        if ledger.sql.lstrip()[:7].lower() == "explain":
-            return True
-    n = sample_every()
-    if n <= 1:
-        return True
-    with _sample_lock:
-        c = _sample_counts.get(kind, 0)
-        _sample_counts[kind] = c + 1
-    return c % n == 0
-
-
-def timed_dispatch(kind: str, fn: Callable[[], Any]) -> Any:
-    """Run one device dispatch with sampled ``block_until_ready``
-    timing; returns ``fn()``'s result unchanged.
-
-    Always (cheap): bumps ``horaedb_device_dispatch_total{kernel=}`` and
-    the ledger's ``device_dispatches``. Sampled: blocks on the result,
-    observes the per-kernel dispatch histogram, and adds the wall
-    milliseconds to the ledger's ``device_ms``. Telemetry off: a bare
-    call."""
+    Two spans: ``dispatch`` around ``fn()`` alone (host time to launch —
+    the call returns before the device is done; ``attrs`` such as
+    ``impl``/``program`` land on it beside ``kernel``) and
+    ``device_wait`` around one ``block_until_ready``. Bumps
+    ``horaedb_device_dispatch_total{kernel=}`` and the ledger's
+    ``device_dispatches``, observes the per-kernel dispatch histogram
+    and adds launch -> ready milliseconds to the ledger's ``device_ms``.
+    Telemetry off: a bare call."""
     if not device_telemetry_enabled():
         return fn()
+    import jax
+
     from ..utils import querystats
 
-    timed = _should_time(kind)
     t0 = time.perf_counter()
-    out = fn()
+    with span("dispatch", kernel=kind, **attrs):
+        out = fn()
+    with span("device_wait"):
+        try:
+            jax.block_until_ready(out)
+        except Exception:
+            pass  # a failed program raises where its result is fetched
+    dt = time.perf_counter() - t0
     counter = _M_DISPATCH.get(kind)
     if counter is None:  # undeclared kind: account it, lint will flag
         counter = REGISTRY.counter(
@@ -249,25 +193,16 @@ def timed_dispatch(kind: str, fn: Callable[[], Any]) -> Any:
             labels={"kernel": kind},
         )
     counter.inc()
-    querystats.record(device_dispatches=1)
-    if timed:
-        try:
-            import jax
-
-            jax.block_until_ready(out)
-        except Exception:
-            pass  # host-side results (numpy) have nothing to block on
-        dt = time.perf_counter() - t0
-        hist = _M_DISPATCH_SECONDS.get(kind)
-        if hist is None:
-            hist = REGISTRY.histogram(
-                "horaedb_device_dispatch_seconds",
-                "sampled on-device dispatch wall seconds (block_until_ready)",
-                buckets=_DISPATCH_BUCKETS,
-                labels={"kernel": kind},
-            )
-        hist.observe(dt)
-        querystats.record(device_ms=dt * 1000.0)
+    hist = _M_DISPATCH_SECONDS.get(kind)
+    if hist is None:
+        hist = REGISTRY.histogram(
+            "horaedb_device_dispatch_seconds",
+            "device dispatch wall seconds, launch -> ready (block_until_ready)",
+            buckets=_DISPATCH_BUCKETS,
+            labels={"kernel": kind},
+        )
+    hist.observe(dt)
+    querystats.record(device_dispatches=1, device_ms=dt * 1000.0)
     return out
 
 

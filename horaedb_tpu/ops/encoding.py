@@ -18,6 +18,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -467,10 +468,15 @@ def decode_layouts(
     sc_parts = _as_parts(series_codes)
     ts_parts = _as_parts(ts_rel)
     n_rows = layout_rows(sc_parts, series_layout)
-    sc = decode_series(sc_parts, series_layout, n_rows, idx)
-    tr = decode_ts(ts_parts, ts_layout, n_rows, idx)
+    # the scopes name the stages in a device trace (the ops are fusion.N)
+    with jax.named_scope("decode_series"):
+        sc = decode_series(sc_parts, series_layout, n_rows, idx)
+    with jax.named_scope("decode_ts"):
+        tr = decode_ts(ts_parts, ts_layout, n_rows, idx)
     layouts = value_layouts or tuple(("raw",) for _ in values)
-    vals = [
-        decode_value(_as_parts(p), l, n_rows, idx) for p, l in zip(values, layouts)
-    ]
+    with jax.named_scope("decode_values"):
+        vals = [
+            decode_value(_as_parts(p), l, n_rows, idx)
+            for p, l in zip(values, layouts)
+        ]
     return sc, tr, vals

@@ -304,24 +304,25 @@ def scan_agg_body(
     """Pure kernel body — also the per-shard program inside shard_map
     (parallel/dist_agg.py wraps it with psum/pmin/pmax collectives)."""
     m = mask
-    for i, (field_idx, op_code) in enumerate(numeric_filters):
-        v = values[field_idx]
-        lit = literals[i]
-        if op_code == 0:
-            m = m & (v == lit)
-        elif op_code == 1:
-            m = m & (v != lit)
-        elif op_code == 2:
-            m = m & (v < lit)
-        elif op_code == 3:
-            m = m & (v <= lit)
-        elif op_code == 4:
-            m = m & (v > lit)
-        else:
-            m = m & (v >= lit)
+    with jax.named_scope("filter"):
+        for i, (field_idx, op_code) in enumerate(numeric_filters):
+            v = values[field_idx]
+            lit = literals[i]
+            if op_code == 0:
+                m = m & (v == lit)
+            elif op_code == 1:
+                m = m & (v != lit)
+            elif op_code == 2:
+                m = m & (v < lit)
+            elif op_code == 3:
+                m = m & (v <= lit)
+            elif op_code == 4:
+                m = m & (v > lit)
+            else:
+                m = m & (v >= lit)
 
-    n_seg = n_groups * n_buckets
-    seg_raw = group_codes * n_buckets + bucket_ids
+        n_seg = n_groups * n_buckets
+        seg_raw = group_codes * n_buckets + bucket_ids
     # ``values`` may be a list of per-field rows (the encoded-layout decode
     # produces one array per field): stack only the agg fields — fields
     # referenced solely by filters never materialize a decoded column.
@@ -340,18 +341,19 @@ def scan_agg_body(
         if segment_impl in ("single",) + SEGMENT_KERNELS
         else resolve_segment_impl(n_seg, segment_impl)
     )
-    if impl_name == "single":
-        counts, sums, mins, maxs = _single_segment_agg(m, agg_vals, need_minmax)
-    elif impl_name == "hash":
-        from .hash_agg import default_hash_slots, hash_segment_agg
+    with jax.named_scope("segment_" + impl_name):
+        if impl_name == "single":
+            counts, sums, mins, maxs = _single_segment_agg(m, agg_vals, need_minmax)
+        elif impl_name == "hash":
+            from .hash_agg import default_hash_slots, hash_segment_agg
 
-        counts, sums, mins, maxs = hash_segment_agg(
-            seg_raw, m, agg_vals, n_seg, need_minmax,
-            hash_slots or default_hash_slots(n_seg),
-        )
-    else:
-        impl = _mxu_segment_agg if impl_name == "mxu" else _scatter_segment_agg
-        counts, sums, mins, maxs = impl(seg_raw, m, agg_vals, n_seg, need_minmax)
+            counts, sums, mins, maxs = hash_segment_agg(
+                seg_raw, m, agg_vals, n_seg, need_minmax,
+                hash_slots or default_hash_slots(n_seg),
+            )
+        else:
+            impl = _mxu_segment_agg if impl_name == "mxu" else _scatter_segment_agg
+            counts, sums, mins, maxs = impl(seg_raw, m, agg_vals, n_seg, need_minmax)
 
     counts = counts.reshape(n_groups, n_buckets)
     if n_agg_fields:
@@ -422,10 +424,11 @@ def cached_scan_agg_body(
     series_codes, ts_rel, values = _decode_layouts(
         series_codes, ts_rel, values, series_layout, ts_layout, value_layouts
     )
-    mask = allowed_series[series_codes]
-    mask = mask & (ts_rel >= lo_rel) & (ts_rel < hi_rel)
-    bucket = jnp.clip((ts_rel - t0_rel) // bucket_ms, 0, n_buckets - 1).astype(jnp.int32)
-    group_codes = group_of_series[series_codes]
+    with jax.named_scope("filter"):
+        mask = allowed_series[series_codes]
+        mask = mask & (ts_rel >= lo_rel) & (ts_rel < hi_rel)
+        bucket = jnp.clip((ts_rel - t0_rel) // bucket_ms, 0, n_buckets - 1).astype(jnp.int32)
+        group_codes = group_of_series[series_codes]
     if not isinstance(values, (list, tuple)):
         # bf16-resident value columns (HORAEDB_CACHE_DTYPE) upcast here:
         # accumulation always runs in f32 (no-op when already f32)
@@ -607,24 +610,73 @@ def _packed_body(
     # counts, never the counts as f32 — small int bit patterns are f32
     # denormals, and the TPU flushes those to zero when it fuses the
     # concatenate (measured on a v5e: every count came back 0).
-    parts = [counts.reshape(-1), _f32_bits(sums)]
-    if need_minmax:
-        parts.extend([_f32_bits(mins), _f32_bits(maxs)])
-    return jnp.concatenate(parts)
+    with jax.named_scope("pack"):
+        parts = [counts.reshape(-1), _f32_bits(sums)]
+        if need_minmax:
+            parts.extend([_f32_bits(mins), _f32_bits(maxs)])
+        return jnp.concatenate(parts)
 
 
 def _f32_bits(x):
     return jax.lax.bitcast_convert_type(x.reshape(-1), jnp.int32)
 
 
-cached_scan_agg_packed = functools.partial(
-    jax.jit,
-    static_argnames=(
-        "n_groups", "n_buckets", "n_agg_fields", "numeric_filters",
-        "need_minmax", "segment_impl", "hash_slots", "selective",
-        "value_layouts", "ts_layout", "series_layout",
-    ),
-)(_packed_body)
+_PACKED_STATIC = (
+    "n_groups", "n_buckets", "n_agg_fields", "numeric_filters",
+    "need_minmax", "segment_impl", "hash_slots", "selective",
+    "value_layouts", "ts_layout", "series_layout",
+)
+_packed_programs: dict[str, object] = {}
+
+
+def packed_program_name(segment_impl: str, selective: bool) -> str:
+    """What the device trace calls the packed cached scan of one concrete
+    segment impl: ``XLA Modules`` reads ``jit_cached_scan_mxu(...)``,
+    ``jit_cached_scan_scatter_sel(...)`` (``_sel``: the gathered subset)."""
+    return f"cached_scan_{segment_impl}" + ("_sel" if selective else "")
+
+
+def _packed_program(name: str):
+    """``_packed_body`` jitted under ``name``: one program per name, so the
+    trace tells the implementations apart without their fingerprints."""
+    program = _packed_programs.get(name)
+    if program is None:
+
+        @functools.wraps(_packed_body)
+        def body(*args, **kwargs):
+            return _packed_body(*args, **kwargs)
+
+        body.__name__ = body.__qualname__ = name
+        program = _packed_programs.setdefault(
+            name, jax.jit(body, static_argnames=_PACKED_STATIC)
+        )
+    return program
+
+
+def _pick_packed(kwargs: dict):
+    """-> (the named program, kwargs with the segment impl made concrete)."""
+    impl = resolve_segment_impl(
+        kwargs["n_groups"] * kwargs["n_buckets"],
+        kwargs.get("segment_impl", "auto"),
+    )
+    name = packed_program_name(impl, bool(kwargs.get("selective", False)))
+    return _packed_program(name), {**kwargs, "segment_impl": impl}
+
+
+def cached_scan_agg_packed(*args, **kwargs):
+    """The packed serving kernel's entry: picks the program named for the
+    statics' (segment impl, selective) and calls it. ``.lower`` does the
+    same for ahead-of-time compiles and ``cost_analysis``."""
+    program, kwargs = _pick_packed(kwargs)
+    return program(*args, **kwargs)
+
+
+def _lower_packed(*args, **kwargs):
+    program, kwargs = _pick_packed(kwargs)
+    return program.lower(*args, **kwargs)
+
+
+cached_scan_agg_packed.lower = _lower_packed
 
 
 def _cohort_body(

@@ -194,23 +194,6 @@ class Proxy:
         )
         self._m_class_latency = _M_CLASS_LATENCY
 
-    @property
-    def slow_threshold_s(self) -> float:
-        return self._slow_threshold_s
-
-    @slow_threshold_s.setter
-    def slow_threshold_s(self, seconds: float) -> None:
-        """The live slow-log threshold also drives the device plane's
-        always-time rule (obs/device): a query about to be slow-logged
-        must carry a measured device_ms whatever threshold the operator
-        dialed in at PUT /debug/slow_threshold — a sampled-out dispatch
-        would render the misleading ``device_ms=0`` this field exists
-        to prevent."""
-        self._slow_threshold_s = seconds
-        from ..obs.device import set_slow_candidate_s
-
-        set_slow_candidate_s(seconds)
-
     def close(self) -> None:
         self.runtime.shutdown()
         self.wlm.close()
@@ -234,7 +217,13 @@ class Proxy:
             observe_budget,
         )
         from ..utils.querystats import finish_ledger, start_ledger
-        from ..utils.tracectx import finish_trace, span, start_trace, tag_trace
+        from ..utils.tracectx import (
+            annotate,
+            finish_trace,
+            span,
+            start_trace,
+            tag_trace,
+        )
 
         # The time budget opens HERE, at ingress, and rides the same
         # ContextVar discipline as the trace/ledger — every layer below
@@ -252,6 +241,9 @@ class Proxy:
         if deadline is None:
             deadline = Deadline(self.default_timeout_ms)
         observe_budget(deadline.budget_ms)
+        # a wire handler's own root (``http_sql``) learns which statement
+        # trace answered it: its id is the response's X-HoraeDB-Request-Id
+        annotate(request_id=ctx.request_id)
         trace, handle = start_trace(ctx.request_id, "sql", sql=sql[:200])
         # The cost ledger rides the same context: every stage the request
         # touches (scans, cache, kernels, remote fan-out) accounts into

@@ -33,6 +33,7 @@ from ..ops import ScanAggSpec, encode_group_codes, scan_aggregate
 from ..ops.encoding import build_padded_batch, time_buckets
 from ..table_engine.predicate import NUMPY_CMP, FilterOp, Predicate
 from ..utils import querystats
+from ..utils.tracectx import span as _span
 from . import ast
 from .plan import AggCall, GroupKey, QueryPlan
 
@@ -600,8 +601,6 @@ class Executor:
             out = try_dist_plan(self, plan, table, m)
             if out is not None:
                 return self._finish_metrics(m, t_start, "dist-plan", out)
-        from ..utils.tracectx import span as _span
-
         # Raw (non-aggregate) reads: the same HBM-serving treatment the
         # aggregate paths got — fused filter + top-k / bounded selection
         # over the scan cache, returning only row indices to gather.
@@ -802,7 +801,7 @@ class Executor:
             return None  # shape not pushable: gather-rows fallback below
         if bounded_hint:
             spec["bounded_hint"] = True
-        from ..utils.tracectx import span as _span, wire_context
+        from ..utils.tracectx import wire_context
 
         wire = wire_context()
         if wire is not None:
@@ -1079,7 +1078,17 @@ class Executor:
         paths). ``allow_selective=False`` skips the gathered-subset
         optimization so the resulting spec stays cohort-mergeable (the
         batched kernel cannot vmap over per-query-variable row
-        indices)."""
+        indices). The whole of it is the span ``prepare`` (a cache build
+        shows there)."""
+        with _span("prepare") as sp:
+            prep = self._prepare_cached_agg(plan, table, m, allow_selective)
+            # "cache" is recorded only once eligibility is confirmed
+            sp.set(cache=m.get("cache", "bail"), rows=m.get("rows_scanned", 0))
+            return prep
+
+    def _prepare_cached_agg(
+        self, plan: QueryPlan, table, m: dict, allow_selective: bool
+    ) -> Optional["CachedAggPrep"]:
         schema = plan.schema
         if schema.tsid_index is None or not table.physical_datas():
             return None
@@ -1334,8 +1343,9 @@ class Executor:
         t0_rel, width_i = prep.t0_rel, prep.width_i
         gos, allow_scan = prep.gos, prep.allow_scan
         row_idx, kernel_key = prep.row_idx, prep.kernel_key
-        values_dev = entry.values_for(value_names)
         import time as _time
+
+        from ..obs.device import cost_analysis, timed_dispatch
 
         t_kernel = _time.perf_counter()
         if entry.mesh is not None:
@@ -1344,15 +1354,12 @@ class Executor:
             # serving path; single-device deployments take the packed arm).
             from ..parallel.dist_agg import make_cached_dist_scan_agg
 
-            from ..obs.device import timed_dispatch
-
-            step = make_cached_dist_scan_agg(entry.mesh, spec)
-            out = timed_dispatch(
-                "cached_dist",
-                lambda: step(
+            with _span("upload", selective=False):
+                step = make_cached_dist_scan_agg(entry.mesh, spec)
+                args = (
                     entry.series_codes_dev,
                     entry.ts_rel_dev,
-                    values_dev,
+                    entry.values_for(value_names),
                     jnp.asarray(gos),
                     jnp.asarray(allow_scan),
                     coerce_literals(literals),
@@ -1360,10 +1367,13 @@ class Executor:
                     np.int32(hi_rel),
                     np.int32(t0_rel),
                     np.int32(width_i),
-                ),
+                )
+            out = timed_dispatch(
+                "cached_dist", lambda: step(*args), impl=spec.segment_impl
             )
             m["mesh_devices"] = int(entry.mesh.devices.size)
-            state = state_to_host(*out)
+            with _span("fetch", bytes=sum(int(o.nbytes) for o in out)):
+                state = state_to_host(*out)
             querystats.note_kernel_dispatch(
                 ("cached-dist", int(entry.mesh.devices.size), *kernel_key),
                 _time.perf_counter() - t_kernel,
@@ -1376,20 +1386,22 @@ class Executor:
             from ..ops.scan_agg import (
                 cached_scan_agg_packed,
                 pack_dyn,
+                packed_program_name,
                 unpack_packed_state,
             )
 
-            from ..obs.device import cost_analysis, timed_dispatch
-
-            session_dev = entry.session_for(gos, allow_scan)
-            dyn = pack_dyn(literals, lo_rel, hi_rel, t0_rel, width_i, row_idx)
-            pargs = (
-                entry.series_parts,
-                entry.ts_parts,
-                values_dev,
-                session_dev,
-                jnp.asarray(dyn),
-            )
+            selective = row_idx is not None
+            with _span("upload", selective=selective):
+                values_dev = entry.values_for(value_names)
+                session_dev = entry.session_for(gos, allow_scan)
+                dyn = pack_dyn(literals, lo_rel, hi_rel, t0_rel, width_i, row_idx)
+                pargs = (
+                    entry.series_parts,
+                    entry.ts_parts,
+                    values_dev,
+                    session_dev,
+                    jnp.asarray(dyn),
+                )
             pkwargs = dict(
                 n_groups=spec.n_groups,
                 n_buckets=spec.n_buckets,
@@ -1398,18 +1410,21 @@ class Executor:
                 need_minmax=spec.need_minmax,
                 segment_impl=spec.segment_impl,
                 hash_slots=spec.hash_slots,
-                selective=row_idx is not None,
+                selective=selective,
                 value_layouts=prep.value_layouts,
                 ts_layout=entry.ts_layout,
                 series_layout=entry.series_layout,
             )
             packed = timed_dispatch(
                 "cached_packed",
-                lambda: cached_scan_agg_packed(*pargs, **pkwargs),
+                lambda: _fetch_behind(cached_scan_agg_packed(*pargs, **pkwargs)),
+                impl=spec.segment_impl,
+                program=packed_program_name(spec.segment_impl, selective),
             )
-            state = unpack_packed_state(packed, spec)
+            with _span("fetch", bytes=int(packed.nbytes)):
+                state = unpack_packed_state(packed, spec)
             querystats.note_kernel_dispatch(
-                ("cached-packed", row_idx is not None, *kernel_key),
+                ("cached-packed", selective, *kernel_key),
                 _time.perf_counter() - t_kernel,
                 kind="cached_packed",
                 cost_fn=lambda: cost_analysis(
@@ -1419,16 +1434,27 @@ class Executor:
         self._finish_kernel(
             prep.krec, spec, m, state, _time.perf_counter() - t_kernel
         )
+        return self._fold_and_assemble(prep, state)
+
+    def _fold_and_assemble(self, prep: "CachedAggPrep", state) -> ResultSet:
+        """One prepared query's host tail: fold its memtable delta into the
+        fetched state (span ``fold_delta``, only when there is one) and
+        assemble the ResultSet (span ``assemble``)."""
         if len(prep.delta) and not prep.empty_range:
-            self._fold_delta(
-                state, prep.delta, entry, plan.schema, gos, prep.allow,
-                prep.agg_cols, value_names, prep.device_filters,
-                prep.lo, prep.hi, prep.t0, prep.width, prep.n_buckets,
+            with _span("fold_delta", rows=len(prep.delta)):
+                self._fold_delta(
+                    state, prep.delta, prep.entry, prep.plan.schema, prep.gos,
+                    prep.allow, prep.agg_cols, prep.value_names,
+                    prep.device_filters, prep.lo, prep.hi, prep.t0, prep.width,
+                    prep.n_buckets,
+                )
+        with _span("assemble") as sp:
+            out = self._assemble_agg_result(
+                prep.plan, prep.tag_keys, prep.key_values, prep.agg_cols, state,
+                max(prep.num_groups, 1), prep.n_buckets, prep.t0, prep.width,
             )
-        return self._assemble_agg_result(
-            plan, prep.tag_keys, prep.key_values, prep.agg_cols, state,
-            max(prep.num_groups, 1), prep.n_buckets, prep.t0, prep.width,
-        )
+            sp.set(rows=out.num_rows)
+            return out
 
     def dispatch_cached_agg_cohort(
         self, preps: list["CachedAggPrep"]
@@ -1458,36 +1484,40 @@ class Executor:
 
         p0 = preps[0]
         entry, spec = p0.entry, p0.spec
-        sessions = np.stack(
-            [pack_session(p.gos, p.allow_scan) for p in preps]
-        )
-        dyns = np.stack(
-            [
-                pack_dyn(p.literals, p.lo_rel, p.hi_rel, p.t0_rel, p.width_i)
-                for p in preps
-            ]
-        )
         B = len(preps)
         # pow2-bucketed batch axis bounds the jit-key count; pad members
         # replicate the last row and their outputs are discarded
         Bp = next_pow2(B, floor=2)
-        if Bp > B:
-            sessions = np.concatenate(
-                [sessions, np.repeat(sessions[-1:], Bp - B, axis=0)]
+        with _span("upload", selective=False):
+            sessions = np.stack(
+                [pack_session(p.gos, p.allow_scan) for p in preps]
             )
-            dyns = np.concatenate([dyns, np.repeat(dyns[-1:], Bp - B, axis=0)])
-        values_dev = entry.values_for(p0.value_names)
+            dyns = np.stack(
+                [
+                    pack_dyn(p.literals, p.lo_rel, p.hi_rel, p.t0_rel, p.width_i)
+                    for p in preps
+                ]
+            )
+            if Bp > B:
+                sessions = np.concatenate(
+                    [sessions, np.repeat(sessions[-1:], Bp - B, axis=0)]
+                )
+                dyns = np.concatenate(
+                    [dyns, np.repeat(dyns[-1:], Bp - B, axis=0)]
+                )
+            values_dev = entry.values_for(p0.value_names)
+            sessions_dev, dyns_dev = jnp.asarray(sessions), jnp.asarray(dyns)
         from ..obs.device import timed_dispatch
 
         t_kernel = _time.perf_counter()
         packed = timed_dispatch(
             "cached_cohort",
-            lambda: cached_scan_agg_cohort(
+            lambda: _fetch_behind(cached_scan_agg_cohort(
                 entry.series_parts,
                 entry.ts_parts,
                 values_dev,
-                jnp.asarray(sessions),
-                jnp.asarray(dyns),
+                sessions_dev,
+                dyns_dev,
                 n_groups=spec.n_groups,
                 n_buckets=spec.n_buckets,
                 n_agg_fields=spec.n_agg_fields,
@@ -1498,9 +1528,11 @@ class Executor:
                 value_layouts=p0.value_layouts,
                 ts_layout=entry.ts_layout,
                 series_layout=entry.series_layout,
-            ),
+            )),
+            impl=spec.segment_impl,
         )
-        rows = np.asarray(jax.device_get(packed))
+        with _span("fetch", bytes=int(packed.nbytes)):
+            rows = np.asarray(jax.device_get(packed))
         elapsed = _time.perf_counter() - t_kernel
         querystats.note_kernel_dispatch(
             ("cached-cohort", Bp, *p0.kernel_key), elapsed,
@@ -1520,18 +1552,7 @@ class Executor:
                     elapsed / B,
                 )
                 p.m["batch_cohort"] = B
-                if len(p.delta) and not p.empty_range:
-                    self._fold_delta(
-                        state, p.delta, entry, p.plan.schema, p.gos, p.allow,
-                        p.agg_cols, p.value_names, p.device_filters,
-                        p.lo, p.hi, p.t0, p.width, p.n_buckets,
-                    )
-                outs.append(
-                    self._assemble_agg_result(
-                        p.plan, p.tag_keys, p.key_values, p.agg_cols, state,
-                        max(p.num_groups, 1), p.n_buckets, p.t0, p.width,
-                    )
-                )
+                outs.append(self._fold_and_assemble(p, state))
             except BaseException as e:
                 outs.append(e)
         return outs
@@ -1852,7 +1873,6 @@ class Executor:
             raw_topk_packed,
             topk_key_bounds,
         )
-        from ..utils.tracectx import span as _span
 
         device_filters = shape["device_filters"]
         series_filters = shape["series_filters"]
@@ -2372,6 +2392,15 @@ class Executor:
         if (stmt.distinct or has_window) and (stmt.limit is not None or stmt.offset):
             result = _slice_result(result, stmt.offset, stmt.limit)
         return result
+
+
+def _fetch_behind(result):
+    """Queue the device -> host copy of a just-launched program's result
+    behind the program. ``timed_dispatch`` waits for the device before the
+    caller fetches; without this the copy would only be issued after that
+    wait (about a third of a millisecond a dispatch on a v5e)."""
+    result.copy_to_host_async()
+    return result
 
 
 def route_segment_kernel(shape_key, spec, n_rows: int, est_distinct,

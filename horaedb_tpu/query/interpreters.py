@@ -396,8 +396,7 @@ class InterpreterFactory:
                 if detail:
                     lines.append(f"  Metrics: {detail}")
                 lines.append(f"  Ledger: {render_ledger(qledger)}")
-                # Device plane: EXPLAIN ANALYZE dispatches are always
-                # timed (obs/device forces sampling for explain runs),
+                # Device plane: every dispatch is timed (obs/device),
                 # so device_ms is present whenever a kernel ran; compile
                 # events this run journaled render inline.
                 dd = int(qledger.counts.get("device_dispatches", 0))

@@ -106,7 +106,19 @@ def _dumps(obj: Any) -> str:
     return json.dumps(obj, default=_json_default)
 
 
+class Served(tuple):
+    """``(kind, payload)`` as ``SqlGateway.execute`` hands it to a wire
+    handler, plus the id of the ``sql`` trace that produced it: the key
+    of ``/debug/trace/{id}`` and ``query_stats`` (None where no statement
+    ran on this node under a handler's trace)."""
+
+    request_id = None
+
+
 FORWARD_HEADER = "X-HoraeDB-Forwarded"
+# The id of the statement's trace, on every /sql response a statement of
+# this node produced (a coalesced twin carries its leader's).
+REQUEST_ID_HEADER = "X-HoraeDB-Request-Id"
 # Deadline propagation (utils/deadline): the client's per-request time
 # budget in milliseconds. Forwarding hops re-stamp it with the
 # REMAINING budget, so a multi-hop read decrements one budget instead
@@ -531,7 +543,21 @@ class SqlGateway:
     async def _run_local(
         self, proxy, query: str, tenant: str = "default", deadline=None
     ):
+        """Run the statement on this node. -> ``Served``: ``(kind,
+        payload)`` plus the id of the statement's ``sql`` trace, which
+        ``Proxy.handle_sql`` stamps on the span that waits for it (a wire
+        handler's ``handle``; no handler trace, no id)."""
+        from ..utils.tracectx import current_span
+
+        served = Served(await self._serve_local(proxy, query, tenant, deadline))
+        waiting = current_span()
+        if waiting is not None:
+            served.request_id = waiting.attrs.get("request_id")
+        return served
+
+    async def _serve_local(self, proxy, query: str, tenant: str, deadline):
         from ..utils.deadline import DeadlineExceeded, QueryCancelled, bind
+        from ..utils.tracectx import span
 
         loop = asyncio.get_running_loop()
         if tenant == "default":
@@ -572,7 +598,10 @@ class SqlGateway:
             return "error", (422, str(e), {})
         if isinstance(out, AffectedRows):
             return "affected", out.count
-        return "rows", (list(out.names), out.to_pylist())
+        with span("rows") as sp:
+            rows = out.to_pylist()
+            sp.set(rows=len(rows))
+        return "rows", (list(out.names), rows)
 
     async def _try_replica_local(
         self,
@@ -1126,10 +1155,28 @@ def create_app(
     app["sql_gateway"] = gateway
 
     async def sql(request: web.Request) -> web.Response:
+        """POST /sql under the handler's own root ``http_sql``: the
+        server's part of what a client calls the wire. It folds into
+        /debug/profile and takes no place in the /debug/trace ring, which
+        keeps the ``sql`` traces (``sql``'s extent is not moved)."""
+        from ..utils.tracectx import finish_trace, start_trace
+
+        trace, handle = start_trace(None, "http_sql")
+        trace.route = "http"
         try:
-            body = await request.json()
-        except json.JSONDecodeError:
-            return web.json_response({"error": "invalid JSON body"}, status=400)
+            return await _sql(request, trace)
+        finally:
+            finish_trace(handle, store=False)
+
+    async def _sql(request: web.Request, trace) -> web.Response:
+        from ..utils.tracectx import span
+
+        with span("accept") as sp:
+            try:
+                body = await request.json()
+            except json.JSONDecodeError:
+                return web.json_response({"error": "invalid JSON body"}, status=400)
+            sp.set(bytes=request.content_length or 0)
         query = body.get("query")
         if not isinstance(query, str) or not query.strip():
             return web.json_response({"error": "missing 'query'"}, status=400)
@@ -1139,28 +1186,37 @@ def create_app(
         # clear before executing or a later statement on the same
         # connection would inherit the previous one's replica headers
         REPLICA_RESPONSE.set(None)
-        kind, payload = await gateway.execute(
-            query,
-            already_forwarded=bool(request.headers.get(FORWARD_HEADER)),
-            protocol="http",
-            # per-tenant quota scope (wlm/quota); absent -> "default"
-            tenant=request.headers.get("X-HoraeDB-Tenant", "default"),
-            replica_read=bool(request.headers.get(REPLICA_READ_HEADER)),
-            staleness_ms=_parse_staleness(
-                request.headers.get(STALENESS_HEADER)
-            ),
-            replica_epoch=(
-                int(request.headers[REPLICA_EPOCH_HEADER])
-                if request.headers.get(REPLICA_EPOCH_HEADER, "").isdigit()
-                else None
-            ),
-            # per-request time budget (forwarding hops re-stamp the
-            # remaining budget into the same header)
-            timeout_ms=_parse_timeout_ms(request.headers.get(TIMEOUT_HEADER)),
-        )
+        # ``handle``: the hop onto the worker thread and the whole ``sql``
+        # trace (and ``rows`` under it), so the root's own time stays small
+        with span("handle"):
+            served = await gateway.execute(
+                query,
+                already_forwarded=bool(request.headers.get(FORWARD_HEADER)),
+                protocol="http",
+                # per-tenant quota scope (wlm/quota); absent -> "default"
+                tenant=request.headers.get("X-HoraeDB-Tenant", "default"),
+                replica_read=bool(request.headers.get(REPLICA_READ_HEADER)),
+                staleness_ms=_parse_staleness(
+                    request.headers.get(STALENESS_HEADER)
+                ),
+                replica_epoch=(
+                    int(request.headers[REPLICA_EPOCH_HEADER])
+                    if request.headers.get(REPLICA_EPOCH_HEADER, "").isdigit()
+                    else None
+                ),
+                # per-request time budget (forwarding hops re-stamp the
+                # remaining budget into the same header)
+                timeout_ms=_parse_timeout_ms(request.headers.get(TIMEOUT_HEADER)),
+            )
+        kind, payload = served
+        headers = {}
+        request_id = getattr(served, "request_id", None)
+        if request_id is not None:
+            trace.trace_id = request_id
+            trace.root.set(request_id=request_id)
+            headers[REQUEST_ID_HEADER] = str(request_id)
         if kind == "error":
             status, msg, extra = payload
-            headers = {}
             if extra.get("retry_after_s") is not None:
                 # shed/quota answers are retryable by contract: say when
                 headers["Retry-After"] = str(
@@ -1172,7 +1228,6 @@ def create_app(
                 # to the leader on it instead of failing the client
                 body["replica"] = extra["kind"]
             return web.json_response(body, status=status, headers=headers)
-        headers = {}
         rinfo = REPLICA_RESPONSE.get()
         if rinfo is not None:
             # follower-served: advertise the manifest epoch + lag
@@ -1183,10 +1238,11 @@ def create_app(
                 {"affected_rows": payload}, headers=headers
             )
         names, rows = payload
+        with span("encode") as sp:
+            text = _dumps({"rows": rows, "names": names})
+            sp.set(bytes=len(text))
         return web.Response(
-            text=_dumps({"rows": rows, "names": names}),
-            content_type="application/json",
-            headers=headers,
+            text=text, content_type="application/json", headers=headers,
         )
 
     async def write(request: web.Request) -> web.Response:
@@ -1218,22 +1274,27 @@ def create_app(
         nonblocking = _query_flag(request, "nonblocking")
 
         def do_write():
-            proxy.limiter.check(table)
-            proxy.wlm.quota.charge_write("default", table, len(rows))
-            t = conn_.catalog.open(table)
-            if t is None:
-                raise ValueError(f"table not found: {table}")
-            from ..common_types.row_group import RowGroup
-            from ..engine.instance import nonblocking_backpressure
+            from ..utils.tracectx import owned_trace
 
-            rg = RowGroup.from_rows(t.schema, rows)
-            if nonblocking:
-                with nonblocking_backpressure():
+            # the write path's own root: the engine's write_wait /
+            # write_group / wal_append / memtable_write spans hang here
+            with owned_trace("write", route="ingest", shape=f"insert {table}"):
+                proxy.limiter.check(table)
+                proxy.wlm.quota.charge_write("default", table, len(rows))
+                t = conn_.catalog.open(table)
+                if t is None:
+                    raise ValueError(f"table not found: {table}")
+                from ..common_types.row_group import RowGroup
+                from ..engine.instance import nonblocking_backpressure
+
+                rg = RowGroup.from_rows(t.schema, rows)
+                if nonblocking:
+                    with nonblocking_backpressure():
+                        t.write(rg)
+                else:
                     t.write(rg)
-            else:
-                t.write(rg)
-            proxy.hotspot.record(table, True)
-            return len(rg)
+                proxy.hotspot.record(table, True)
+                return len(rg)
 
         try:
             n = await asyncio.get_running_loop().run_in_executor(None, do_write)
@@ -2355,14 +2416,13 @@ def create_app(
         """The device telemetry plane (obs/device): HBM residency
         inventory (the same rows served SQL-side by
         ``system.public.device``), byte totals by component, per-kernel
-        compile-cache stats, and the sampling policy in force."""
+        compile-cache stats."""
         from ..obs import device as obs_device
 
         def collect():
             rows = obs_device.device_inventory()
             return {
                 "enabled": obs_device.device_telemetry_enabled(),
-                "sample_every": obs_device.sample_every(),
                 "inventory": rows,
                 "totals": obs_device.occupancy_totals(rows),
                 "compile": obs_device.compile_stats(),

@@ -491,7 +491,6 @@ def cmd_device(ep: str, args) -> None:
     print(
         "\ntotals: "
         + "  ".join(f"{k}={v}" for k, v in sorted(totals.items()))
-        + f"  (sampling 1-in-{data.get('sample_every')})"
     )
     compile_stats = data.get("compile", {})
     if compile_stats:

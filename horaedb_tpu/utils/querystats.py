@@ -96,9 +96,10 @@ NUMERIC_FIELDS: dict[str, str] = {
 FLOAT_FIELDS: dict[str, str] = {
     "jit_compile_seconds": "wall seconds spent compiling new kernel shapes",
     "admission_wait_seconds": "wall seconds waiting for an admission slot",
-    # sampled on-device dispatch wall (obs/device timed_dispatch):
-    # milliseconds for render friendliness — tiny kernels are sub-ms
-    "device_ms": "sampled on-device dispatch wall milliseconds (block_until_ready timing)",
+    # device dispatch wall, launch -> ready, of every dispatch (obs/device
+    # timed_dispatch): milliseconds for render friendliness — tiny
+    # kernels are sub-ms
+    "device_ms": "device dispatch wall milliseconds, launch -> ready (block_until_ready timing)",
 }
 
 LEDGER_FIELDS: dict[str, str] = {**NUMERIC_FIELDS, **FLOAT_FIELDS}

@@ -12,6 +12,8 @@ import concurrent.futures as cf
 import threading
 from typing import Callable, TypeVar
 
+from .tracectx import open_span
+
 T = TypeVar("T")
 
 
@@ -47,7 +49,16 @@ class PriorityRuntime:
         target_prefix = "query-low" if priority == "low" else "query-high"
         if name.startswith(target_prefix):
             return fn()
-        return self.submit(priority, fn).result()
+        # ``lane_wait``: submit -> a pool thread starts ``fn``, and closes
+        # the span there. This thread sleeps until the result: waking it
+        # to close the span itself cost a GIL hand-over per request.
+        waited = open_span("lane_wait", priority=priority)
+
+        def task() -> T:
+            waited.finish()
+            return fn()
+
+        return self.submit(priority, task).result()
 
     def shutdown(self) -> None:
         self._high.shutdown(wait=False, cancel_futures=True)

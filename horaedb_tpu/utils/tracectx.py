@@ -19,15 +19,23 @@ tree with every remote span it fanned out.
 Finished traces land in the bounded in-process ``TRACE_STORE`` (ring of
 recent + ring of slow), surfaced at /debug/trace and
 /debug/trace/{request_id}.
+
+One clock with the device: every entered span, roots included, also
+enters a ``jax.profiler.TraceAnnotation`` named ``hdb:<span name>``
+(``open_span`` hand-offs, finished on another thread, do not). It is inert
+until a profiler session runs; then the span lands on ``/host:CPU`` of
+the same xplane as ``XLA Ops``, so a device idle gap can be put down to
+the program stage that covers it. The session is the only switch.
 """
 
 from __future__ import annotations
 
 import contextvars
 import itertools
+import sys
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Any, Iterator, Optional
 
 # ---- flat request id (set by start_trace; wire_context falls back to it
@@ -197,6 +205,23 @@ _current_span: contextvars.ContextVar[Optional[Span]] = contextvars.ContextVar(
 )
 
 
+_TraceAnnotation = None  # jax.profiler.TraceAnnotation, once jax is there
+_NO_ANNOTATION = nullcontext()
+
+
+def _annotation(name: str):
+    """The profiler's view of a span: a context manager that writes
+    ``hdb:<name>`` into a running profiler trace and costs well under a
+    microsecond when none runs. jax is never imported from here: a
+    process that has not loaded it has no profiler session either."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        if "jax" not in sys.modules:
+            return _NO_ANNOTATION
+        from jax.profiler import TraceAnnotation as _TraceAnnotation
+    return _TraceAnnotation("hdb:" + name)
+
+
 def current_trace() -> Optional[Trace]:
     return _current_trace.get()
 
@@ -212,10 +237,13 @@ def start_trace(trace_id, name: str = "request", **attrs: Any):
     """Begin a trace in the current context. Returns ``(trace, handle)``;
     pass the handle to ``finish_trace``."""
     trace = Trace(trace_id, name, attrs or None)
+    annotation = _annotation(name)
+    annotation.__enter__()
     tokens = (
         _current_trace.set(trace),
         _current_span.set(trace.root),
         _request_id.set(trace_id),
+        annotation,
     )
     return trace, tokens
 
@@ -233,23 +261,28 @@ def tag_trace(route: Optional[str] = None, shape: Optional[str] = None) -> None:
         trace.shape = shape
 
 
-def finish_trace(handle, record: bool = True, slow: bool = False) -> None:
+def finish_trace(handle, record: bool = True, slow: bool = False,
+                 store: bool = True) -> None:
     """End the trace started with ``start_trace`` and (by default) record
     its snapshot in the global TRACE_STORE and fold it into the profile
     aggregator (obs/profile). ``record=False`` (serving_trace) skips
     BOTH: the subtree ships home and folds once, at the coordinator —
-    never double-counted fleetwide."""
-    t_tok, s_tok, r_tok = handle
+    never double-counted fleetwide. ``store=False`` folds without taking
+    a place in the ring (a wire handler's own root: the ring is for the
+    statement traces it wraps)."""
+    t_tok, s_tok, r_tok, annotation = handle
     trace = _current_trace.get()
     _current_trace.reset(t_tok)
     _current_span.reset(s_tok)
     _request_id.reset(r_tok)
+    annotation.__exit__(None, None, None)
     if trace is None:
         return
     trace.root.finish()
     if record:
         root = trace.to_dict()["root"]  # ONE locked walk per request
-        TRACE_STORE.record_snapshot(trace.trace_id, root, slow=slow)
+        if store:
+            TRACE_STORE.record_snapshot(trace.trace_id, root, slow=slow)
         try:
             from ..obs.profile import fold_trace
 
@@ -275,10 +308,25 @@ def span(name: str, **attrs: Any):
         return
     token = _current_span.set(s)
     try:
-        yield s
+        with _annotation(name):
+            yield s
     finally:
         s.finish()
         _current_span.reset(token)
+
+
+def open_span(name: str, **attrs: Any):
+    """A child of the current span that is handed on, not entered: it
+    parents nothing, and whoever holds it calls ``finish()`` — on another
+    thread if need be (``lane_wait``: opened at submit, closed by the
+    pool thread that picks the work up). It carries no profiler
+    annotation, which belongs to one thread: a device gap under it is
+    labelled with the enclosing span. No active trace → the no-op span."""
+    trace = _current_trace.get()
+    if trace is None:
+        return _NULL_SPAN
+    parent = _current_span.get() or trace.root
+    return trace.new_span(parent, name, attrs or None) or _NULL_SPAN
 
 
 _bg_trace_ids = itertools.count(1)

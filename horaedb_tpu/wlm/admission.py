@@ -40,6 +40,7 @@ from contextlib import contextmanager
 from typing import Optional
 
 from ..utils.metrics import REGISTRY
+from ..utils.tracectx import span
 
 CLASSES = ("cheap", "normal", "expensive")
 
@@ -352,7 +353,10 @@ class AdmissionController:
             budget.state = "queued"
         t0 = time.perf_counter()
         deadline = t0 + deadline_s
-        with self._cv:
+        # ``admission_wait`` covers the wait alone (zero-length when a slot
+        # is free): the slot is held outside any span of its own
+        with span("admission_wait", **{"class": cls}) as waiting, self._cv:
+            waiting.set(queued=self._waiting[cls])
             if not self._fits_locked(cls, units, mem):
                 if self._waiting[cls] >= self.queue_depth:
                     raise self._shed(

@@ -464,6 +464,171 @@ class TestSpanTracing:
             s.set(y=2)  # absorbed, nothing recorded anywhere
         assert current_span() is None
 
+    def test_span_outside_a_trace_allocates_nothing(self, monkeypatch):
+        """Outside a trace ``span()`` hands out the one shared null span:
+        no Span, no id, and no profiler annotation is made."""
+        from horaedb_tpu.utils import tracectx
+
+        made = []
+        monkeypatch.setattr(
+            tracectx, "_annotation", lambda name: made.append(name)
+        )
+        monkeypatch.setattr(
+            tracectx, "Span", lambda *a, **k: made.append("Span")
+        )
+        for name in ("prepare", "dispatch", "device_wait"):
+            with tracectx.span(name, rows=1) as s:
+                assert s is tracectx._NULL_SPAN
+                s.set(more=2).finish()
+        assert made == []
+
+    def test_spans_and_roots_enter_profiler_annotations(self, monkeypatch):
+        """One clock: every span of a trace, the root too, is entered as
+        ``hdb:<name>`` for exactly its lifetime (inert until a profiler
+        session runs; here a recorder stands in for the profiler)."""
+        import jax  # noqa: F401  the annotation exists once jax is loaded
+
+        from horaedb_tpu.utils import tracectx
+
+        log = []
+
+        class Recorder:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                log.append(("enter", self.name))
+
+            def __exit__(self, *exc):
+                log.append(("exit", self.name))
+
+        monkeypatch.setattr(tracectx, "_TraceAnnotation", Recorder)
+        trace, handle = tracectx.start_trace(77, "sql")
+        with tracectx.span("execute"):
+            with tracectx.span("dispatch", kernel="cached_packed"):
+                pass
+        tracectx.finish_trace(handle, record=False)
+        with tracectx.owned_trace("write", route="ingest") as root:
+            root.set(rows=1)
+        assert log == [
+            ("enter", "hdb:sql"), ("enter", "hdb:execute"),
+            ("enter", "hdb:dispatch"), ("exit", "hdb:dispatch"),
+            ("exit", "hdb:execute"), ("exit", "hdb:sql"),
+            ("enter", "hdb:write"), ("exit", "hdb:write"),
+        ]
+
+    def test_served_cached_aggregate_span_tree(self, monkeypatch):
+        """The served read path, stage by stage: one cached aggregate
+        over /sql holds admission_wait under ``sql`` and lane_wait,
+        prepare, upload, dispatch, device_wait, fetch, assemble in that
+        order under ``sql/execute``; the tree still reconciles; and the
+        handler's own ``http_sql`` root answers to the same request id,
+        which the response carries."""
+        from horaedb_tpu.obs import profile
+        from horaedb_tpu.obs.profile import UNTRACKED, ProfileAggregator
+        from horaedb_tpu.utils.tracectx import TRACE_STORE
+
+        monkeypatch.setenv("HORAEDB_ADAPTIVE_PATH", "0")  # device-first
+
+        async def body(client):
+            await client.post("/sql", json={"query":
+                "CREATE TABLE st (h string TAG, v double, ts timestamp KEY)"})
+            values = ", ".join(
+                f"('h{i % 4}', {float(i)}, {1000 + i})" for i in range(64)
+            )
+            await client.post("/sql", json={"query":
+                f"INSERT INTO st (h, v, ts) VALUES {values}"})
+            select = {"query": "SELECT h, sum(v) AS s FROM st GROUP BY h"}
+            for _ in range(4):  # candidate, build, hit, hit
+                resp = await client.post("/sql", json=select)
+                assert resp.status == 200
+            rid = int(resp.headers["X-HoraeDB-Request-Id"])
+            recent = await (await client.get("/debug/queries")).json()
+            assert recent[-1]["request_id"] == rid
+            entry = await (await client.get(f"/debug/trace/{rid}")).json()
+            root = entry["root"]
+            assert root["name"] == "sql"
+            top = [c["name"] for c in root["children"]]
+            assert top == ["parse_plan", "admission_wait", "execute"]
+            wait, execute = root["children"][1:]
+            # the class follows the shape's cost history (a slow host makes
+            # the build statement "expensive"): any class, nobody queued
+            assert wait["attrs"]["queued"] == 0
+            assert wait["attrs"]["class"] == execute["attrs"]["admission"]
+            stages = [c["name"] for c in execute["children"]]
+            assert stages == [
+                "lane_wait", "prepare", "upload", "dispatch", "device_wait",
+                "fetch", "assemble",
+            ]
+            assert all(
+                c["parent_id"] == execute["span_id"]
+                for c in execute["children"]
+            )
+            by_name = {c["name"]: c for c in execute["children"]}
+            assert by_name["prepare"]["attrs"] == {"cache": "hit", "rows": 64}
+            assert by_name["upload"]["attrs"] == {"selective": False}
+            dispatch = by_name["dispatch"]["attrs"]
+            assert dispatch["kernel"] == "cached_packed"
+            assert dispatch["program"] == "cached_scan_" + dispatch["impl"]
+            assert by_name["fetch"]["attrs"]["bytes"] > 0
+            assert by_name["assemble"]["attrs"] == {"rows": 4}
+            # root_ms == sum of non-root exclusive + untracked, still
+            agg = ProfileAggregator()
+            agg.fold(rid, root, route="query", shape="s")
+            rows = {r["path"]: r for r in agg.list()}
+            assert "sql/execute/device_wait" in rows
+            non_root = sum(
+                r["exclusive_ms"] for p, r in rows.items() if "/" in p
+            )
+            assert non_root == pytest.approx(root["duration_ms"], abs=1e-6)
+            assert f"sql/{UNTRACKED}" in rows
+            # the handler's root: folded, and not in the /debug/trace ring
+            assert profile.flush(5.0)
+            prof = (await (await client.get(
+                "/debug/profile?path=http_sql&route=http"
+            )).json())["profile"]
+            paths = {r["path"]: r for r in prof}
+            assert set(paths) == {
+                "http_sql", "http_sql/accept", "http_sql/handle",
+                "http_sql/handle/rows", "http_sql/encode",
+                f"http_sql/{UNTRACKED}",
+            }
+            assert all(r["last_trace_id"] == rid for r in paths.values())
+            assert not any(
+                t["name"] == "http_sql" for t in TRACE_STORE.list()
+            )
+
+        with_client(body)
+
+    def test_write_path_has_a_trace_root(self):
+        """POST /write opens the ``write`` root, so the engine's write
+        spans are recorded on the HTTP write path and the ingest plane
+        has profile rows."""
+        from horaedb_tpu.obs import profile
+        from horaedb_tpu.utils.tracectx import TRACE_STORE
+
+        async def body(client):
+            await client.post("/sql", json={"query":
+                "CREATE TABLE wr (h string TAG, v double, ts timestamp KEY)"})
+            resp = await client.post("/write", json={
+                "table": "wr", "rows": [{"h": "a", "v": 1.0, "ts": 7}]})
+            assert (await resp.json())["affected_rows"] == 1
+            newest = TRACE_STORE.list()[0]
+            assert newest["name"] == "write"
+            root = TRACE_STORE.get(newest["trace_id"])["root"]
+            group = [c for c in root["children"] if c["name"] == "write_group"]
+            assert group and any(
+                c["name"] == "memtable_write" for c in group[0]["children"]
+            )
+            assert profile.flush(5.0)
+            prof = (await (await client.get(
+                "/debug/profile?path=write&route=ingest"
+            )).json())["profile"]
+            mine = {r["path"] for r in prof if r["shape"] == "insert wr"}
+            assert {"write", "write/write_group"} <= mine
+
+        with_client(body)
+
     def test_children_bounded(self):
         from horaedb_tpu.utils.tracectx import (
             MAX_CHILDREN, finish_trace, span, start_trace,
@@ -1133,11 +1298,14 @@ class TestMetricsNameLint:
         if "kernel_compile" not in EVENT_KINDS:
             missing.append("kernel_compile: not in EVENT_KINDS")
         for knob in (
-            "HORAEDB_DEVICE_TELEMETRY", "HORAEDB_DEVICE_SAMPLE",
-            "HORAEDB_DEVICE_SLOW_MS", "HORAEDB_DEVICE_COST_ANALYSIS",
+            "HORAEDB_DEVICE_TELEMETRY", "HORAEDB_DEVICE_COST_ANALYSIS",
         ):
             if f"`{knob}`" not in wdocs:
                 missing.append(f"{knob}: undocumented in docs/WORKLOAD.md")
+        # the sampling knobs went with the sampling: every dispatch is timed
+        for knob in ("HORAEDB_DEVICE_" + "SAMPLE", "HORAEDB_DEVICE_" + "SLOW_MS"):
+            if knob in wdocs or knob in docs:
+                missing.append(f"{knob}: retired, still documented")
         assert not missing, missing
 
     def test_engine_families_live_after_flush(self, tmp_path):

@@ -141,12 +141,19 @@ PACKED = {
 
 @pytest.mark.parametrize("case", sorted(PACKED))
 def test_cached_packed_compiles(compile_for, case):
-    from horaedb_tpu.ops.scan_agg import cached_scan_agg_packed
+    from horaedb_tpu.ops.scan_agg import (
+        cached_scan_agg_packed,
+        packed_program_name,
+    )
 
     args, static = PACKED[case]
-    mem = compile_for(cached_scan_agg_packed, args, **static).memory_analysis()
+    compiled = compile_for(cached_scan_agg_packed, args, **static)
     # HBM beyond the resident columns stays a small share of the 16 GB
+    mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < (1 << 30), mem
+    # what a device trace's ``XLA Modules`` line will call it
+    name = packed_program_name(static["segment_impl"], static["selective"])
+    assert f"HloModule jit_{name}," in compiled.as_text()
 
 
 def test_raw_topk_compiles(compile_for):
@@ -216,6 +223,58 @@ def test_sharded_steps_compile_on_four_chips(mesh4):
 
 
 # ---- CPU tests: the policy and the repair the compiles above rest on ------
+
+# (segment impl, selective) -> the program's documented name
+# (docs/OBSERVABILITY.md, "Names on the device")
+PROGRAM_NAMES = {
+    ("single", False): "cached_scan_single",
+    ("single", True): "cached_scan_single_sel",
+    ("mxu", False): "cached_scan_mxu",
+    ("mxu", True): "cached_scan_mxu_sel",
+    ("scatter", False): "cached_scan_scatter",
+    ("scatter", True): "cached_scan_scatter_sel",
+    ("hash", False): "cached_scan_hash",
+    ("hash", True): "cached_scan_hash_sel",
+}
+
+
+@pytest.mark.parametrize("impl,selective", sorted(PROGRAM_NAMES))
+def test_named_program_lowers_under_its_name(monkeypatch, impl, selective):
+    """Each (segment impl, selective) is a jitted program of its own,
+    named as documented, and its stages carry their ``named_scope`` into
+    the HLO metadata (what a device trace shows for ``fusion.N``)."""
+    import jax
+    import jax.numpy as jnp
+
+    from horaedb_tpu.ops import scan_agg
+
+    monkeypatch.delenv("HORAEDB_SEGMENT_IMPL", raising=False)
+    n, s, m = 1024, 7, 64
+    n_groups, n_buckets = (1, 1) if impl == "single" else (8, 16)
+    series = (jax.ShapeDtypeStruct((n // 16 + 1,), jnp.uint32),
+              jax.ShapeDtypeStruct((n // 128,), jnp.int32))  # ("delta", 1)
+    ts = (jax.ShapeDtypeStruct((n * 9 // 32 + 1,), jnp.uint32),
+          jax.ShapeDtypeStruct((512,), jnp.int32))  # ("dict", 9)
+    value = (jax.ShapeDtypeStruct((n * 7 // 32 + 1,), jnp.uint32),
+             jax.ShapeDtypeStruct((128,), jnp.float32))  # ("dict", 7, True)
+    lowered = scan_agg.cached_scan_agg_packed.lower(
+        series, ts, (value, value),
+        jax.ShapeDtypeStruct((2 * (s + 1),), jnp.int32),
+        jax.ShapeDtypeStruct((1 + 4 + (m if selective else 0),), jnp.int32),
+        n_groups=n_groups, n_buckets=n_buckets, n_agg_fields=2,
+        numeric_filters=((0, 4),), need_minmax=True, segment_impl=impl,
+        hash_slots=0, selective=selective,
+        value_layouts=(("dict", 7, True),) * 2,
+        ts_layout=("dict", 9), series_layout=("delta", 1),
+    )
+    name = PROGRAM_NAMES[impl, selective]
+    assert scan_agg.packed_program_name(impl, selective) == name
+    text = lowered.as_text(debug_info=True)
+    assert f"module @jit_{name} " in text
+    for scope in ("decode_series", "decode_ts", "decode_values", "filter",
+                  "segment_" + impl, "pack"):
+        assert f"jit({name})/{scope}/" in text, scope
+
 
 # Step 2's outcome on this tree at N = 2^21 with min/max wanted, per
 # (impl, n_seg): True compiled for the described v5e, False refused.
