@@ -16,7 +16,7 @@ the boundary where that impedance is resolved, all in vectorized numpy:
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Optional, Sequence
+from typing import NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -203,9 +203,12 @@ def build_padded_batch(
 # how much of the working set gets device-path serving. These codecs trade
 # a few register-level ops per row for 4-8x fewer HBM bytes:
 #
-# - ``pack_bits``/``unpack_bits`` — a uint32 word stream holding fixed-width
-#   codes (1..16 bits). The device unpack is random-access (any gather index
-#   works), so the same stream serves full scans AND decode-on-gather.
+# - ``pack_bits`` — a uint32 word stream holding fixed-width codes (1..16
+#   bits). One stream, two device unpacks: a full scan reads it by its
+#   static structure (``unpack_bits_all``: transposes and constant shifts,
+#   no index), decode-on-gather reads the rows an index picks
+#   (``unpack_bits``: two gathers). On a v5e a gather costs 7-9 ns per
+#   result row whatever it reads, so the gather form is for M << N rows only.
 # - ``dict_encode`` — sorted-dictionary encoding for low-cardinality
 #   columns: bit-packed codes + a small pow2-padded dictionary. Sorted
 #   dictionaries let the executor pre-translate comparison literals into
@@ -280,8 +283,11 @@ def unpack_bits(words, width: int, idx):
     """Device random-access unpack: codes at row positions ``idx``.
 
     ``words`` is the uint32 stream (with safety word); ``idx`` any int32
-    index array. Two gathers + shifts, all in registers — HBM traffic is
-    the packed words, never a decoded column.
+    index array. Two gathers of ``len(idx)`` rows + shifts: the form for a
+    picked subset (the ``_sel`` programs' 4096 rows take 0.03-0.11 ms a
+    gather on a v5e). Never for all rows in order — each gather took 60-72
+    ms over 2^23 rows there (PERF.md, PR 25); ``unpack_bits_all`` reads
+    the same stream with no gather.
     """
     p = idx.astype(jnp.uint32) * jnp.uint32(width)
     wi = (p >> 5).astype(jnp.int32)
@@ -292,6 +298,35 @@ def unpack_bits(words, width: int, idx):
         sh == 0, jnp.uint32(0), words[wi + 1] << ((jnp.uint32(32) - sh) & jnp.uint32(31))
     )
     return (lo | hi) & jnp.uint32((1 << width) - 1)
+
+
+def unpack_bits_all(words, width: int, n_rows: int):
+    """Device full-scan unpack: codes of rows ``0 .. n_rows - 1``, in order.
+
+    No index and no gather: 32 consecutive codes of ``width`` bits fill
+    exactly ``width`` words, so the stream is a ``(n_rows / 32, width)``
+    matrix and code ``j`` of a matrix row sits at a STATIC word and shift.
+    The matrix is transposed first, so that each of its ``width`` word
+    columns is one lane-dense vector: the 32 code vectors are shifts of
+    those by constants, and one transpose back puts them in row order.
+    On a v5e that is 1.1-1.7 ms for 2^23 rows at every width, against 133
+    ms for the two gathers of ``unpack_bits`` over ``arange``; slicing the
+    columns out of the untransposed matrix took 5.5-9.3 ms, and a
+    broadcast of each word over its codes (widths that divide 32) 1.1 ms
+    at width 1 but 6.9 at 16 (my chip runs, PR 26).
+    Bit-exact with ``unpack_bits(words, width, arange(n_rows))``;
+    ``n_rows`` is a multiple of 32 (every shape bucket is).
+    """
+    mask = jnp.uint32((1 << width) - 1)
+    cols = words[: n_rows * width // 32].reshape(n_rows // 32, width).T
+    codes = []
+    for j in range(32):
+        wi, sh = divmod(j * width, 32)
+        code = cols[wi] >> jnp.uint32(sh)
+        if sh + width > 32:
+            code = code | (cols[wi + 1] << jnp.uint32(32 - sh))
+        codes.append(code & mask)
+    return jnp.stack(codes).T.reshape(n_rows)
 
 
 @dataclass(frozen=True)
@@ -383,33 +418,86 @@ def delta_for_encode(arr: np.ndarray, max_bits: int) -> Optional[DeltaEncoded]:
 # ---- device-side layout decode (shared by scan_agg / scan_topk) -----------
 
 
-def _iota(n_rows: int):
-    return jnp.arange(n_rows, dtype=jnp.int32)
+def _unpack(words, width: int, n_rows: int, idx):
+    """Codes of all rows by the stream's structure, or of ``idx``'s rows by
+    gather: each decode below takes the form its caller's program needs."""
+    if idx is None:
+        return unpack_bits_all(words, width, n_rows)
+    return unpack_bits(words, width, idx)
 
 
-def decode_series(parts, layout, n_rows: int, idx=None):
+def _delta_decode(words, base, width: int, n_rows: int, idx):
+    """base-of-block + offset. A full scan broadcasts each base over its
+    FOR block (a reshape, not the N-row gather ``base[row >> 7]`` is)."""
+    if idx is not None:
+        return base[idx >> _FOR_SHIFT] + unpack_bits(words, width, idx).astype(jnp.int32)
+    off = unpack_bits_all(words, width, n_rows).astype(jnp.int32)
+    return (base[:, None] + off.reshape(-1, FOR_BLOCK)).reshape(n_rows)
+
+
+class BlockedSeries(NamedTuple):
+    """A full scan's series codes left as their FOR parts: the code of row
+    ``r`` is ``base[r >> 7] + offsets[r]``, and nothing adds them up —
+    ``lookup_series`` reads per-series tables through the blocks."""
+
+    offsets: jax.Array  # uint32[N], each < 2**width
+    base: jax.Array  # int32[N / FOR_BLOCK]
+    width: int
+
+
+# The widest series layout that ``lookup_series`` reads per block. A block
+# of a ("delta", w) stream holds series base .. base + 2**w - 1 only, so a
+# table lookup is a gather of N / 128 * 2**w candidates plus 2**w selects
+# a row instead of an N-row gather: 1/64 of the gathered elements at the
+# w = 1 of long series, 1/8 at w = 4. The selects are vector work (a pass
+# over 2^23 rows is ~0.1 ms on a v5e); gathered elements cost 7-9 ns each.
+# Past 1/8 the gain is small and the select chain long: the row gather stays.
+BLOCK_LOOKUP_MAX_WIDTH = 4
+
+
+def lookup_series(table, series):
+    """``table[series code]`` for every row; ``series`` is an int32 code
+    array or a ``BlockedSeries``. ``table`` has one entry per series plus
+    the pad series' last one, which also catches candidates past the end."""
+    if not isinstance(series, BlockedSeries):
+        return table[series]
+    offsets = series.offsets.reshape(-1, FOR_BLOCK)
+    candidates = jnp.minimum(
+        series.base[:, None] + jnp.arange(1 << series.width, dtype=jnp.int32),
+        table.shape[0] - 1,
+    )
+    picked = table[candidates]  # (N / 128, 2**w): the only gather
+    out = picked[:, :1]
+    for k in range(1, 1 << series.width):
+        out = jnp.where(offsets == k, picked[:, k : k + 1], out)
+    return out.reshape(-1)  # width >= 1: the selects broadcast it over the block
+
+
+def decode_series(parts, layout, n_rows: int, idx=None, blocked: bool = False):
     """int32 series codes under ``layout`` — all rows (idx=None) or a gather.
 
     ``parts`` is the device part tuple: ("raw",) -> (codes,);
-    ("delta", w) -> (words, base).
+    ("delta", w) -> (words, base). ``blocked`` lets a full scan of a
+    narrow delta layout come back as a ``BlockedSeries`` (for callers that
+    only look tables up by series: ``lookup_series``).
     """
     if layout[0] == "raw":
         return parts[0] if idx is None else parts[0][idx]
     words, base = parts
-    ix = _iota(n_rows) if idx is None else idx
-    return base[ix >> _FOR_SHIFT] + unpack_bits(words, layout[1], ix).astype(jnp.int32)
+    if blocked and idx is None and layout[1] <= BLOCK_LOOKUP_MAX_WIDTH:
+        return BlockedSeries(unpack_bits_all(words, layout[1], n_rows), base, layout[1])
+    return _delta_decode(words, base, layout[1], n_rows, idx)
 
 
 def decode_ts(parts, layout, n_rows: int, idx=None):
     """int32 relative timestamps under ``layout``."""
     if layout[0] == "raw":
         return parts[0] if idx is None else parts[0][idx]
-    ix = _iota(n_rows) if idx is None else idx
     if layout[0] == "dict":
         words, dictionary = parts
-        return dictionary[unpack_bits(words, layout[1], ix)]
+        return dictionary[_unpack(words, layout[1], n_rows, idx)]
     words, base = parts
-    return base[ix >> _FOR_SHIFT] + unpack_bits(words, layout[1], ix).astype(jnp.int32)
+    return _delta_decode(words, base, layout[1], n_rows, idx)
 
 
 def decode_value(parts, layout, n_rows: int, idx=None):
@@ -423,7 +511,7 @@ def decode_value(parts, layout, n_rows: int, idx=None):
         arr = parts[0] if idx is None else parts[0][idx]
         return arr.astype(jnp.float32)
     words, dictionary = parts
-    codes = unpack_bits(words, layout[1], _iota(n_rows) if idx is None else idx)
+    codes = _unpack(words, layout[1], n_rows, idx)
     if len(layout) > 2 and not layout[2]:
         return codes.astype(jnp.float32)
     return dictionary[codes]
@@ -441,16 +529,20 @@ def _as_parts(x):
 
 
 def decode_layouts(
-    series_codes, ts_rel, values, series_layout, ts_layout, value_layouts, idx=None
+    series_codes, ts_rel, values, series_layout, ts_layout, value_layouts,
+    idx=None, blocked_series: bool = False,
 ):
     """Reconstruct kernel inputs from their resident layouts.
 
     With ``idx`` given, only those row positions decode (decode-on-gather:
     the selective path ships an M-row index and the device reads M encoded
-    rows, not N). Raw inputs pass through untouched — legacy callers
-    (dist paths, direct tests) never pay for the generality. Encoded
-    values come back as a LIST of per-field rows; the kernels stack only
-    what they aggregate.
+    rows, not N); without, every stream unpacks by its static structure
+    and no row-sized gather is issued for it. Raw inputs pass through
+    untouched — legacy callers (dist paths, direct tests) never pay for
+    the generality. Encoded values come back as a LIST of per-field rows;
+    the kernels stack only what they aggregate. ``blocked_series``: the
+    caller reads the series codes through ``lookup_series`` alone, so a
+    full scan may hand them over as a ``BlockedSeries``.
     """
     if (
         series_layout[0] == "raw"
@@ -470,7 +562,7 @@ def decode_layouts(
     n_rows = layout_rows(sc_parts, series_layout)
     # the scopes name the stages in a device trace (the ops are fusion.N)
     with jax.named_scope("decode_series"):
-        sc = decode_series(sc_parts, series_layout, n_rows, idx)
+        sc = decode_series(sc_parts, series_layout, n_rows, idx, blocked_series)
     with jax.named_scope("decode_ts"):
         tr = decode_ts(ts_parts, ts_layout, n_rows, idx)
     layouts = value_layouts or tuple(("raw",) for _ in values)

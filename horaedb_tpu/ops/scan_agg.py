@@ -36,7 +36,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..utils.env import env_int
-from .encoding import PaddedBatch, decode_layouts as _decode_layouts, next_pow2
+from .encoding import (
+    PaddedBatch,
+    decode_layouts as _decode_layouts,
+    lookup_series,
+    next_pow2,
+)
 
 AGG_OPS = ("count", "sum", "min", "max", "avg")
 
@@ -412,23 +417,30 @@ def cached_scan_agg_body(
 
     Compressed layouts (ISSUE 19): when the layout descriptors say so,
     ``series_codes``/``ts_rel`` arrive as encoded part tuples and
-    ``values`` as a tuple of per-field part tuples. The decode below runs
-    in registers at the top of the fused program — HBM traffic is the
-    encoded bytes, and filter-only dict fields compare raw codes against
-    host-pre-translated literals without ever touching the dictionary.
+    ``values`` as a tuple of per-field part tuples. This body is always a
+    scan of ALL its rows (the ``_sel`` programs gather and decode their
+    picked rows first and arrive here raw), so the decode below reads each
+    packed stream by its static structure — transposes and constant shifts —
+    and the per-series tables through the FOR blocks of the series codes
+    (``lookup_series``): no gather of N rows, which a v5e runs at 7-9 ns a
+    row whatever is gathered (four of them were 271 of this program's 272
+    ms at 2^23 rows; PERF.md, PR 25). HBM traffic is the encoded bytes, and
+    filter-only dict fields compare raw codes against host-pre-translated
+    literals without ever touching the dictionary.
 
     Pure body: also the per-shard program when the cache is sharded over a
     mesh (parallel/dist_agg.make_cached_dist_scan_agg wraps it with
     psum/pmin/pmax collectives — that path always runs the raw layout).
     """
-    series_codes, ts_rel, values = _decode_layouts(
-        series_codes, ts_rel, values, series_layout, ts_layout, value_layouts
+    series, ts_rel, values = _decode_layouts(
+        series_codes, ts_rel, values, series_layout, ts_layout, value_layouts,
+        blocked_series=True,
     )
     with jax.named_scope("filter"):
-        mask = allowed_series[series_codes]
+        mask = lookup_series(allowed_series, series)
         mask = mask & (ts_rel >= lo_rel) & (ts_rel < hi_rel)
         bucket = jnp.clip((ts_rel - t0_rel) // bucket_ms, 0, n_buckets - 1).astype(jnp.int32)
-        group_codes = group_of_series[series_codes]
+        group_codes = lookup_series(group_of_series, series)
     if not isinstance(values, (list, tuple)):
         # bf16-resident value columns (HORAEDB_CACHE_DTYPE) upcast here:
         # accumulation always runs in f32 (no-op when already f32)

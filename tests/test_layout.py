@@ -11,12 +11,20 @@ import pytest
 
 import horaedb_tpu
 from horaedb_tpu.ops.encoding import (
+    BLOCK_LOOKUP_MAX_WIDTH,
     FOR_BLOCK,
+    BlockedSeries,
     DictEncoded,
+    decode_series,
+    decode_ts,
+    decode_value,
     delta_for_encode,
     dict_encode,
+    lookup_series,
     pack_bits,
     unpack_bits,
+    unpack_bits_all,
+    unpack_bits_host,
 )
 
 
@@ -49,19 +57,81 @@ def warm(db, sql):
     return db.execute(sql)
 
 
+def _sorted_codes(n, run, step=1):
+    """Non-decreasing int32 codes: a new series every ``run`` rows, codes
+    ``step`` apart (a 128-row block then spans 128 / run * step of them)."""
+    return (np.arange(n, dtype=np.int32) // run) * step
+
+
 class TestCodecs:
-    def test_pack_unpack_roundtrip_all_widths(self):
+    @pytest.mark.parametrize("n", [128, 4096, 1 << 16])
+    @pytest.mark.parametrize("width", range(1, 17))
+    def test_pack_unpack_roundtrip_all_widths(self, width, n):
+        """Both device unpacks — the full scan's static form and the
+        gather form over every row — are bit-equal to the host mirror."""
         import jax.numpy as jnp
 
-        rng = np.random.default_rng(7)
-        for width in range(1, 17):
-            n = 256
-            vals = rng.integers(0, 1 << width, size=n).astype(np.uint32)
-            words = pack_bits(vals, width)
-            got = unpack_bits(
-                jnp.asarray(words), width, jnp.arange(n, dtype=jnp.int32)
-            )
-            assert np.array_equal(np.asarray(got), vals.astype(np.int32)), width
+        rng = np.random.default_rng(7 * width + n)
+        vals = rng.integers(0, 1 << width, size=n).astype(np.uint32)
+        vals[[0, -1]] = (1 << width) - 1  # all ones at both ends
+        words = pack_bits(vals, width)
+        assert np.array_equal(unpack_bits_host(words, width, n), vals)
+        dev = jnp.asarray(words)
+        static = unpack_bits_all(dev, width, n)
+        gathered = unpack_bits(dev, width, jnp.arange(n, dtype=jnp.int32))
+        assert static.dtype == gathered.dtype == jnp.uint32
+        assert np.array_equal(np.asarray(static), vals)
+        assert np.array_equal(np.asarray(gathered), vals)
+
+    @pytest.mark.parametrize(
+        "layout",
+        [("delta", 1), ("delta", 3), ("delta", 8), ("delta", 13)],
+        ids=lambda l: f"delta{l[1]}",
+    )
+    @pytest.mark.parametrize("decode", [decode_series, decode_ts])
+    def test_delta_decode_full_scan_equals_gather(self, decode, layout):
+        import jax.numpy as jnp
+
+        n = 8 * FOR_BLOCK
+        rng = np.random.default_rng(layout[1])
+        offsets = rng.integers(0, 1 << layout[1], size=(n // FOR_BLOCK, FOR_BLOCK))
+        offsets[:, 0], offsets[:, -1] = 0, (1 << layout[1]) - 1
+        base = rng.integers(-50, 1000, size=(n // FOR_BLOCK, 1))
+        vals = (base + offsets).ravel().astype(np.int32)
+        enc = delta_for_encode(vals, 16)
+        assert enc.width == layout[1]
+        parts = (jnp.asarray(enc.words), jnp.asarray(enc.base))
+        full = decode(parts, layout, n)
+        picked = decode(parts, layout, n, jnp.arange(n, dtype=jnp.int32))
+        assert np.array_equal(np.asarray(full), vals)
+        assert np.array_equal(np.asarray(picked), vals)
+
+    @pytest.mark.parametrize("card", [2, 100, 300, 5000])
+    def test_dict_decode_full_scan_equals_gather(self, card):
+        """``dict`` timestamps and values, and a filter-only field's bare
+        codes: idx=None against idx=arange, bit for bit."""
+        import jax.numpy as jnp
+
+        n = 8192
+        rng = np.random.default_rng(card)
+        idx = jnp.arange(n, dtype=jnp.int32)
+        ts = rng.integers(0, card, n).astype(np.int32) * 10_000
+        ts[:card] = np.arange(card) * 10_000
+        enc = dict_encode(ts, 1 << 16)
+        parts = (jnp.asarray(enc.words), jnp.asarray(enc.dictionary))
+        layout = ("dict", enc.width)
+        assert np.array_equal(np.asarray(decode_ts(parts, layout, n)), ts)
+        assert np.array_equal(np.asarray(decode_ts(parts, layout, n, idx)), ts)
+        vals = (ts / 7).astype(np.float32)
+        enc = dict_encode(vals, 1 << 16)
+        parts = (jnp.asarray(enc.words), jnp.asarray(enc.dictionary))
+        for full_decode in (True, False):
+            layout = ("dict", enc.width, full_decode)
+            want = vals if full_decode else np.searchsorted(
+                enc.dict_host, vals).astype(np.float32)
+            full = np.asarray(decode_value(parts, layout, n))
+            picked = np.asarray(decode_value(parts, layout, n, idx))
+            assert full.tobytes() == picked.tobytes() == want.tobytes()
 
     def test_dict_encode_bit_exact_roundtrip(self):
         import jax.numpy as jnp
@@ -355,3 +425,178 @@ class TestMemtableLayoutHandoff:
             assert low_cardinality_hint("t", "v") == 8
         finally:
             clear_hints()
+
+
+def _stablehlo_gathers(lowered):
+    """Element counts of every ``stablehlo.gather`` result of a lowering."""
+    import math
+    import re
+
+    shapes = re.findall(
+        r'"stablehlo\.gather".*?-> tensor<((?:\d+x)*)[a-z]+\d+>',
+        lowered.as_text(),
+    )
+    return [math.prod(int(d) for d in s.split("x") if d) for s in shapes]
+
+
+class TestFullScanNoRowGather:
+    """PR 26: a full scan reads its packed streams by their static
+    structure and the per-series tables through the FOR blocks. On a v5e
+    a gather of N rows cost 7-9 ns a row (four of them were 271 of the
+    272 ms of ``cached_scan_single`` at 2^23 rows), so the lowering itself
+    is guarded: plain ``jax.jit(...).lower``, no described chip."""
+
+    N, S, M = 4096, 7, 64
+
+    def _lower(self, impl, selective, packed_streams=False):
+        import jax
+        import jax.numpy as jnp
+
+        from horaedb_tpu.ops.scan_agg import cached_scan_agg_packed
+
+        n, sds = self.N, jax.ShapeDtypeStruct
+        series = (sds((n // 32 + 1,), jnp.uint32), sds((n // 128,), jnp.int32))
+        if packed_streams:
+            ts = (sds((n * 13 // 32 + 1,), jnp.uint32), sds((8192,), jnp.int32))
+            value = (sds((n * 7 // 32 + 1,), jnp.uint32), sds((128,), jnp.float32))
+            values, ts_layout = (value, value), ("dict", 13)
+            value_layouts = (("dict", 7, True), ("dict", 7, False))
+        else:  # what ScanCache builds for the benchmark's cpu table
+            ts, ts_layout = (sds((n,), jnp.int32),), ("raw",)
+            values, value_layouts = sds((2, n), jnp.float32), (("raw",),) * 2
+        n_groups, n_buckets = (1, 1) if impl == "single" else (8, 16)
+        return cached_scan_agg_packed.lower(
+            series, ts, values, sds((2 * (self.S + 1),), jnp.int32),
+            sds((1 + 4 + (self.M if selective else 0),), jnp.int32),
+            n_groups=n_groups, n_buckets=n_buckets,
+            n_agg_fields=1 if packed_streams else 2,
+            numeric_filters=((1 if packed_streams else 0, 4),),
+            need_minmax=True, segment_impl=impl, hash_slots=0,
+            selective=selective, value_layouts=value_layouts,
+            ts_layout=ts_layout, series_layout=("delta", 1),
+        )
+
+    @pytest.mark.parametrize("impl", ["single", "scatter", "mxu"])
+    def test_full_scan_lowers_without_row_sized_gather(self, impl):
+        gathers = _stablehlo_gathers(self._lower(impl, selective=False))
+        # the allow-list (and the group map, where groups exist) through
+        # two candidate series a block; nothing of N elements
+        assert gathers == [self.N // FOR_BLOCK * 2] * (1 if impl == "single" else 2)
+
+    @pytest.mark.parametrize("impl", ["single", "scatter", "mxu"])
+    def test_full_scan_of_packed_streams_gathers_only_dictionaries(self, impl):
+        """Every stream packed (ts ``dict`` 13, an aggregated and a
+        filter-only ``dict`` 7 value): the unpacks add no gather; what
+        stays row-sized are the two dictionary lookups themselves."""
+        gathers = _stablehlo_gathers(
+            self._lower(impl, selective=False, packed_streams=True)
+        )
+        assert sorted(g for g in gathers if g >= self.N) == [self.N] * 2
+        assert len(gathers) == 2 + (1 if impl == "single" else 2)
+
+    @pytest.mark.parametrize("packed_streams", [False, True], ids=["raw", "packed"])
+    @pytest.mark.parametrize("impl", ["single", "scatter", "mxu"])
+    def test_selective_program_gathers_as_before(self, impl, packed_streams):
+        """The ``_sel`` programs decode M picked rows with the index: their
+        gathers are those of the parent of PR 26, all of M elements."""
+        gathers = _stablehlo_gathers(
+            self._lower(impl, selective=True, packed_streams=packed_streams)
+        )
+        assert set(gathers) == {self.M}
+        tables = 1 if impl == "single" else 2  # allow-list (+ group map)
+        # series words x2 + base, then ts and two values: raw 1 gather
+        # each, dict words x2 + dictionary (the filter-only value: no
+        # dictionary)
+        assert len(gathers) == 3 + (3 + 3 + 2 if packed_streams else 3) + tables
+
+    @pytest.mark.parametrize("width,run", [(1, 300), (3, 19), (4, 9)])
+    def test_lookup_series_matches_row_lookup(self, width, run):
+        import jax.numpy as jnp
+
+        assert width <= BLOCK_LOOKUP_MAX_WIDTH
+        n, rng = 4096, np.random.default_rng(width)
+        codes = np.minimum(_sorted_codes(n, run), (n - 37) // run)
+        n_series = int(codes.max())  # the last code is the pad series
+        enc = delta_for_encode(codes, 8)
+        assert enc.width == width
+        parts = (jnp.asarray(enc.words), jnp.asarray(enc.base))
+        series = decode_series(parts, ("delta", width), n, blocked=True)
+        assert isinstance(series, BlockedSeries)
+        for table in (
+            rng.integers(0, 1 << 20, n_series + 1).astype(np.int32),
+            rng.random(n_series + 1) > 0.5,
+        ):
+            got = lookup_series(jnp.asarray(table), series)
+            assert np.array_equal(np.asarray(got), table[codes])
+
+    def test_wide_series_layout_keeps_the_row_lookup(self):
+        """Past ``BLOCK_LOOKUP_MAX_WIDTH`` the candidates of a block are no
+        longer few: the codes decode whole and tables are read per row."""
+        import jax.numpy as jnp
+
+        n = 4096
+        codes = _sorted_codes(n, 5)  # 26-27 series a block: 5 bits
+        enc = delta_for_encode(codes, 8)
+        assert enc.width == BLOCK_LOOKUP_MAX_WIDTH + 1
+        parts = (jnp.asarray(enc.words), jnp.asarray(enc.base))
+        series = decode_series(parts, ("delta", enc.width), n, blocked=True)
+        assert not isinstance(series, BlockedSeries)
+        assert np.array_equal(np.asarray(series), codes)
+        table = jnp.arange(int(codes.max()) + 1, dtype=jnp.int32) * 3
+        assert np.array_equal(np.asarray(lookup_series(table, series)), codes * 3)
+
+
+ALLOW_LISTS = {
+    "none": lambda rng, s: np.zeros(s + 1, bool),
+    "half": lambda rng, s: np.append(rng.random(s) > 0.5, False),
+    "all": lambda rng, s: np.ones(s + 1, bool),  # the pad series too
+    "pad-masked": lambda rng, s: np.append(np.ones(s, bool), False),
+}
+
+
+class TestBlockLookupKernelEquivalence:
+    """The cached kernel over encoded series codes against the same
+    kernel over the raw codes (what ``HORAEDB_CACHE_LAYOUT=raw`` serves):
+    synthetic resident columns whose series are short enough for offset
+    widths 1, 3 (looked up per block) and 8 (per row), with the block
+    that straddles the last series and the pad rows."""
+
+    @pytest.mark.parametrize("allow_kind", sorted(ALLOW_LISTS))
+    @pytest.mark.parametrize("impl", ["single", "scatter", "mxu"])
+    @pytest.mark.parametrize("width,run,step", [(1, 300, 1), (3, 19, 1), (8, 1, 2)])
+    def test_encoded_series_equal_raw(self, width, run, step, impl, allow_kind):
+        import jax.numpy as jnp
+
+        from horaedb_tpu.ops.scan_agg import cached_scan_agg
+
+        n, n_valid = 4096, 29 * FOR_BLOCK + 87  # block 29 straddles the pad
+        rng = np.random.default_rng(width * 100 + len(allow_kind))
+        codes = _sorted_codes(n, run, step)
+        n_series = int(codes[n_valid - 1]) + 1
+        codes[n_valid:] = n_series  # pad rows: the pad series
+        enc = delta_for_encode(codes, 8)
+        assert enc is not None and enc.width == width
+        ts = rng.integers(0, 64_000, n).astype(np.int32)
+        vals = rng.normal(size=(2, n)).astype(np.float32)
+        allow = ALLOW_LISTS[allow_kind](rng, n_series)
+        n_groups, n_buckets = (1, 1) if impl == "single" else (8, 16)
+        groups = rng.integers(0, n_groups, n_series + 1).astype(np.int32)
+        args = (
+            jnp.asarray(ts), jnp.asarray(vals), jnp.asarray(groups),
+            jnp.asarray(allow), jnp.asarray(np.float32([-0.5])),
+            jnp.int32(1000), jnp.int32(60_000), jnp.int32(0), jnp.int32(4000),
+        )
+        static = dict(
+            n_groups=n_groups, n_buckets=n_buckets, n_agg_fields=2,
+            numeric_filters=((1, 4),), need_minmax=True, segment_impl=impl,
+        )
+        raw = cached_scan_agg(jnp.asarray(codes), *args, **static)
+        encoded = cached_scan_agg(
+            (jnp.asarray(enc.words), jnp.asarray(enc.base)), *args, **static,
+            series_layout=("delta", width),
+            value_layouts=(("raw",), ("raw",)),
+        )
+        m = allow[codes] & (ts >= 1000) & (ts < 60_000) & (vals[1] > -0.5)
+        assert int(np.asarray(raw[0]).sum()) == int(m.sum())
+        for a, b in zip(encoded, raw):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
