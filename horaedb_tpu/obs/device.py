@@ -251,6 +251,47 @@ def note_compile(kind: str, key, wall_s: float,
     record_event("kernel_compile", **attrs)
 
 
+def device_free_bytes() -> Optional[int]:
+    """HBM the first local device has left (its allocator's limit less the
+    bytes in use, resident columns included), or None where the backend
+    reports no limit (the CPU): what a program's temporaries must fit."""
+    import jax
+
+    stats = jax.local_devices()[0].memory_stats()
+    if not stats or "bytes_limit" not in stats:
+        return None
+    return int(stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0))
+
+
+def refusal_of(exc: BaseException) -> Optional[str]:
+    """The first line of what the device said when ``exc`` is its refusal
+    of a program for memory (``RESOURCE_EXHAUSTED``: the compiler found
+    no room in HBM for the program's temporaries, or the allocator none
+    for its buffers), else None — any other failure is not a refusal."""
+    import jax
+
+    if not isinstance(exc, jax.errors.JaxRuntimeError):
+        return None
+    text = str(exc).strip()
+    if "RESOURCE_EXHAUSTED" not in text:
+        return None
+    return text.splitlines()[0][:300]
+
+
+def note_refusal(kind: str, impl: str, key, message: str) -> None:
+    """The device refused a dispatch's program: journal the typed
+    ``kernel_refused`` event (trace-linked like ``kernel_compile``) —
+    ``kernel`` (the kind), ``impl``, ``shape`` and the device's first
+    line. The caller marks the impl unusable and serves the request by
+    another route."""
+    from ..utils.events import record_event
+
+    record_event(
+        "kernel_refused", kernel=kind, impl=impl, shape=_shape_of(key),
+        message=message,
+    )
+
+
 def note_compile_cache_hit(kind: str) -> None:
     """A seen shape dispatched again: the compile cache served it."""
     if not device_telemetry_enabled():

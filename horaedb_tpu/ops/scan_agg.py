@@ -67,6 +67,9 @@ _COUNT_CHUNK = 1 << 24
 # hold in HBM (1 B/cell). Unchunked, 2^21 rows x 8192 segments asked the
 # v5e compiler for 16 GB and was refused.
 _MINMAX_MASK_BYTES = 1 << 28
+# Bytes of the row-major (rows, fields) update tile one step of the scatter
+# impl may hold in HBM (``scatter_chunk_rows``).
+_SCATTER_TILE_BYTES = 1 << 25
 
 
 def pinned_segment_impl() -> str:
@@ -269,26 +272,133 @@ def _single_segment_agg(m, agg_vals, need_minmax: bool):
     return counts, sums, mins, maxs
 
 
+def _tile_row_bytes(n_fields: int) -> int:
+    """Bytes a row of a ``(rows, fields)`` f32 tile takes on the TPU, which
+    pads the minor axis to 128 lanes: 512 B for any ``n_fields`` <= 128."""
+    return 4 * 128 * -(-max(n_fields, 1) // 128)
+
+
+def scatter_chunk_rows(n_fields: int) -> int:
+    """Rows one step of the scatter impl takes: the largest power of two whose
+    ``(rows, fields)`` update tile stays under ``_SCATTER_TILE_BYTES``."""
+    rows = max(_SCATTER_TILE_BYTES // _tile_row_bytes(n_fields), 128)
+    return 1 << (rows.bit_length() - 1)
+
+
+def segment_row_chunks(impl: str, n_rows: int, n_seg: int, n_fields: int,
+                       need_minmax: bool) -> int:
+    """How many row chunks ``impl``'s segment reduction runs ``n_rows`` in
+    (1: in one piece) — the host-side mirror of the in-trace cuts, for the
+    ``dispatch`` span's ``chunks`` attribute. ``hash`` is cut where its
+    overflow fallback, the scatter impl, is."""
+    if impl in ("scatter", "hash"):
+        return max(1, -(-n_rows // scatter_chunk_rows(n_fields)))
+    if impl == "mxu" and need_minmax and n_fields:
+        return max(1, -(-n_rows // max(_MINMAX_MASK_BYTES // n_seg, 128)))
+    return 1
+
+
+def segment_temp_bytes(impl: str, n_rows: int, n_seg: int, n_fields: int,
+                       need_minmax: bool) -> int:
+    """HBM ``impl``'s segment reduction needs beside the resident columns, from
+    above: what the policy holds against the device's free memory before it
+    offers the impl (``query/path_router.candidate_kernels``). Every impl
+    keeps a handful of row-length vectors (segment ids, mask, a scatter's
+    sort keys and permutation); the scatter holds one update tile and one
+    accumulator (in and out) per reduction, each padded to 128 lanes; the MXU
+    impl its min/max match mask; the hash impl its probe's row vectors and
+    its overflow fallback, the scatter. ``tests/test_tpu_compile.py`` holds it
+    against the v5e compiler's own accounting at 2^21 and 2^25 rows."""
+    row_vectors = 8 * 4 * n_rows
+    if impl == "single":
+        return row_vectors
+    if impl == "mxu":
+        return row_vectors + (
+            2 * _MINMAX_MASK_BYTES if need_minmax and n_fields else 0
+        )
+    if impl == "hash":
+        row_vectors *= 2
+    reductions = (3 if need_minmax else 1) if n_fields else 0
+    row_bytes = _tile_row_bytes(n_fields)
+    tile = min(n_rows, scatter_chunk_rows(n_fields)) * row_bytes
+    accumulators = 2 * (n_seg + 1) * (4 + reductions * row_bytes)
+    return row_vectors + reductions * tile + accumulators
+
+
 def _scatter_segment_agg(seg_raw, m, agg_vals, n_seg: int, need_minmax: bool):
-    """(counts, sums, mins, maxs) via segment_* scatter ops (CPU/GPU, or
-    large segment counts where O(N*n_seg) matmul work loses to O(N))."""
+    """(counts, sums, mins, maxs) via scatter ops (CPU/GPU, or large segment
+    counts where O(N*n_seg) matmul work loses to O(N)).
+
+    The scatters take their updates row-major, ``(rows, F)``, so the value
+    columns transpose first, and that tile is what the program holds beside
+    the resident columns: 4.3 GB at 2^23 rows, refused by the v5e's compiler
+    at 2^25 (17.2 GB). Above ``scatter_chunk_rows`` rows the scatters
+    therefore run over row chunks in a ``lax.scan`` INTO carried
+    accumulators: the same updates in the same row order as one scatter over
+    all rows, so counts are exact and sums round as before."""
     seg = jnp.where(m, seg_raw, n_seg)  # masked rows land in a dump slot
-    counts = jax.ops.segment_sum(m.astype(jnp.int32), seg, num_segments=n_seg + 1)[:n_seg]
+    n = seg.shape[0]
+    n_fields = 0 if agg_vals is None else agg_vals.shape[0]
+    chunk = scatter_chunk_rows(n_fields)
+    acc = _scatter_zero(n_seg, n_fields, need_minmax,
+                        None if agg_vals is None else agg_vals.dtype)
+    if n <= chunk:
+        acc = _scatter_rows(acc, seg, m, agg_vals, need_minmax)
+    else:
+        n_chunks = -(-n // chunk)
+        pad = n_chunks * chunk - n
+        seg = jnp.pad(seg, (0, pad), constant_values=n_seg)
+        m = jnp.pad(m, (0, pad))
+        if agg_vals is not None:
+            agg_vals = jnp.pad(agg_vals, ((0, 0), (0, pad)))
+
+        def step(acc, i):
+            with jax.named_scope("slice"):
+                s = jax.lax.dynamic_slice_in_dim(seg, i * chunk, chunk)
+                mm = jax.lax.dynamic_slice_in_dim(m, i * chunk, chunk)
+                v = None if agg_vals is None else jax.lax.dynamic_slice_in_dim(
+                    agg_vals, i * chunk, chunk, axis=1
+                )
+            return _scatter_rows(acc, s, mm, v, need_minmax), None
+
+        acc, _ = jax.lax.scan(step, acc, jnp.arange(n_chunks, dtype=jnp.int32))
+    counts = acc[0][:n_seg]
     if agg_vals is None:
         return counts, None, None, None
-    mf = m.astype(agg_vals.dtype)
-    sums = jax.ops.segment_sum((agg_vals * mf).T, seg, num_segments=n_seg + 1)[:n_seg].T
+    sums = acc[1][:n_seg].T
+    if need_minmax:
+        return counts, sums, acc[2][:n_seg].T, acc[3][:n_seg].T
+    return counts, sums, jnp.zeros_like(sums), jnp.zeros_like(sums)
+
+
+def _scatter_zero(n_seg: int, n_fields: int, need_minmax: bool, dtype):
+    """The scatter impl's empty accumulators, dump slot included: counts
+    ``(n_seg + 1,)`` and, per wanted reduction, ``(n_seg + 1, F)``."""
+    acc = [jnp.zeros((n_seg + 1,), jnp.int32)]
+    if n_fields:
+        acc.append(jnp.zeros((n_seg + 1, n_fields), dtype))
+        if need_minmax:
+            big = jnp.asarray(jnp.inf, dtype=dtype)
+            acc.append(jnp.full((n_seg + 1, n_fields), big))
+            acc.append(jnp.full((n_seg + 1, n_fields), -big))
+    return tuple(acc)
+
+
+def _scatter_rows(acc, seg, m, agg_vals, need_minmax: bool):
+    """Fold one run of rows into the accumulators of ``_scatter_zero``."""
+    with jax.named_scope("counts"):
+        out = [acc[0].at[seg].add(m.astype(jnp.int32))]
+    if agg_vals is None:
+        return tuple(out)
+    with jax.named_scope("sums"):
+        out.append(acc[1].at[seg].add((agg_vals * m.astype(agg_vals.dtype)).T))
     if need_minmax:
         big = jnp.asarray(jnp.inf, dtype=agg_vals.dtype)
-        mins = jax.ops.segment_min(
-            jnp.where(m, agg_vals, big).T, seg, num_segments=n_seg + 1
-        )[:n_seg].T
-        maxs = jax.ops.segment_max(
-            jnp.where(m, agg_vals, -big).T, seg, num_segments=n_seg + 1
-        )[:n_seg].T
-    else:
-        mins = maxs = jnp.zeros_like(sums)
-    return counts, sums, mins, maxs
+        with jax.named_scope("mins"):
+            out.append(acc[2].at[seg].min(jnp.where(m, agg_vals, big).T))
+        with jax.named_scope("maxs"):
+            out.append(acc[3].at[seg].max(jnp.where(m, agg_vals, -big).T))
+    return tuple(out)
 
 
 def scan_agg_body(

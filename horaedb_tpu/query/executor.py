@@ -471,7 +471,6 @@ class CachedAggPrep:
     hi_rel: int
     t0_rel: int
     width_i: int
-    kernel_key: tuple
     tag_keys: list
     key_values: tuple
     agg_cols: list
@@ -480,6 +479,21 @@ class CachedAggPrep:
     # static per-field layout descriptors (ISSUE 19) — jit-key fragments:
     # a column re-encoding between preps must not share a traced kernel
     value_layouts: tuple = ()
+    # the router's cardinality estimate, kept for a re-route should the
+    # device refuse the chosen impl's program
+    est_distinct: int = 1
+
+    @property
+    def kernel_key(self) -> tuple:
+        """The static shape of this prep's kernel: the compile-accounting
+        key (and the ``shape`` of a ``kernel_refused`` event)."""
+        spec, entry = self.spec, self.entry
+        return (
+            spec.n_groups, spec.n_buckets, spec.n_agg_fields,
+            spec.numeric_filters, spec.need_minmax,
+            spec.segment_impl, spec.hash_slots,
+            self.value_layouts, entry.ts_layout, entry.series_layout,
+        )
 
     def fuse_key(self, i: int) -> tuple:
         """Grouping key for cohort merging: preps agreeing on the cache
@@ -575,6 +589,10 @@ class Executor:
             if cached is not None:
                 path = "device-cached"
                 return self._finish_metrics(m, t_start, path, cached)
+            if m.get("kernel_refused"):
+                # the device refused the aggregate's program and no other
+                # impl is left for it: the host scan answers, exactly
+                route = "host"
         # Partitioned tables: push the aggregate DOWN to each partition
         # (local kernel per partition; remote partitions over the wire —
         # ref: dist_sql_query resolver push-down) and combine partials.
@@ -840,6 +858,27 @@ class Executor:
             sql=ledger.sql if ledger else "",
         )
 
+    def _route_cached_kernel(self, plan: QueryPlan, spec, n_rows: int,
+                             est_distinct):
+        """``_route_kernel`` for the cached path, with "auto"/a pin
+        resolved to the CONCRETE impl on host: it keys the packed jit
+        call, so flipping the env knobs re-traces warm shapes instead of
+        silently reusing the stale compiled branch. (None, None) when
+        there is no impl to offer (``route_segment_kernel``)."""
+        import dataclasses
+
+        from ..ops.scan_agg import resolve_segment_impl
+
+        spec, krec = self._route_kernel(plan, spec, n_rows, est_distinct)
+        if spec is None:
+            return None, None
+        return dataclasses.replace(
+            spec,
+            segment_impl=resolve_segment_impl(
+                spec.n_groups * spec.n_buckets, spec.segment_impl
+            ),
+        ), krec
+
     def _finish_kernel(self, krec, spec, m: dict, state,
                        seconds: float, n_valid=None) -> None:
         finish_segment_kernel(krec, spec, m, state, seconds, n_valid)
@@ -962,6 +1001,8 @@ class Executor:
             plan, spec, n_rows=n,
             est_distinct=max(enc.num_groups, 1) * n_buckets,
         )
+        if spec is None:  # no impl fits the device, or it refused them all
+            return self._execute_agg_host(plan, rows)
 
         # Large scans shard over the device mesh (partial agg per device,
         # monoid combine via psum/pmin/pmax collectives); small ones stay
@@ -1247,23 +1288,15 @@ class Executor:
             active_groups = len(np.unique(series_group[scan_allowed]))
         else:
             active_groups = 1
-        spec, krec = self._route_kernel(
-            plan, spec, n_rows=entry.n_valid,
-            est_distinct=max(active_groups, 1) * n_buckets,
+        est_distinct = max(active_groups, 1) * n_buckets
+        spec, krec = self._route_cached_kernel(
+            plan, spec, entry.n_valid, est_distinct
         )
-        # Resolve "auto"/pin to the CONCRETE impl on host: it keys the
-        # packed jit call below, so flipping the env knobs re-traces warm
-        # shapes instead of silently reusing the stale compiled branch.
-        import dataclasses
-
-        from ..ops.scan_agg import resolve_segment_impl
-
-        spec = dataclasses.replace(
-            spec,
-            segment_impl=resolve_segment_impl(
-                spec.n_groups * spec.n_buckets, spec.segment_impl
-            ),
-        )
+        if spec is None:
+            # no impl fits the device's free memory, or it has refused
+            # them all (dispatch_cached_agg): the host serves the query
+            m["kernel_refused"] = True
+            return None
 
         gos = np.append(series_group, 0).astype(np.int32)  # pad series -> masked
         allow = np.append(allowed, False)  # delta fold: NO value pruning
@@ -1298,12 +1331,6 @@ class Executor:
         hi_rel = hi - entry.min_ts
         t0_rel = max(t0 - entry.min_ts, -(2**31) + 1) if not empty_range else 0
         width_i = width if width else 1
-        kernel_key = (
-            spec.n_groups, spec.n_buckets, spec.n_agg_fields,
-            spec.numeric_filters, spec.need_minmax,
-            spec.segment_impl, spec.hash_slots,
-            value_layouts, entry.ts_layout, entry.series_layout,
-        )
         row_idx = None
         if entry.mesh is None and allow_selective and not empty_range:
             row_idx = self._selective_row_idx(entry, scan_allowed, lo, hi)
@@ -1317,22 +1344,28 @@ class Executor:
             lo=lo, hi=hi, t0=t0, width=width, n_buckets=n_buckets,
             empty_range=empty_range,
             lo_rel=lo_rel, hi_rel=hi_rel, t0_rel=t0_rel, width_i=width_i,
-            kernel_key=kernel_key,
             tag_keys=tag_keys, key_values=key_values, agg_cols=agg_cols,
             num_groups=num_groups, delta=delta,
-            value_layouts=value_layouts,
+            value_layouts=value_layouts, est_distinct=est_distinct,
         )
 
-    def dispatch_cached_agg(self, prep: "CachedAggPrep") -> ResultSet:
+    def dispatch_cached_agg(self, prep: "CachedAggPrep") -> Optional[ResultSet]:
         """The "spec -> dispatch" half for ONE prepared query: device
         call (mesh shard_map or the RTT-minimized packed path), delta
-        fold, result assembly — exactly the pre-split cached path."""
+        fold, result assembly — exactly the pre-split cached path.
+
+        A packed program the device refuses for memory is a typed
+        ``kernel_refused`` event, never the request's error: the impl is
+        marked unusable for the shape and the next candidate runs; with
+        none left this returns None and the caller serves from the host
+        (``m["kernel_refused"]``)."""
         from ..utils.deadline import checkpoint as _deadline_checkpoint
 
         # last cheap exit before committing to the device dispatch
         # (cohort dispatches intentionally skip this: a cohort carries
         # MANY budgets; members observe their own at the batch layer)
         _deadline_checkpoint("dispatch")
+        import jax
         import jax.numpy as jnp
 
         from ..ops.scan_agg import coerce_literals, encode_filter_ops, state_to_host
@@ -1342,10 +1375,10 @@ class Executor:
         lo_rel, hi_rel = prep.lo_rel, prep.hi_rel
         t0_rel, width_i = prep.t0_rel, prep.width_i
         gos, allow_scan = prep.gos, prep.allow_scan
-        row_idx, kernel_key = prep.row_idx, prep.kernel_key
+        row_idx = prep.row_idx
         import time as _time
 
-        from ..obs.device import cost_analysis, timed_dispatch
+        from ..obs.device import cost_analysis, refusal_of, timed_dispatch
 
         t_kernel = _time.perf_counter()
         if entry.mesh is not None:
@@ -1375,7 +1408,7 @@ class Executor:
             with _span("fetch", bytes=sum(int(o.nbytes) for o in out)):
                 state = state_to_host(*out)
             querystats.note_kernel_dispatch(
-                ("cached-dist", int(entry.mesh.devices.size), *kernel_key),
+                ("cached-dist", int(entry.mesh.devices.size), *prep.kernel_key),
                 _time.perf_counter() - t_kernel,
                 kind="cached_dist",
             )
@@ -1387,6 +1420,7 @@ class Executor:
                 cached_scan_agg_packed,
                 pack_dyn,
                 packed_program_name,
+                segment_row_chunks,
                 unpack_packed_state,
             )
 
@@ -1402,29 +1436,53 @@ class Executor:
                     session_dev,
                     jnp.asarray(dyn),
                 )
-            pkwargs = dict(
-                n_groups=spec.n_groups,
-                n_buckets=spec.n_buckets,
-                n_agg_fields=spec.n_agg_fields,
-                numeric_filters=encode_filter_ops(spec.numeric_filters),
-                need_minmax=spec.need_minmax,
-                segment_impl=spec.segment_impl,
-                hash_slots=spec.hash_slots,
-                selective=selective,
-                value_layouts=prep.value_layouts,
-                ts_layout=entry.ts_layout,
-                series_layout=entry.series_layout,
-            )
-            packed = timed_dispatch(
-                "cached_packed",
-                lambda: _fetch_behind(cached_scan_agg_packed(*pargs, **pkwargs)),
-                impl=spec.segment_impl,
-                program=packed_program_name(spec.segment_impl, selective),
-            )
-            with _span("fetch", bytes=int(packed.nbytes)):
-                state = unpack_packed_state(packed, spec)
+            n_rows = len(row_idx) if selective else entry.padded_rows
+            while True:
+                pkwargs = dict(
+                    n_groups=spec.n_groups,
+                    n_buckets=spec.n_buckets,
+                    n_agg_fields=spec.n_agg_fields,
+                    numeric_filters=encode_filter_ops(spec.numeric_filters),
+                    need_minmax=spec.need_minmax,
+                    segment_impl=spec.segment_impl,
+                    hash_slots=spec.hash_slots,
+                    selective=selective,
+                    value_layouts=prep.value_layouts,
+                    ts_layout=entry.ts_layout,
+                    series_layout=entry.series_layout,
+                )
+                try:
+                    packed = timed_dispatch(
+                        "cached_packed",
+                        lambda: _fetch_behind(
+                            cached_scan_agg_packed(*pargs, **pkwargs)
+                        ),
+                        impl=spec.segment_impl,
+                        program=packed_program_name(spec.segment_impl, selective),
+                        chunks=segment_row_chunks(
+                            spec.segment_impl, n_rows,
+                            spec.n_groups * spec.n_buckets,
+                            spec.n_agg_fields, spec.need_minmax,
+                        ),
+                    )
+                    with _span("fetch", bytes=int(packed.nbytes)):
+                        state = unpack_packed_state(packed, spec)
+                    break
+                except jax.errors.JaxRuntimeError as e:
+                    # a program the device has no room for (the compiler's
+                    # refusal raises at the call, a failed execution where
+                    # its result is fetched): the next impl, else the host
+                    message = refusal_of(e)
+                    if message is None:
+                        raise
+                    spec = self._reroute_refused(
+                        prep, message, _time.perf_counter() - t_kernel
+                    )
+                    if spec is None:
+                        m["kernel_refused"] = True
+                        return None
             querystats.note_kernel_dispatch(
-                ("cached-packed", selective, *kernel_key),
+                ("cached-packed", selective, *prep.kernel_key),
                 _time.perf_counter() - t_kernel,
                 kind="cached_packed",
                 cost_fn=lambda: cost_analysis(
@@ -1435,6 +1493,37 @@ class Executor:
             prep.krec, spec, m, state, _time.perf_counter() - t_kernel
         )
         return self._fold_and_assemble(prep, state)
+
+    def _reroute_refused(self, prep: "CachedAggPrep", message: str,
+                         seconds: float):
+        """The device refused ``prep``'s packed program: journal it, take
+        the impl out of the router for this shape and choose again. ->
+        the spec to dispatch next (also set on ``prep``), or None when
+        nothing is left to offer (or the impl was pinned, not routed)."""
+        import dataclasses
+
+        from ..obs.decisions import resolve_decision
+        from ..obs.device import note_refusal
+        from .path_router import KERNEL_ROUTER
+
+        spec = prep.spec
+        note_refusal("cached_packed", spec.segment_impl, prep.kernel_key, message)
+        if prep.krec is None:
+            return None
+        key, routed, dec_id = prep.krec
+        KERNEL_ROUTER.refuse(key, routed)
+        resolve_decision(
+            dec_id, actual=seconds, outcome="refused",
+            loop="kernel_router", calibrate=False,
+        )
+        spec, prep.krec = self._route_cached_kernel(
+            prep.plan,
+            dataclasses.replace(spec, segment_impl="auto", hash_slots=0),
+            prep.entry.n_valid, prep.est_distinct,
+        )
+        if spec is not None:
+            prep.spec = spec
+        return spec
 
     def _fold_and_assemble(self, prep: "CachedAggPrep", state) -> ResultSet:
         """One prepared query's host tail: fold its memtable delta into the
@@ -1628,6 +1717,9 @@ class Executor:
                                 (prep.row_idx != prep.entry.n_valid).sum()
                             )
                     out = self.dispatch_cached_agg(prep)
+                    if out is None:  # refused by the device: the solo path
+                        outcomes[i] = self.execute(plans[i], table)
+                        continue
                     outcomes[i] = self._finish_metrics(
                         prep.m, t_start, "device-cached", out
                     )
@@ -2410,7 +2502,9 @@ def route_segment_kernel(shape_key, spec, n_rows: int, est_distinct,
     push-down (query/partial.py runs on partition owners with no
     Executor instance in scope). Returns (spec, token); token is None
     when routing doesn't apply (n_seg == 1, pinned HORAEDB_SEGMENT_IMPL,
-    or router disabled)."""
+    or router disabled); spec is None when no impl can be offered: none
+    fits the device's free memory (``candidate_kernels``) or the device
+    has refused every one for this key (``KernelRouter.refuse``)."""
     from ..ops.scan_agg import pinned_segment_impl
     from .path_router import (
         KERNEL_ROUTER,
@@ -2440,12 +2534,17 @@ def route_segment_kernel(shape_key, spec, n_rows: int, est_distinct,
 
     from ..ops.hash_agg import hash_slots_for
 
-    candidates = candidate_kernels(n_seg, n_rows, est)
+    candidates = candidate_kernels(
+        n_seg, n_rows, est, spec.n_agg_fields, spec.need_minmax
+    )
     impl = KERNEL_ROUTER.choose(
         key,
         seed_kernel(n_seg, est, jax.default_backend()),
         candidates,
     )
+    if impl is None:
+        # nothing fits the device's free memory, or it refused them all
+        return None, None
     spec = dataclasses.replace(
         spec,
         segment_impl=impl,

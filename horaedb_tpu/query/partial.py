@@ -321,10 +321,12 @@ def _partial_kernel(
         tuple((c, op) for c, op, _ in spec["device_filters"]),
         tuple((c, op) for c, op, _ in spec["exact_filters"]),
     )
-    kspec, krec = route_segment_kernel(
+    routed, krec = route_segment_kernel(
         shape_key, kspec, n_rows=batch.n_valid,
         est_distinct=max(enc.num_groups, 1) * n_buckets,
     )
+    if routed is not None:  # else: nothing to offer, the static choice stands
+        kspec = routed
 
     import time as _time
 

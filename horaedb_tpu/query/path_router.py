@@ -157,17 +157,22 @@ def kernel_routing_enabled() -> bool:
     )
 
 
-def candidate_kernels(n_seg: int, n_rows: int, est_distinct=None) -> tuple:
+def candidate_kernels(n_seg: int, n_rows: int, est_distinct=None,
+                      n_fields: int = 0, need_minmax: bool = False) -> tuple:
     """Impls worth PROBING for this shape. Routing must never schedule a
     probe that is catastrophically wrong by construction: the MXU one-hot
     is O(N * n_seg) — beyond a bounded extrapolation of the static
     crossover a single probe could cost seconds — and the hash table
     cannot beat the direct impls when the domain is already tiny or the
     live cardinality fills most of it (a near-full table just routes
-    everything through the overflow fallback)."""
+    everything through the overflow fallback). Nor one the device would
+    refuse: an impl whose temporaries (``segment_temp_bytes``) exceed the
+    device's free memory is not offered — () when none fits, and the host
+    serves the query."""
     import jax
 
-    from ..ops.scan_agg import mxu_max_segments
+    from ..obs.device import device_free_bytes
+    from ..ops.scan_agg import mxu_max_segments, segment_temp_bytes
 
     cands = ["scatter"]
     if n_seg <= (
@@ -178,6 +183,12 @@ def candidate_kernels(n_seg: int, n_rows: int, est_distinct=None) -> tuple:
         cands.append("mxu")
     if n_seg > 64 and (est_distinct is None or est_distinct * 4 <= n_seg):
         cands.append("hash")
+    free = device_free_bytes()
+    if free is not None:
+        cands = [
+            k for k in cands
+            if segment_temp_bytes(k, n_rows, n_seg, n_fields, need_minmax) <= free
+        ]
     return tuple(cands)
 
 
@@ -220,11 +231,17 @@ class KernelRouter:
         self._stats[key] = st
         return st
 
-    def choose(self, key, seed: str, candidates: tuple) -> str:
-        """The impl to dispatch this call with."""
+    def choose(self, key, seed: str, candidates: tuple):
+        """The impl to dispatch this call with; None when there is none to
+        offer: ``candidates`` is empty, or the device has refused every one
+        of them for this key (``refuse``)."""
         with self._lock:
             st = self._touch(key)
             st["calls"] += 1
+            refused = st.get("refused", ())
+            candidates = tuple(k for k in candidates if k not in refused)
+            if not candidates:
+                return None
             samples, times = st["n"], st["t"]
             order = [seed] + [k for k in candidates if k != seed]
             for k in order:
@@ -256,6 +273,12 @@ class KernelRouter:
                 seconds if prev is None else min(seconds, prev * 1.1)
             )
 
+    def refuse(self, key, kernel: str) -> None:
+        """The device refused ``kernel``'s program for this key (no room in
+        HBM): never offer it for the key again."""
+        with self._lock:
+            self._touch(key).setdefault("refused", set()).add(kernel)
+
     def note_segments(self, key, live: int) -> None:
         """Observed live (group x bucket) cells — EWMA'd so the hash
         slot table is sized from what the shape actually produces."""
@@ -275,7 +298,7 @@ class KernelRouter:
         with self._lock:
             st = self._stats.get(key, {})
             return {
-                k: (dict(v) if isinstance(v, dict) else v)
+                k: (type(v)(v) if isinstance(v, (dict, set)) else v)
                 for k, v in st.items()
             }
 

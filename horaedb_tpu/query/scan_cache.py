@@ -40,6 +40,20 @@ from ..common_types.dict_column import as_values
 from ..common_types.row_group import RowGroup
 from ..ops.encoding import pad_to_bucket, shape_bucket
 from ..table_engine.predicate import Predicate
+from ..utils.metrics import REGISTRY
+from ..utils.tracectx import span
+
+# A build reads the table whole, sorts it into the resident layout, encodes
+# and uploads it: seconds at millions of rows, paid by the request that
+# misses. Counted from start-up so a scrape can tell "none yet" from "none".
+_M_BUILDS = REGISTRY.counter(
+    "horaedb_scan_cache_builds_total",
+    "scan-cache entries built (table read + resident layout + upload)",
+)
+_M_BUILD_SECONDS = REGISTRY.counter(
+    "horaedb_scan_cache_build_seconds_total",
+    "wall seconds spent building scan-cache entries",
+)
 
 _I32_MAX = 2**31 - 1
 
@@ -721,6 +735,27 @@ class ScanCache:
             if entry is None:
                 return None, False, None
             return entry, False, delta
+        t_build = time.perf_counter()
+        with span("cache_build", table=table.name) as sp:
+            entry = self._build_entry(table, base_fp, value_columns, read_rows)
+            if entry is not None:
+                sp.set(
+                    rows=entry.n_valid, bucket=entry.padded_rows,
+                    bytes=entry.device_bytes,
+                    series_layout="/".join(map(str, entry.series_layout)),
+                    ts_layout="/".join(map(str, entry.ts_layout)),
+                )
+        if entry is None:
+            return None, False, None
+        _M_BUILDS.inc()
+        _M_BUILD_SECONDS.inc(time.perf_counter() - t_build)
+        return entry, True, entry.empty_rows
+
+    def _build_entry(self, table, base_fp, value_columns: list[str],
+                     read_rows) -> Optional[CachedTableScan]:
+        """The miss path of ``get``: read the table whole, build the entry
+        and insert it; None where the table cannot (or may not yet) be
+        cached."""
         seq_before = {d.table_id: d.last_sequence for d in table.physical_datas()}
         rows = read_rows()
         seq_after = {d.table_id: d.last_sequence for d in table.physical_datas()}
@@ -728,14 +763,14 @@ class ScanCache:
             # Writes or a flush raced the build read: the entry's exact
             # row set would be ambiguous (delta double/under-count) —
             # skip building this time.
-            return None, False, None
+            return None
         n = len(rows)
         if n == 0:
-            return None, False, None
+            return None
         ts = rows.timestamps
         min_ts, max_ts = int(ts.min()), int(ts.max())
         if max_ts - min_ts >= _I32_MAX:
-            return None, False, None
+            return None
         # A table whose resident state ALONE busts the byte budget never
         # builds — the host path serves it instead of a failing (or
         # budget-starving) giant device_put. Under the layout tuner the
@@ -747,7 +782,7 @@ class ScanCache:
             est //= 8
         host_est = min(_rowgroup_bytes(rows), self.max_host_rows_bytes)
         if est + host_est > self.max_bytes:
-            return None, False, None
+            return None
         entry = self._build(
             base_fp, rows, min_ts, max_ts, value_columns, table.name
         )
@@ -755,7 +790,7 @@ class ScanCache:
             # the codecs didn't deliver the admitted ratio: the realized
             # entry alone busts the budget — never insert it
             self._resolve_pending_evicted(entry)
-            return None, False, None
+            return None
         entry.built_seqs = seq_after
         entry.last_hit_at = time.time()
         with self._lock:
@@ -766,8 +801,7 @@ class ScanCache:
         from ..obs.device import refresh_occupancy
 
         refresh_occupancy(force=True)  # a build is a residency mutation
-        empty = entry.empty_rows
-        return entry, True, empty
+        return entry
 
     @staticmethod
     def _resident_layout(rows: RowGroup):

@@ -64,6 +64,7 @@ EVENT_KINDS: dict[str, str] = {
     "query_timeout": "a query exceeded its time budget and unwound at a checkpoint",
     "query_cancelled": "a query was cooperatively cancelled (KILL QUERY / ctl / disconnect)",
     "kernel_compile": "a device kernel shape compiled for the first time (XLA compile)",
+    "kernel_refused": "the device refused a kernel's program for memory; the impl is unusable for that shape and the request was served by another route",
     "decision_resolved": "an adaptive loop's journaled decision got its realized outcome (sampled per loop)",
     "loop_miscalibrated": "an adaptive loop's fast+slow calibration windows crossed the error threshold",
 }

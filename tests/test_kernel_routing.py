@@ -531,3 +531,131 @@ class TestCacheDtypeAutoTune:
         entry = self._entry(db)
         assert entry is not None
         assert entry.value_cols_dev["w"].dtype == jnp.float32
+
+
+class TestRefusedKernel:
+    """The served cached path's guard (PR 27): a packed program the device
+    refuses for memory is a typed event and another route's exact answer,
+    never the request's error."""
+
+    SQL = TestRoutingEndToEnd.SQL
+    # what the v5e's compiler said of the scatter impl at 2^25 rows (PR 27)
+    REFUSAL = (
+        "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out of "
+        "memory in memory space hbm. Used 18.28G of 15.75G hbm. Exceeded "
+        "hbm capacity by 2.54G.\n\nTotal hbm usage >= 18.80G:"
+    )
+
+    @pytest.fixture()
+    def served(self, db, monkeypatch):
+        """-> (run, refuse, calls): ``run()`` serves SQL once through the
+        proxy; ``refuse`` is the set of impls whose packed program the fake
+        device refuses; ``calls`` lists the impls dispatched."""
+        import jax
+
+        from horaedb_tpu.ops import scan_agg
+        from horaedb_tpu.proxy import Proxy
+
+        monkeypatch.delenv("HORAEDB_SEGMENT_IMPL", raising=False)
+        monkeypatch.setenv("HORAEDB_ADAPTIVE_PATH", "0")
+        real = scan_agg.cached_scan_agg_packed
+        refuse: set = set()
+        calls: list = []
+
+        def packed(*args, **kwargs):
+            calls.append(kwargs["segment_impl"])
+            if kwargs["segment_impl"] in refuse:
+                raise jax.errors.JaxRuntimeError(self.REFUSAL)
+            return real(*args, **kwargs)
+
+        packed.lower = real.lower
+        monkeypatch.setattr(scan_agg, "cached_scan_agg_packed", packed)
+        _seed_groupby(db)
+        proxy = Proxy(db)
+        yield (lambda: proxy.handle_sql(self.SQL)), refuse, calls
+        proxy.close()
+
+    @staticmethod
+    def _rows(out):
+        return sorted(tuple(r.values()) for r in out.to_pylist())
+
+    @staticmethod
+    def _events():
+        from horaedb_tpu.utils.events import EVENT_STORE
+
+        return EVENT_STORE.list(kind="kernel_refused")
+
+    def _want(self, db):
+        # the host's answer: 25 rows a host, v = i, w = 2 i
+        return sorted(
+            (f"h{h}", 25, float(sum(range(h, 500, 20))), float(2 * h))
+            for h in range(20)
+        )
+
+    def test_refused_impl_is_an_event_and_the_next_candidate_serves(
+        self, db, served
+    ):
+        from horaedb_tpu.query.path_router import KERNEL_ROUTER
+        from horaedb_tpu.utils.metrics import REGISTRY
+
+        run, refuse, calls = served
+        counter = 'horaedb_events_total{kind="kernel_refused"}'
+        assert f"{counter} " in REGISTRY.expose()  # exported before any
+        before, seen = len(self._events()), []
+        refuse.add("scatter")  # the CPU's seed for 32 segments
+        for _ in range(5):
+            out = run()
+            assert self._rows(out) == self._want(db)
+            seen.append((out.metrics["path"], out.metrics.get("kernel")))
+        # served from the cache by the other candidate once the cache is built
+        assert seen[-1] == ("device-cached", "mxu"), seen
+        events = self._events()[before:]
+        assert len(events) == 1, events
+        attrs = events[0]["attrs"]
+        assert attrs["kernel"] == "cached_packed" and attrs["impl"] == "scatter"
+        assert attrs["message"] == self.REFUSAL.splitlines()[0]
+        assert "scatter" in attrs["shape"]
+        assert calls.count("scatter") == 1  # never offered for the shape again
+        (stats,) = [
+            KERNEL_ROUTER.stats(k) for k in list(KERNEL_ROUTER._stats)
+            if KERNEL_ROUTER.stats(k).get("refused")
+        ]
+        assert stats["refused"] == {"scatter"}
+
+    def test_every_impl_refused_is_the_hosts_exact_answer(self, db, served):
+        run, refuse, calls = served
+        before = len(self._events())
+        refuse.update(("scatter", "mxu", "hash"))
+        paths = []
+        for _ in range(5):
+            out = run()  # never raises
+            assert self._rows(out) == self._want(db)
+            paths.append(out.metrics["path"])
+        assert paths[-1] == "host" and out.metrics["kernel_refused"] is True
+        refused = [e["attrs"]["impl"] for e in self._events()[before:]]
+        assert sorted(refused) == ["mxu", "scatter"]  # hash: never a candidate
+        assert sorted(calls) == ["mxu", "scatter"]  # one try each, then none
+
+    def test_a_pinned_impl_refused_goes_to_the_host(self, db, served, monkeypatch):
+        run, refuse, calls = served
+        monkeypatch.setenv("HORAEDB_SEGMENT_IMPL", "scatter")
+        refuse.add("scatter")
+        for _ in range(4):
+            out = run()
+            assert self._rows(out) == self._want(db)
+        assert out.metrics["path"] == "host"
+
+    def test_another_failure_is_still_the_requests_error(self, db, monkeypatch):
+        from horaedb_tpu.ops import scan_agg
+
+        monkeypatch.setenv("HORAEDB_ADAPTIVE_PATH", "0")
+
+        def broken(*args, **kwargs):
+            raise ValueError("not a refusal")
+
+        _seed_groupby(db)
+        db.execute(self.SQL)  # first sighting: no cached dispatch yet
+        monkeypatch.setattr(scan_agg, "cached_scan_agg_packed", broken)
+        with pytest.raises(ValueError, match="not a refusal"):
+            for _ in range(3):
+                db.execute(self.SQL)

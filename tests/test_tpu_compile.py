@@ -139,6 +139,60 @@ PACKED = {
 }
 
 
+# cpu-4000x12h (benchmark/configs): 17,280,000 rows padded to 2^25, the
+# layouts ScanCache builds for them (series ("delta", 1), ts and values raw),
+# double-groupby-all's 4000 hosts x 12 h -> 4096 x 16 = 65,536 segments, 48,000
+# of them live. Before PR 27 the compiler refused the scatter impl at 2^25
+# rows whatever the segment count ("Used 18.28G of 15.75G hbm": its (N, F)
+# update tile, F padded to 128 lanes) and, through its fallback, the hash impl.
+N_4000X12H = 1 << 25
+EST_4000X12H = 48_000
+AT_4000X12H = {
+    "avg-10-fields": (10, False),
+    "minmax-5-fields": (5, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(AT_4000X12H))
+def test_cached_packed_compiles_at_4000x12h(monkeypatch, compile_for, case):
+    """The grouped full scan of the 2^25-row deployment compiles under the
+    impl the policy picks for it, with temporaries under 4 GB and under what
+    the policy reckons for the impl (``segment_temp_bytes``)."""
+    import jax
+
+    from horaedb_tpu.ops.scan_agg import (
+        cached_scan_agg_packed,
+        segment_temp_bytes,
+    )
+    from horaedb_tpu.query.path_router import candidate_kernels, seed_kernel
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("HORAEDB_MXU_MAX_SEGMENTS", raising=False)
+    n_fields, need_minmax = AT_4000X12H[case]
+    n, n_groups, n_buckets = N_4000X12H, 4096, 16
+    n_seg = n_groups * n_buckets
+    impl = seed_kernel(n_seg, EST_4000X12H, "tpu")
+    assert impl == "scatter"
+    assert candidate_kernels(
+        n_seg, n, EST_4000X12H, n_fields, need_minmax
+    ) == (impl,)
+    args = (
+        (((n // 32 + 1,), "uint32"), ((n // 128,), "int32")),  # ("delta", 1)
+        (((n,), "int32"),),
+        ((n_fields, n), "float32"),
+        ((2 * (S + 1),), "int32"), ((4,), "int32"),
+    )
+    static = dict(
+        _packed_static(n_groups, n_buckets, n_fields, need_minmax, impl),
+        ts_layout=("raw",),
+    )
+    mem = compile_for(cached_scan_agg_packed, args, **static).memory_analysis()
+    assert mem.temp_size_in_bytes < (4 << 30), mem
+    assert mem.temp_size_in_bytes <= segment_temp_bytes(
+        impl, n, n_seg, n_fields, need_minmax
+    ), mem
+
+
 @pytest.mark.parametrize("case", sorted(PACKED))
 def test_cached_packed_compiles(compile_for, case):
     from horaedb_tpu.ops.scan_agg import (
@@ -151,6 +205,14 @@ def test_cached_packed_compiles(compile_for, case):
     # HBM beyond the resident columns stays a small share of the 16 GB
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < (1 << 30), mem
+    if not static["selective"]:  # what the policy reckons is from above
+        from horaedb_tpu.ops.scan_agg import segment_temp_bytes
+
+        assert mem.temp_size_in_bytes <= segment_temp_bytes(
+            static["segment_impl"], N,
+            static["n_groups"] * static["n_buckets"],
+            static["n_agg_fields"], static["need_minmax"],
+        ), mem
     # what a device trace's ``XLA Modules`` line will call it
     name = packed_program_name(static["segment_impl"], static["selective"])
     assert f"HloModule jit_{name}," in compiled.as_text()
@@ -271,8 +333,12 @@ def test_named_program_lowers_under_its_name(monkeypatch, impl, selective):
     assert scan_agg.packed_program_name(impl, selective) == name
     text = lowered.as_text(debug_info=True)
     assert f"module @jit_{name} " in text
-    for scope in ("decode_series", "decode_ts", "decode_values", "filter",
-                  "segment_" + impl, "pack"):
+    scopes = ["decode_series", "decode_ts", "decode_values", "filter",
+              "segment_" + impl, "pack"]
+    if impl == "scatter":  # its stages, one scatter each
+        scopes += [f"segment_scatter/{stage}" for stage in
+                   ("counts", "sums", "mins", "maxs")]
+    for scope in scopes:
         assert f"jit({name})/{scope}/" in text, scope
 
 
@@ -287,7 +353,22 @@ STEP2_AT_2M_ROWS = {
 }
 
 
-def test_tpu_policy_offers_no_refused_impl(monkeypatch):
+# The same at N = 2^25 (cpu-4000x12h), 10 fields avg-only and 5 fields with
+# min/max, per (impl, n_seg), on this tree (scratch compiles for the described
+# v5e, PR 27; temporaries 0.17-0.94 GB at 65,536 segments). On the parent (81d0b0f) every
+# ("scatter", *) and ("hash", *) was False at this row count, 64 segments
+# included: "Used 17.25G-18.69G of 15.75G hbm".
+STEP2_AT_32M_ROWS = {
+    (impl, n_seg): True
+    for impl in ("mxu", "scatter", "hash")
+    for n_seg in (64, 4096, 8192, 32768, 65536)
+}
+
+
+@pytest.mark.parametrize("n_rows,table", [
+    (N, STEP2_AT_2M_ROWS), (N_4000X12H, STEP2_AT_32M_ROWS),
+], ids=["2M-rows", "32M-rows"])
+def test_tpu_policy_offers_no_refused_impl(monkeypatch, n_rows, table):
     """Told the backend is a TPU, the three places that choose a segment
     impl never offer one whose program the chip's compiler refused at
     that (rows, segments)."""
@@ -300,13 +381,174 @@ def test_tpu_policy_offers_no_refused_impl(monkeypatch):
     monkeypatch.delenv("HORAEDB_SEGMENT_IMPL", raising=False)
     monkeypatch.delenv("HORAEDB_MXU_MAX_SEGMENTS", raising=False)
     assert resolve_segment_impl(64) == "mxu"  # the TPU branch is live
-    for n_seg in sorted({n for _, n in STEP2_AT_2M_ROWS}):
+    for n_seg in sorted({n for _, n in table}):
         offered = {resolve_segment_impl(n_seg)}
         for est in (None, 8, n_seg):
             offered.add(seed_kernel(n_seg, est, "tpu"))
-            offered.update(candidate_kernels(n_seg, N, est))
+            offered.update(candidate_kernels(n_seg, n_rows, est, 10, False))
+            offered.update(candidate_kernels(n_seg, n_rows, est, 5, True))
         for impl in offered:
-            assert STEP2_AT_2M_ROWS.get((impl, n_seg), True), (impl, n_seg)
+            assert table.get((impl, n_seg), True), (impl, n_seg)
+
+
+def test_policy_offers_only_what_fits_the_device(monkeypatch):
+    """``candidate_kernels`` holds each impl's reckoned temporaries against
+    the device's free memory: with a v5e's 15.75 GB free the 2^25-row
+    deployment's shape keeps its scatter; a segment domain whose three
+    accumulators alone outgrow the chip is offered nothing (the host serves
+    it), and so is anything once the device is nearly full."""
+    from horaedb_tpu.obs import device
+    from horaedb_tpu.query.path_router import candidate_kernels
+
+    free = [int(15.75 * 2**30)]
+    monkeypatch.setattr(device, "device_free_bytes", lambda: free[0])
+    shape = (65_536, N_4000X12H, EST_4000X12H)
+    assert candidate_kernels(*shape, 10, False) == ("scatter",)
+    assert candidate_kernels(*shape, 5, True) == ("scatter",)
+    # 17.28M rows by host and 10 s tick: 2^25 segments x 3 x 512 B x 2
+    assert candidate_kernels(1 << 25, N_4000X12H, None, 5, True) == ()
+    free[0] = 1 << 30
+    assert candidate_kernels(*shape, 10, False) == ()
+    monkeypatch.setattr(device, "device_free_bytes", lambda: None)  # the CPU
+    assert candidate_kernels(1 << 25, N_4000X12H, None, 5, True) == (
+        "scatter", "hash",
+    )
+
+
+def _numpy_group_by(seg, mask, vals, n_seg):
+    """The plain reference: counts, and float64 sums / mins / maxs per field,
+    by ``np.add.at`` / ``np.minimum.at`` / ``np.maximum.at``."""
+    seg, vals = seg[mask], vals[:, mask].astype(np.float64)
+    counts = np.zeros(n_seg, np.int64)
+    np.add.at(counts, seg, 1)
+    sums = np.zeros((vals.shape[0], n_seg))
+    mins = np.full((vals.shape[0], n_seg), np.inf)
+    maxs = np.full((vals.shape[0], n_seg), -np.inf)
+    for f in range(vals.shape[0]):
+        np.add.at(sums[f], seg, vals[f])
+        np.minimum.at(mins[f], seg, vals[f])
+        np.maximum.at(maxs[f], seg, vals[f])
+    return counts, sums, mins, maxs
+
+
+# rows, chunk rows: one piece; chunks that divide the rows; a last chunk that
+# is mostly padding; a last chunk of one row
+SCATTER_CUTS = {
+    "whole": (1000, 1024), "even": (1024, 128), "padded-tail": (1000, 128),
+    "tail-of-one": (1025, 256),
+}
+
+
+@pytest.mark.parametrize("need_minmax", [False, True], ids=["avg", "minmax"])
+@pytest.mark.parametrize("cut", sorted(SCATTER_CUTS))
+def test_chunked_scatter_equals_numpy_group_by(monkeypatch, cut, need_minmax):
+    """The scatter impl, cut into row chunks by ``_SCATTER_TILE_BYTES``, against
+    a plain numpy group-by on seeded data laid out as the cache lays it (rows
+    sorted by segment, so segments span chunk boundaries): masked rows, a run
+    of masked rows that covers a whole chunk, an empty segment.
+
+    Tolerances: counts, mins and maxs exact (integers; f32 values picked, not
+    computed). Sums are f32 accumulations of <= 360 values of [0, 100]
+    (1000 rows over 37 segments: ~27 each) against float64: 2e-5 relative,
+    the limit the benchmark's double-groupby-all cells hold ``value_gap`` to;
+    avg = sum / count inherits it."""
+    import jax.numpy as jnp
+
+    from horaedb_tpu.ops import scan_agg
+
+    n, chunk = SCATTER_CUTS[cut]
+    n_fields, n_seg = 3, 40
+    monkeypatch.setattr(scan_agg, "_SCATTER_TILE_BYTES", chunk * 512)
+    assert scan_agg.scatter_chunk_rows(n_fields) == chunk
+    assert scan_agg.segment_row_chunks(
+        "scatter", n, n_seg, n_fields, need_minmax
+    ) == -(-n // chunk)
+    rng = np.random.default_rng(27)
+    seg = np.sort(rng.integers(0, 37, n)).astype(np.int32)  # 37..39 stay empty
+    seg[seg == 11] = 12  # and one inside the range
+    mask = rng.random(n) > 0.2
+    mask[128:256] = False  # a whole chunk of the 128-row cuts
+    vals = rng.uniform(0, 100, (n_fields, n)).astype(np.float32)
+    want = _numpy_group_by(seg, mask, vals, n_seg)
+    counts, sums, mins, maxs = (
+        np.asarray(a) for a in scan_agg._scatter_segment_agg(
+            jnp.asarray(seg), jnp.asarray(mask), jnp.asarray(vals), n_seg,
+            need_minmax,
+        )
+    )
+    np.testing.assert_array_equal(counts, want[0])
+    assert counts.dtype == np.int32 and counts[11] == 0 and counts[37:].sum() == 0
+    np.testing.assert_allclose(sums, want[1], rtol=2e-5, atol=0)
+    live = want[0] > 0
+    np.testing.assert_allclose(
+        (sums / np.maximum(counts, 1))[:, live],
+        (want[1] / np.maximum(want[0], 1))[:, live], rtol=2e-5, atol=0,
+    )
+    if need_minmax:
+        np.testing.assert_array_equal(mins, want[2].astype(np.float32))
+        np.testing.assert_array_equal(maxs, want[3].astype(np.float32))
+    else:  # not wanted: zeros in their slots
+        assert not mins.any() and not maxs.any()
+    # counts only (count(*) with no field): the same cuts
+    only = scan_agg._scatter_segment_agg(
+        jnp.asarray(seg), jnp.asarray(mask), None, n_seg, need_minmax
+    )
+    np.testing.assert_array_equal(np.asarray(only[0]), want[0])
+    assert only[1:] == (None, None, None)
+
+
+def test_chunked_scatter_through_the_packed_program(monkeypatch):
+    """``cached_scan_scatter`` end to end with the cut forced small: count, sum,
+    min, max and avg of a grouped, bucketed, filtered scan equal the uncut
+    program's bit for bit, and the numpy group-by's within f32."""
+    import jax.numpy as jnp
+
+    from horaedb_tpu.ops import scan_agg
+    from horaedb_tpu.ops.scan_agg import (
+        ScanAggSpec, cached_scan_agg_packed, pack_dyn, pack_session,
+        unpack_packed_state,
+    )
+
+    rng = np.random.default_rng(28)
+    n_series, per, n = 12, 80, 1024  # 960 rows + 64 pad rows
+    codes = np.full(n, n_series, np.int32)
+    codes[: n_series * per] = np.repeat(np.arange(n_series, dtype=np.int32), per)
+    ts = np.full(n, -1, np.int32)
+    ts[: n_series * per] = np.tile(np.arange(per, dtype=np.int32) * 10, n_series)
+    vals = rng.uniform(0, 100, (2, n)).astype(np.float32)
+    gos = np.append(np.arange(n_series, dtype=np.int32) % 5, 0)  # 5 groups
+    allow = np.append(rng.random(n_series) > 0.25, False)
+    spec = ScanAggSpec(n_groups=8, n_buckets=4, n_agg_fields=2,
+                       numeric_filters=((1, ">"),), need_minmax=True,
+                       segment_impl="scatter")
+    args = ((jnp.asarray(codes),), (jnp.asarray(ts),), jnp.asarray(vals),
+            jnp.asarray(pack_session(gos, allow)),
+            jnp.asarray(pack_dyn([30.0], 100, 700, 0, 200)))
+    static = dict(
+        n_groups=8, n_buckets=4, n_agg_fields=2, numeric_filters=((1, 4),),
+        need_minmax=True, segment_impl="scatter", hash_slots=0,
+        selective=False, value_layouts=(("raw",),) * 2,
+        ts_layout=("raw",), series_layout=("raw",),
+    )
+
+    def run():
+        scan_agg._packed_programs.clear()  # the constant is read at trace time
+        return np.asarray(cached_scan_agg_packed(*args, **static))
+
+    whole = run()
+    monkeypatch.setattr(scan_agg, "_SCATTER_TILE_BYTES", 128 * 512)
+    cut = run()
+    scan_agg._packed_programs.clear()
+    np.testing.assert_array_equal(whole, cut)
+    state = unpack_packed_state(cut, spec)
+    live = (codes < n_series) & allow[codes] & (ts >= 100) & (ts < 700) & (vals[1] > 30.0)
+    seg = gos[codes] * 4 + np.clip(ts // 200, 0, 3)
+    counts, sums, mins, maxs = _numpy_group_by(seg, live, vals, 32)
+    np.testing.assert_array_equal(state.counts.reshape(-1), counts)
+    got = (state.sums, state.mins, state.maxs)
+    for a, b in zip(got, (sums, mins, maxs)):
+        a, b = a.reshape(2, 32)[:, counts > 0], b[:, counts > 0]
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=0)
 
 
 def test_mxu_minmax_chunks_are_exact(monkeypatch):
