@@ -562,12 +562,7 @@ class Executor:
             # Only shapes the device kernels can serve are worth routing;
             # everything else goes straight to its natural path.
             if self._adaptive and self._agg_device_shape(plan) is not None:
-                from .path_router import plan_shape_key
-
-                key = plan_shape_key(plan)
-                route = self.path_router.choose(key)
-                m["_adaptive_key"] = key
-                m["route"] = route
+                route = self._choose_route(plan, m)
         # Memory bound: when pruned SST metadata says the scan would
         # materialize more than HORAEDB_AGG_MEMORY_MB, aggregate per
         # segment window through the partial machinery instead — checked
@@ -650,13 +645,10 @@ class Executor:
                     # table size, selectivity, and backend. Always route
                     # through the learned PathRouter; only the explicit
                     # HORAEDB_ADAPTIVE_PATH=0 override pins device-first.
-                    from .path_router import plan_shape_key, raw_adaptive_enabled
+                    from .path_router import raw_adaptive_enabled
 
                     if raw_adaptive_enabled():
-                        key = plan_shape_key(plan)
-                        raw_route = self.path_router.choose(key)
-                        m["_adaptive_key"] = key
-                        m["route"] = raw_route
+                        raw_route = self._choose_route(plan, m)
                     if raw_route != "host":
                         raw_attempted = True
                         out = self._try_raw_device(plan, table, raw_shape, m)
@@ -707,6 +699,17 @@ class Executor:
                 out = self._execute_projection(plan, rows, m)
         return self._finish_metrics(m, t_start, path, out)
 
+    def _choose_route(self, plan: QueryPlan, m: dict) -> str:
+        """The PathRouter's route for this request, with what
+        ``_finish_metrics`` needs to hand the sample back."""
+        from .path_router import plan_shape_key
+
+        key = plan_shape_key(plan)
+        m["_adaptive_key"] = key
+        m["_compiles_before"] = querystats.kernel_compiles()
+        m["route"] = self.path_router.choose(key)
+        return m["route"]
+
     def _finish_metrics(
         self, m: dict, t_start: float, path: str, out: ResultSet
     ) -> ResultSet:
@@ -719,15 +722,23 @@ class Executor:
         # served the request (the cost side of the span tree).
         querystats.set_route(path)
         akey = m.pop("_adaptive_key", None)
+        compiles_before = m.pop("_compiles_before", None)
         raw_fellback = bool(m.pop("_raw_fallback", False))
-        if akey is not None and m.get("cache") != "build":
-            # one-off cache-build cost must not poison the device estimate;
+        if akey is not None:
             # a raw attempt that bounced to host charges the DEVICE arm
             # (attempt + host serve — see _try_raw_device)
             kind = (
                 "device" if raw_fellback or path != "host" else "host"
             )
-            self.path_router.record(akey, kind, _time.perf_counter() - t_start)
+            # one-off costs must not become a route's estimate: the scan
+            # cache's build, or a program compiled while the request ran
+            clean = (
+                m.get("cache") != "build"
+                and querystats.kernel_compiles() == compiles_before
+            )
+            self.path_router.record(
+                akey, kind, _time.perf_counter() - t_start, clean=clean
+            )
         out.metrics = m
         # Observability conveniences; atomic rebinds (read-only snapshots
         # for tests/dashboards — per-request truth travels on the result).
@@ -1927,8 +1938,8 @@ class Executor:
         if out is None and "_adaptive_key" in m:
             # A bounced attempt must still feed the router's DEVICE arm:
             # the serve falls through to host, but recording it as a
-            # host sample would leave device_n < 2 forever — the router
-            # would stay in its probe phase and re-pay the failed
+            # host sample would leave the device unmeasured forever — the
+            # router would stay in its probe phase and re-pay the failed
             # attempt (cache lookup, per-series filters, eligibility)
             # on every single query. Charged as device, the attempt+host
             # total can only measure >= the pure host arm, so a shape

@@ -7,8 +7,29 @@ this is the TPU-native generalization: the profitable path depends on the
 accelerator's dispatch latency, which varies by deployment (an attached
 chip ~us; a remote one ~ms). Instead of a static threshold,
 the router MEASURES both paths per query shape and serves from the winner,
-re-probing the loser on a fixed cadence so it adapts when conditions change
-(scan cache finishes building, data grows, dispatch latency shifts).
+re-probing the loser so it adapts when conditions change (scan cache
+finishes building, data grows, dispatch latency shifts).
+
+How a router keeps its estimate of a losing route (``_ProbeSchedule``,
+one rule for ``PathRouter`` and ``KernelRouter``):
+
+* Only a clean sample becomes an estimate. A request during which a
+  program compiled or the scan cache was built says nothing of a route's
+  speed: the executor reports it as not ``clean`` and it is dropped (up
+  to ``PROBE_EVERY`` in a row; a shape that compiles on every call really
+  is that slow, so the next one is folded whatever it carried).
+* A loser is re-probed by a share of serving time, not by a count of
+  calls: once the winner has served for ``PROBE_EVERY`` times the loser's
+  estimate since the loser was last sampled, each serve counted at the
+  winner's estimate. Re-measuring a loser then costs at most one part in
+  ``PROBE_EVERY + 1`` of the serving time whatever it lost by (at equal
+  times that is every 17th call, and never more often however the
+  samples spread; a loser 60x slower is probed every ~960 serves).
+* One sample is not trusted for long: a loser whose estimate rests on a
+  single sample is confirmed after ``PROBE_EVERY`` calls of the winner,
+  and moves to the budget above from its second sample on.
+* A winner that slows past the loser's estimate flips the route at once,
+  with no probe needed.
 
 Keyed by (table, select-statement shape): repeated dashboard/TSBS-style
 queries converge after one probe of each path. Latencies fold into an EWMA
@@ -25,8 +46,90 @@ import dataclasses
 import os
 import threading
 
-PROBE_EVERY = 16  # serve the winner; re-probe the loser every Nth call
+from ..utils.metrics import REGISTRY
+
+# serve the winner; a loser's re-probes get one part in PROBE_EVERY of the
+# time the winner serves
+PROBE_EVERY = 16
 MAX_KEYS = 512  # LRU bound on tracked query shapes
+
+# Eager registration: both routers' counters exist at 0 from the first
+# scrape, so a window without probes reads 0 and not "no such counter".
+_PROBES = {
+    router: REGISTRY.counter(
+        "horaedb_router_probes_total",
+        "requests a router sent down a losing route to re-measure it",
+        labels={"router": router},
+    )
+    for router in ("path", "kernel")
+}
+
+
+class _ProbeSchedule:
+    """The estimates and the probe schedule both routers share.
+
+    Per key, ``st["t"]`` maps a route to its estimated time, ``st["n"]``
+    to the samples folded into it, and ``st["since"]`` to what the winner
+    has served since the route was last sampled or last handed out as a
+    probe — counted in the route's own estimates, so it is due at
+    ``PROBE_EVERY`` (a route with one sample: counted in calls). The rule
+    has no unit and reads no clock: samples may be seconds (the query
+    routers) or seconds per row (engine/merge.py), and only their ratios
+    matter."""
+
+    router = ""  # the ``horaedb_router_probes_total`` label
+
+    def __init__(self) -> None:
+        self._stats: dict = {}
+        self._lock = threading.Lock()
+
+    def _touch(self, key) -> dict:
+        """stats entry for key, LRU-bumped; evicts the oldest past MAX_KEYS
+        (dicts preserve insertion order — re-inserting moves to the back)."""
+        st = self._stats.pop(key, None)
+        if st is None:
+            st = {"t": {}, "n": {}, "since": {}}
+            if len(self._stats) >= MAX_KEYS:
+                self._stats.pop(next(iter(self._stats)))
+        self._stats[key] = st
+        return st
+
+    @staticmethod
+    def _fold(st: dict, route: str, seconds: float) -> None:
+        """Fold one clean sample in. The estimate adapts DOWN instantly (a
+        faster time is proof the route can go that fast) and creeps UP by
+        10% per sample (one GC pause or dispatch hiccup must not flip the
+        route). A sample of the route that now wins is serving time, and
+        pays into every other route's probe budget — at the estimate just
+        folded, which is never more than the sample: one slow sample (a GC
+        pause, a wait for the GIL) cannot buy a probe, so no loser is
+        probed more often than one call in ``PROBE_EVERY + 1`` however the
+        samples spread. A loser with a single sample is paid a whole call:
+        whatever tainted that sample, it is confirmed after ``PROBE_EVERY``
+        calls, not after ``PROBE_EVERY`` times itself."""
+        times, n, since = st["t"], st["n"], st["since"]
+        prev = times.get(route)
+        est = times[route] = seconds if prev is None else min(seconds, prev * 1.1)
+        n[route] = n.get(route, 0) + 1
+        since[route] = 0.0
+        if all(est <= t for t in times.values()):
+            for k, t in times.items():
+                if k != route:
+                    since[k] += est / t if n[k] > 1 and t > 0 else 1.0
+
+    def _due_probe(self, st: dict, losers):
+        """The loser to re-measure on this call, or None: one whose budget
+        is full, the most overdue first. The budget is spent at hand-out,
+        so concurrent callers do not all take the probe while the first is
+        still in flight."""
+        since = st["since"]
+        due = [k for k in losers if since[k] >= PROBE_EVERY]
+        if not due:
+            return None
+        probe = max(due, key=since.get)
+        since[probe] = 0.0
+        _PROBES[self.router].inc()
+        return probe
 
 
 def plan_shape_key(plan) -> tuple:
@@ -55,62 +158,54 @@ def _shape(node):
     return node
 
 
-class PathRouter:
-    def __init__(self) -> None:
-        # key -> {"device": s, "host": s, "device_n": int, "calls": int}
-        self._stats: dict = {}
-        self._lock = threading.Lock()
-
-    def _touch(self, key) -> dict:
-        """stats entry for key, LRU-bumped; evicts the oldest past MAX_KEYS
-        (dicts preserve insertion order — re-inserting moves to the back)."""
-        st = self._stats.pop(key, None)
-        if st is None:
-            st = {"calls": 0}
-            if len(self._stats) >= MAX_KEYS:
-                self._stats.pop(next(iter(self._stats)))
-        self._stats[key] = st
-        return st
+class PathRouter(_ProbeSchedule):
+    router = "path"
 
     def choose(self, key) -> str:
         """"device" or "host".
 
-        Collects TWO device samples before judging: the first device
-        execution of a query shape pays jit trace+compile, and the second
-        typically absorbs the scan cache's deferred build (scan_cache
-        builds on the second sighting of a stable base state) — neither
-        reflects steady-state serving. Then one host sample, then the
-        measured winner with periodic probes of the loser.
+        "device" until the device holds one CLEAN sample: the first device
+        executions of a query shape pay jit trace+compile (the uncached
+        program, then the cached one) and the scan cache's deferred build
+        (scan_cache builds on the second sighting of a stable base state)
+        — none reflects steady-state serving, and ``record`` drops them.
+        Then one host sample, then the measured winner, with the loser
+        re-probed as its budget allows (``_due_probe``).
         """
         with self._lock:
             st = self._touch(key)
-            if st.get("device_n", 0) < 2:
+            times = st["t"]
+            if "device" not in times:
                 return "device"
-            if "host" not in st:
+            if "host" not in times:
                 return "host"
-            st["calls"] += 1
-            winner = "device" if st["device"] <= st["host"] else "host"
-            if st["calls"] % PROBE_EVERY == 0:
-                return "host" if winner == "device" else "device"
-            return winner
+            winner, loser = (
+                ("device", "host") if times["device"] <= times["host"]
+                else ("host", "device")
+            )
+            return self._due_probe(st, (loser,)) or winner
 
-    def record(self, key, kind: str, seconds: float) -> None:
-        """Fold a sample in: adapt DOWN instantly (a faster time is proof
-        the path can go that fast), creep UP by 10% per sample (one GC
-        pause or dispatch hiccup must not flip the route)."""
+    def record(self, key, kind: str, seconds: float, clean: bool = True) -> None:
+        """Fold a sample in (``_fold``). ``clean=False``: a program was
+        compiled or the scan cache built while the request ran — dropped,
+        unless ``PROBE_EVERY`` such samples of the route came in a row
+        before it."""
         with self._lock:
             st = self._touch(key)
-            prev = st.get(kind)
-            if kind == "device":
-                n = st.get("device_n", 0) + 1
-                st["device_n"] = n
-                if n == 2:
-                    prev = None  # drop the compile-tainted first sample
-            st[kind] = seconds if prev is None else min(seconds, prev * 1.1)
+            tainted = st.setdefault("tainted", {})
+            dropped = tainted.pop(kind, 0)  # in a row, of this route
+            if not clean and dropped < PROBE_EVERY:
+                tainted[kind] = dropped + 1
+                return
+            self._fold(st, kind, seconds)
 
     def stats(self, key) -> dict:
+        """{"device": s, "host": s, "n": {route: samples}, "since": {...}}."""
         with self._lock:
-            return dict(self._stats.get(key, {}))
+            st = self._stats.get(key)
+            if st is None:
+                return {}
+            return {**st["t"], "n": dict(st["n"]), "since": dict(st["since"])}
 
 
 def adaptive_enabled() -> bool:
@@ -140,7 +235,7 @@ def raw_adaptive_enabled() -> bool:
 # mxu one-hot matmul, scatter segment_* ops, hash slot table) and the
 # winner flips with group cardinality and skew (arXiv 2411.13245) — a
 # static import-time threshold leaves a regime on the table on every
-# deployment. Same EWMA + periodic-reprobe machinery as PathRouter, one
+# deployment. Same estimates and probe schedule as PathRouter, one
 # level down: keyed by (plan shape, segment-count bucket), choosing the
 # IMPL the jitted kernel branches on instead of the device/host path.
 # The first call of a shape is seeded from estimated group cardinality
@@ -208,28 +303,17 @@ def seed_kernel(n_seg: int, est_distinct, backend: str) -> str:
     return "scatter"
 
 
-class KernelRouter:
+class KernelRouter(_ProbeSchedule):
     """Per-(plan-shape, segment-bucket) EWMA over the segment impls.
 
     Same discipline as PathRouter: warm each candidate (dropping its
     compile-tainted first sample), serve the measured winner, re-probe
-    the losers round-robin every PROBE_EVERY-th call so the choice
-    adapts when conditions change. Also remembers the observed live
-    segment count per key — the feedback that sizes the hash slot table
-    and corrects a bad seed estimate."""
+    each loser as its own budget of serving time allows, the most overdue
+    first, so the choice adapts when conditions change. Also remembers
+    the observed live segment count per key — the feedback that sizes the
+    hash slot table and corrects a bad seed estimate."""
 
-    def __init__(self) -> None:
-        self._stats: dict = {}
-        self._lock = threading.Lock()
-
-    def _touch(self, key) -> dict:
-        st = self._stats.pop(key, None)
-        if st is None:
-            st = {"calls": 0, "n": {}, "t": {}}
-            if len(self._stats) >= MAX_KEYS:
-                self._stats.pop(next(iter(self._stats)))
-        self._stats[key] = st
-        return st
+    router = "kernel"
 
     def choose(self, key, seed: str, candidates: tuple):
         """The impl to dispatch this call with; None when there is none to
@@ -237,47 +321,41 @@ class KernelRouter:
         of them for this key (``refuse``)."""
         with self._lock:
             st = self._touch(key)
-            st["calls"] += 1
             refused = st.get("refused", ())
             candidates = tuple(k for k in candidates if k not in refused)
             if not candidates:
                 return None
-            samples, times = st["n"], st["t"]
-            order = [seed] + [k for k in candidates if k != seed]
-            for k in order:
+            times = st["t"]
+            for k in [seed] + [k for k in candidates if k != seed]:
                 # two samples each: the first pays jit trace+compile and
                 # is dropped by record() — judging needs a clean one
-                if k in candidates and samples.get(k, 0) < 2:
+                if k in candidates and k not in times:
                     return k
-            measured = {k: times[k] for k in candidates if k in times}
-            if not measured:
-                return seed if seed in candidates else candidates[0]
-            winner = min(measured, key=measured.get)
-            if st["calls"] % PROBE_EVERY == 0:
-                losers = [k for k in candidates if k != winner]
-                if losers:
-                    return losers[(st["calls"] // PROBE_EVERY) % len(losers)]
-            return winner
+            winner = min(candidates, key=times.get)
+            losers = [k for k in candidates if k != winner]
+            return self._due_probe(st, losers) or winner
 
     def record(self, key, kernel: str, seconds: float) -> None:
-        """Fold a dispatch latency in: adapt DOWN instantly, creep UP by
-        10% per sample; the first sample of each impl (compile-tainted)
-        only counts, never judges."""
+        """Fold a dispatch latency in (``_fold``); the first sample of each
+        impl (compile-tainted) only counts, never judges."""
         with self._lock:
             st = self._touch(key)
-            n = st["n"][kernel] = st["n"].get(kernel, 0) + 1
-            if n == 1:
-                return  # compile-tainted
-            prev = st["t"].get(kernel)
-            st["t"][kernel] = (
-                seconds if prev is None else min(seconds, prev * 1.1)
-            )
+            warmed = st.setdefault("warmed", set())
+            if kernel not in warmed or kernel in st.get("refused", ()):
+                # compile-tainted; or refused while this one was in flight
+                warmed.add(kernel)
+                return
+            self._fold(st, kernel, seconds)
 
     def refuse(self, key, kernel: str) -> None:
         """The device refused ``kernel``'s program for this key (no room in
-        HBM): never offer it for the key again."""
+        HBM): never offer it for the key again, and drop its estimate —
+        a route that cannot serve is no winner for ``_fold`` to defer to."""
         with self._lock:
-            self._touch(key).setdefault("refused", set()).add(kernel)
+            st = self._touch(key)
+            st.setdefault("refused", set()).add(kernel)
+            for per_route in (st["t"], st["n"], st["since"]):
+                per_route.pop(kernel, None)
 
     def note_segments(self, key, live: int) -> None:
         """Observed live (group x bucket) cells — EWMA'd so the hash

@@ -370,6 +370,14 @@ _seen_kernel_keys: set = set()
 _kernel_lock = threading.Lock()
 
 
+def kernel_compiles() -> int:
+    """Programs this process has compiled so far. A request that reads it
+    when it starts and again when it ends knows whether one compiled while
+    it ran — with device telemetry off and no ledger in scope, which
+    ``compile_hit`` and ``jit_compiles`` both need."""
+    return len(_seen_kernel_keys)
+
+
 def note_kernel_dispatch(key, elapsed_s: float, kind: str = "",
                          cost_fn=None) -> None:
     """Account one device-kernel dispatch: a never-seen static ``key``

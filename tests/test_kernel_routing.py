@@ -270,13 +270,25 @@ class TestKernelRouter:
         for i in range(2 * len(cands)):
             k = r.choose("key", "scatter", cands)
             r.record("key", k, 0.01 if k == "scatter" else 0.05)
-        picks = []
-        for i in range(2 * PROBE_EVERY):
-            k = r.choose("key", "scatter", cands)
-            picks.append(k)
-            r.record("key", k, 0.01 if k == "scatter" else 0.05)
-        assert picks.count("hash") >= 1  # losers still get probed
-        assert picks.count("scatter") > picks.count("hash")
+        def serve(calls):
+            picks = []
+            for i in range(calls):
+                k = r.choose("key", "scatter", cands)
+                picks.append(k)
+                r.record("key", k, 0.01 if k == "scatter" else 0.05)
+            return picks
+
+        # hash's one clean sample is confirmed after PROBE_EVERY calls ...
+        assert serve(PROBE_EVERY + 1) == ["scatter"] * PROBE_EVERY + ["hash"]
+        # ... then the cadence is served time, not calls: hash (5 x slower) is
+        # due once scatter has served PROBE_EVERY x 0.05 s, every 81st call
+        picks = serve(2 * (5 * PROBE_EVERY + 2))
+        assert picks.count("hash") == 2  # losers still get probed
+        assert "hash" not in picks[:5 * PROBE_EVERY - 1]
+        # the probes cost one part in PROBE_EVERY + 1 of the served seconds
+        assert picks.count("hash") * 0.05 <= (
+            picks.count("scatter") * 0.01 + picks.count("hash") * 0.05
+        ) / (PROBE_EVERY + 1) + 0.05
 
     def test_lru_bound(self):
         from horaedb_tpu.query.path_router import MAX_KEYS, KernelRouter

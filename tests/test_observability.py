@@ -851,6 +851,75 @@ class TestPrometheusContentType:
         with_client(body)
 
 
+class TestRouterProbeCounter:
+    """``horaedb_router_probes_total{router=path|kernel}``: the probe
+    schedule's counter (query/path_router.py)."""
+
+    LINES = {
+        r: f'horaedb_router_probes_total{{router="{r}"}}'
+        for r in ("path", "kernel")
+    }
+
+    def test_both_labelsets_export_zero_from_process_start(self):
+        """A window without probes must read 0, not a missing series: both
+        labelsets exist once the module is imported, before any probe."""
+        import os
+        import subprocess
+        import sys
+
+        out = subprocess.run(
+            [sys.executable, "-c",
+             "import horaedb_tpu.query.path_router\n"
+             "from horaedb_tpu.utils.metrics import REGISTRY\n"
+             "print(REGISTRY.expose())"],
+            capture_output=True, text=True, timeout=120,
+            cwd=os.path.join(os.path.dirname(__file__), ".."),
+            env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        )
+        assert out.returncode == 0, out.stderr
+        for line in self.LINES.values():
+            assert f"{line} 0.0" in out.stdout.splitlines()
+
+    def test_metrics_counts_one_per_hand_out(self):
+        from horaedb_tpu.query.path_router import (
+            PROBE_EVERY,
+            KernelRouter,
+            PathRouter,
+        )
+
+        def read(text):
+            return {
+                r: float(next(
+                    ln for ln in text.splitlines() if ln.startswith(line)
+                ).split()[-1])
+                for r, line in self.LINES.items()
+            }
+
+        async def body(client):
+            before = read(await (await client.get("/metrics")).text())
+            path, kernel = PathRouter(), KernelRouter()
+            for kind in ("device", "device", "host"):
+                path.record("k", kind, 1.0 if kind == "device" else 2.0)
+            for impl in ("scatter", "scatter", "hash", "hash"):
+                kernel.record("k", impl, 1.0 if impl == "scatter" else 2.0)
+            handed = {"path": [], "kernel": []}
+            for _ in range(2 * PROBE_EVERY + 1):
+                handed["path"].append(path.choose("k"))
+                path.record("k", handed["path"][-1], 1.0)
+                handed["kernel"].append(
+                    kernel.choose("k", "scatter", ("scatter", "hash"))
+                )
+                kernel.record("k", handed["kernel"][-1], 1.0)
+            assert handed["path"].count("host") == 1
+            assert handed["kernel"].count("hash") == 1
+            after = read(await (await client.get("/metrics")).text())
+            assert {r: after[r] - before[r] for r in after} == {
+                "path": 1.0, "kernel": 1.0,
+            }
+
+        with_client(body)
+
+
 class TestWireProtocolLatency:
     """Front-end parity: MySQL and PostgreSQL record request-latency
     histograms in the same labeled family the HTTP path uses."""

@@ -903,16 +903,31 @@ class TestAdaptivePathRouting:
 
         r = PathRouter()
         key = ("t", "shape")
-        # collects two device samples (compile + steady) then one host
+        # "device" until it holds a clean sample, then one host sample
         assert r.choose(key) == "device"
-        r.record(key, "device", 2.3)  # jit-compile-tainted
+        r.record(key, "device", 2.3, clean=False)  # jit-compile-tainted
         assert r.choose(key) == "device"
-        r.record(key, "device", 0.080)  # steady: replaces the first
+        r.record(key, "device", 0.080)  # steady
         assert r.choose(key) == "host"
         r.record(key, "host", 0.002)
-        picks = [r.choose(key) for _ in range(PROBE_EVERY * 2)]
-        assert picks.count("host") >= PROBE_EVERY * 2 - 3
-        assert "device" in picks  # loser is still re-probed
+        lat = {"host": 0.002, "device": 0.080}
+
+        def serve(calls):
+            picks = []
+            for _ in range(calls):
+                picks.append(r.choose(key))
+                r.record(key, picks[-1], lat[picks[-1]])
+            return picks
+
+        # the loser's one sample is confirmed after PROBE_EVERY calls of the
+        # winner (the host's first sample was one of them) ...
+        assert serve(PROBE_EVERY) == ["host"] * (PROBE_EVERY - 1) + ["device"]
+        # ... and from its second sample on it (40 x slower) is re-probed once
+        # the winner has SERVED PROBE_EVERY x its time: 16 x 0.080 s = 640
+        # host serves
+        picks = serve(2 * (PROBE_EVERY * 40 + 2))
+        assert "device" not in picks[:PROBE_EVERY * 40 - 1]
+        assert picks.count("device") == 2  # loser is still re-probed
         assert r.stats(key)["device"] == 0.080  # compile sample dropped
 
     def test_router_adapts_when_loser_improves(self):

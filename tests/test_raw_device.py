@@ -355,8 +355,9 @@ class TestRawKillSwitchAndRouting:
         assert "horaedb_raw_scan_total" in text
 
     def test_learned_routing_probes_then_serves(self, db, monkeypatch):
-        """With routing enabled the PathRouter warms device (2 probes),
-        samples host once, then serves the measured winner."""
+        """With routing enabled the PathRouter serves device until it holds
+        a clean sample (no compile, no cache build), samples host once, then
+        serves the measured winner."""
         monkeypatch.setenv("HORAEDB_ADAPTIVE_PATH", "1")
         _seed(db, n=150)
         sql = "SELECT host, v FROM rd WHERE v < 60 ORDER BY ts DESC LIMIT 5"
@@ -369,7 +370,7 @@ class TestRawKillSwitchAndRouting:
 
         plan = db.frontend.statement_to_plan(db.frontend.parse_sql(sql))
         st = db.interpreters.executor.path_router.stats(plan_shape_key(plan))
-        assert st.get("device_n", 0) >= 2 and "host" in st
+        assert "device" in st and "host" in st
 
     def test_persistent_fallback_converges_to_host(self, db, monkeypatch):
         """Review regression: a shape whose device attempt always
@@ -396,7 +397,7 @@ class TestRawKillSwitchAndRouting:
         # both arms sampled -> the router can judge instead of probing
         # device-first forever (timing RATIOS are host jitter — the
         # convergence property is that both estimates exist)
-        assert st.get("device_n", 0) >= 2 and "host" in st
+        assert "device" in st and "host" in st
 
     def test_ledger_and_query_stats_cover_raw(self, db):
         from horaedb_tpu.proxy import Proxy
