@@ -104,6 +104,38 @@ class Server:
         self._loop.close()
 
 
+def _rows_agree(a: list, b: list, rtol: float = 1e-3, atol: float = 1e-3) -> bool:
+    if len(a) != len(b):
+        return False
+
+    # Row order is unspecified without ORDER BY; canonicalize before the
+    # pairwise numeric comparison. Sort by the exact-typed fields (group
+    # keys) first — float aggregates differ slightly between paths and
+    # must not drive the pairing.
+    def key(row):
+        exact = tuple(
+            (k, v) for k, v in sorted(row.items()) if not isinstance(v, float)
+        )
+        approx = tuple(
+            (k, round(v, 4)) for k, v in sorted(row.items()) if isinstance(v, float)
+        )
+        return (exact, approx)
+
+    a = sorted(a, key=key)
+    b = sorted(b, key=key)
+    for ra, rb in zip(a, b):
+        if set(ra) != set(rb):
+            return False
+        for k in ra:
+            va, vb = ra[k], rb[k]
+            if isinstance(va, float) or isinstance(vb, float):
+                if not np.isclose(va, vb, rtol=rtol, atol=atol, equal_nan=True):
+                    return False
+            elif va != vb:
+                return False
+    return True
+
+
 @contextlib.contextmanager
 def host_executor(ex):
     """Force the numpy host executor — the plain reference. Aggregates
@@ -140,8 +172,6 @@ def device_first(ex):
 def run_statement(server, ex, checks, name, sql, device_path, repeats=REPEATS):
     """``repeats`` default-routed serves over HTTP + one host-executor
     serve; -> the per-repeat records."""
-    from bench import _rows_agree
-
     reps, answers = [], []
 
     def serve(**note) -> dict:
@@ -210,7 +240,6 @@ def run_statement(server, ex, checks, name, sql, device_path, repeats=REPEATS):
 def write_phase(server, ex, checks, seed: int) -> None:
     """Two acknowledged /write batches above the table's maximum
     timestamp, then read every row back."""
-    from bench import _rows_agree
     from horaedb_tpu.tools import tsbs
 
     rng = np.random.default_rng(seed + 1)

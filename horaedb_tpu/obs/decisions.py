@@ -40,8 +40,7 @@ Surfaces: ``system.public.decisions`` + ``system.public.calibration``
 EXPLAIN ANALYZE ``Decision:`` line, and the registry-linted
 ``horaedb_decision_*`` / ``horaedb_calibration_*`` families.
 ``HORAEDB_DECISIONS=0`` turns the plane off (record returns 0,
-resolve(0) is a no-op); the ``BENCH_CONFIG=decisions`` gate pins
-journal-on within 2% of off on the flood shape.
+resolve(0) is a no-op).
 """
 
 from __future__ import annotations

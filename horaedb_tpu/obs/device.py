@@ -41,8 +41,7 @@ Four legs:
    other family.
 
 ``HORAEDB_DEVICE_TELEMETRY=0`` turns the whole plane off (dispatch
-wrappers become bare calls); the overhead budget with it ON is <2% on
-the groupby/rawscan benches (``BENCH_CONFIG=devicetel`` gates it).
+wrappers become bare calls).
 """
 
 from __future__ import annotations

@@ -27,7 +27,6 @@ mesh (psum), and final agg after dedup.
 from __future__ import annotations
 
 import functools
-import os
 from dataclasses import dataclass
 from typing import Sequence
 
@@ -35,7 +34,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..utils.env import env_int
 from .encoding import (
     PaddedBatch,
     decode_layouts as _decode_layouts,
@@ -48,18 +46,17 @@ AGG_OPS = ("count", "sum", "min", "max", "avg")
 # Numeric filter ops, by static code (part of the jit cache key).
 _FILTER_OPS = {"=": 0, "!=": 1, "<": 2, "<=": 3, ">": 4, ">=": 5}
 
-# The three segment-reduction implementations. TPU scatter
-# (segment_sum/min/max) is serialized and slow (~10-20ms/M rows measured
-# on v5e); for small-to-medium segment counts a one-hot matmul rides the
-# MXU and a fused masked broadcast-reduce handles min/max — 5-100x
-# faster. Above the threshold the matmul's O(N*n_seg) work loses to
-# scatter's O(N). The hash impl (ops/hash_agg.py) aggregates through a
-# small slot table first — the winner when the rows present touch far
-# fewer segments than the domain holds (low cardinality, heavy skew).
-# Which impl serves a query is decided per (plan shape, segment bucket)
-# by the learned router (query/path_router.KernelRouter); the spec's
-# ``segment_impl`` carries the choice into the jit cache key.
-SEGMENT_KERNELS = ("mxu", "scatter", "hash")
+# The segment-reduction kernels, timed on the v5e (PERF.md §5-6): the
+# scatter sorts (segment, iota) and costs 7-11 ns a row whatever is moved
+# (two scatter-adds are 146 of 158.5 ms at 2^23 rows x 16,384 segments, 651
+# of 721.7 ms at 2^25 x 65,536); the one-hot matmul rides the MXU with
+# O(N * n_seg) work (2.494 ms against the scatter's 2.667 on a gathered
+# 64-segment subset, 480.4 against 158.5 at 16,384 segments). ``single``
+# is the plain reduction of one segment (1.868 ms at 2^23 rows). Which one
+# serves a query is decided in query/kernel_choice.py and nowhere else: the
+# spec's ``segment_impl`` carries the choice into the jit cache key, and
+# every entry below takes the name as given.
+SEGMENT_KERNELS = ("mxu", "scatter")
 # f32 one-hot counts are exact up to 2^24 rows per segment; beyond that the
 # count matvec runs in row chunks with int32 accumulation between chunks.
 _COUNT_CHUNK = 1 << 24
@@ -72,42 +69,16 @@ _MINMAX_MASK_BYTES = 1 << 28
 _SCATTER_TILE_BYTES = 1 << 25
 
 
-def pinned_segment_impl() -> str:
-    """The HORAEDB_SEGMENT_IMPL kill switch: pins ONE static impl for
-    every query shape (exists to bisect lowerings — the override must
-    cover every shape, including global aggregates). Empty string means
-    auto. Read per call so tests/operators can flip it live."""
-    v = os.environ.get("HORAEDB_SEGMENT_IMPL", "auto")
-    return v if v in SEGMENT_KERNELS else ""
-
-
-def mxu_max_segments() -> int:
-    """Static auto-heuristic crossover (measured ~8-16k segments at 1M
-    rows). Guarded: a malformed value degrades to the default instead of
-    aborting import."""
-    return env_int("HORAEDB_MXU_MAX_SEGMENTS", 8192)
-
-
-def resolve_segment_impl(n_seg: int, requested: str = "auto") -> str:
-    """Which impl a kernel trace will take for ``n_seg`` — "single",
-    "mxu", "scatter" or "hash". Host-side mirror of the in-trace branch
-    (deterministic: static args + backend only), so the router and the
-    ledger can name the kernel without re-deriving the rules."""
-    pinned = pinned_segment_impl()
-    if pinned:
-        return pinned
-    if n_seg == 1:
-        # Global aggregate: both scatter (4 scalarized segment_* ops)
-        # and MXU (a width-1 one-hot matmul) waste passes; four
-        # streaming reduces are the bandwidth floor.
-        return "single"
-    if requested in SEGMENT_KERNELS:
-        return requested
-    return (
-        "mxu"
-        if jax.default_backend() == "tpu" and n_seg <= mxu_max_segments()
-        else "scatter"
-    )
+def concrete_impl(segment_impl: str) -> str:
+    """``segment_impl`` if it names a kernel, else ValueError: the kernels
+    run the chooser's answer (``query/kernel_choice.choose``) and derive
+    none themselves."""
+    if segment_impl != "single" and segment_impl not in SEGMENT_KERNELS:
+        raise ValueError(
+            f"segment_impl {segment_impl!r}: a kernel takes one of "
+            f"{('single',) + SEGMENT_KERNELS}, as query/kernel_choice names it"
+        )
+    return segment_impl
 
 
 @dataclass(frozen=True)
@@ -122,15 +93,12 @@ class ScanAggSpec:
     # False when no min/max aggregate is requested: the kernel skips the
     # min/max reductions entirely and returns zeros in their slots.
     need_minmax: bool = True
-    # Segment-reduction impl for this dispatch: "auto" (static
-    # heuristic) or one of SEGMENT_KERNELS as chosen by the learned
-    # router. Static jit arg — the chosen kernel IS part of the compile
-    # cache key, on the direct, cached, and shard_map dist paths alike.
+    # Segment-reduction impl for this dispatch: "auto" until
+    # ``query/kernel_choice.choose`` names "single" or one of
+    # SEGMENT_KERNELS — no kernel runs an unchosen spec. Static jit arg:
+    # the chosen kernel IS part of the compile cache key, on the direct,
+    # cached, and shard_map dist paths alike.
     segment_impl: str = "auto"
-    # Hash-impl slot-table size (power of 2; 0 = derive from n_seg).
-    # Sized from the router's cardinality estimate, bucketed to powers
-    # of two so it mints a bounded number of jit keys.
-    hash_slots: int = 0
     # Compressed-layout descriptors (ops.encoding, ISSUE 19). Static and
     # hashable: flipping a column's layout re-keys the trace, exactly like
     # a segment-impl change. () / ("raw",) are the legacy dense layouts.
@@ -152,7 +120,6 @@ class ScanAggSpec:
             numeric_filters=self.numeric_filters,
             need_minmax=self.need_minmax,
             segment_impl=self.segment_impl,
-            hash_slots=self.hash_slots,
             value_layouts=self.value_layouts,
             ts_layout=self.ts_layout,
             series_layout=self.series_layout,
@@ -289,9 +256,8 @@ def segment_row_chunks(impl: str, n_rows: int, n_seg: int, n_fields: int,
                        need_minmax: bool) -> int:
     """How many row chunks ``impl``'s segment reduction runs ``n_rows`` in
     (1: in one piece) — the host-side mirror of the in-trace cuts, for the
-    ``dispatch`` span's ``chunks`` attribute. ``hash`` is cut where its
-    overflow fallback, the scatter impl, is."""
-    if impl in ("scatter", "hash"):
+    ``dispatch`` span's ``chunks`` attribute."""
+    if impl == "scatter":
         return max(1, -(-n_rows // scatter_chunk_rows(n_fields)))
     if impl == "mxu" and need_minmax and n_fields:
         return max(1, -(-n_rows // max(_MINMAX_MASK_BYTES // n_seg, 128)))
@@ -302,12 +268,11 @@ def segment_temp_bytes(impl: str, n_rows: int, n_seg: int, n_fields: int,
                        need_minmax: bool) -> int:
     """HBM ``impl``'s segment reduction needs beside the resident columns, from
     above: what the policy holds against the device's free memory before it
-    offers the impl (``query/path_router.candidate_kernels``). Every impl
+    offers the impl (``query/kernel_choice.candidate_kernels``). Every impl
     keeps a handful of row-length vectors (segment ids, mask, a scatter's
     sort keys and permutation); the scatter holds one update tile and one
     accumulator (in and out) per reduction, each padded to 128 lanes; the MXU
-    impl its min/max match mask; the hash impl its probe's row vectors and
-    its overflow fallback, the scatter. ``tests/test_tpu_compile.py`` holds it
+    impl its min/max match mask. ``tests/test_tpu_compile.py`` holds it
     against the v5e compiler's own accounting at 2^21 and 2^25 rows."""
     row_vectors = 8 * 4 * n_rows
     if impl == "single":
@@ -316,8 +281,6 @@ def segment_temp_bytes(impl: str, n_rows: int, n_seg: int, n_fields: int,
         return row_vectors + (
             2 * _MINMAX_MASK_BYTES if need_minmax and n_fields else 0
         )
-    if impl == "hash":
-        row_vectors *= 2
     reductions = (3 if need_minmax else 1) if n_fields else 0
     row_bytes = _tile_row_bytes(n_fields)
     tile = min(n_rows, scatter_chunk_rows(n_fields)) * row_bytes
@@ -413,8 +376,7 @@ def scan_agg_body(
     n_agg_fields: int,
     numeric_filters: tuple[tuple[int, int], ...] = (),
     need_minmax: bool = True,
-    segment_impl: str = "auto",
-    hash_slots: int = 0,
+    segment_impl: str,
 ):
     """Pure kernel body — also the per-shard program inside shard_map
     (parallel/dist_agg.py wraps it with psum/pmin/pmax collectives)."""
@@ -445,29 +407,11 @@ def scan_agg_body(
         agg_vals = jnp.stack(values[:n_agg_fields]) if n_agg_fields else None
     else:
         agg_vals = values[:n_agg_fields] if n_agg_fields else None
-    # Dispatch entry points (scan_aggregate, the executor's cached-packed
-    # call, dist_agg's step builders) resolve the impl ON HOST and pass
-    # the concrete name as this static arg — so flipping the env pin /
-    # threshold mints a NEW jit key instead of silently reusing a warm
-    # trace. The in-body resolve below is only a safety net for callers
-    # that still pass "auto" (identity for concrete names).
-    impl_name = (
-        segment_impl
-        if segment_impl in ("single",) + SEGMENT_KERNELS
-        else resolve_segment_impl(n_seg, segment_impl)
-    )
-    with jax.named_scope("segment_" + impl_name):
-        if impl_name == "single":
+    with jax.named_scope("segment_" + concrete_impl(segment_impl)):
+        if segment_impl == "single":
             counts, sums, mins, maxs = _single_segment_agg(m, agg_vals, need_minmax)
-        elif impl_name == "hash":
-            from .hash_agg import default_hash_slots, hash_segment_agg
-
-            counts, sums, mins, maxs = hash_segment_agg(
-                seg_raw, m, agg_vals, n_seg, need_minmax,
-                hash_slots or default_hash_slots(n_seg),
-            )
         else:
-            impl = _mxu_segment_agg if impl_name == "mxu" else _scatter_segment_agg
+            impl = _mxu_segment_agg if segment_impl == "mxu" else _scatter_segment_agg
             counts, sums, mins, maxs = impl(seg_raw, m, agg_vals, n_seg, need_minmax)
 
     counts = counts.reshape(n_groups, n_buckets)
@@ -489,7 +433,7 @@ _fused_scan_agg = functools.partial(
     jax.jit,
     static_argnames=(
         "n_groups", "n_buckets", "n_agg_fields", "numeric_filters",
-        "need_minmax", "segment_impl", "hash_slots",
+        "need_minmax", "segment_impl",
     ),
 )(scan_agg_body)
 
@@ -511,8 +455,7 @@ def cached_scan_agg_body(
     n_agg_fields: int,
     numeric_filters: tuple[tuple[int, int], ...],
     need_minmax: bool = True,
-    segment_impl: str = "auto",
-    hash_slots: int = 0,
+    segment_impl: str,
     value_layouts: tuple = (),
     ts_layout: tuple = ("raw",),
     series_layout: tuple = ("raw",),
@@ -567,69 +510,6 @@ def cached_scan_agg_body(
         numeric_filters=numeric_filters,
         need_minmax=need_minmax,
         segment_impl=segment_impl,
-        hash_slots=hash_slots,
-    )
-
-
-cached_scan_agg = functools.partial(
-    jax.jit,
-    static_argnames=(
-        "n_groups", "n_buckets", "n_agg_fields", "numeric_filters",
-        "need_minmax", "segment_impl", "hash_slots",
-        "value_layouts", "ts_layout", "series_layout",
-    ),
-)(cached_scan_agg_body)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "n_groups", "n_buckets", "n_agg_fields", "numeric_filters",
-        "need_minmax", "segment_impl", "hash_slots",
-    ),
-)
-def selective_cached_scan_agg(
-    row_idx,  # int32[M] indices into the resident arrays (pad -> pad row)
-    series_codes,
-    ts_rel,
-    values,
-    group_of_series,
-    allowed_series,
-    literals,
-    lo_rel,
-    hi_rel,
-    t0_rel,
-    bucket_ms,
-    *,
-    n_groups: int,
-    n_buckets: int,
-    n_agg_fields: int,
-    numeric_filters: tuple[tuple[int, int], ...],
-    need_minmax: bool = True,
-    segment_impl: str = "auto",
-    hash_slots: int = 0,
-):
-    """Cached kernel over a GATHERED subset of the resident rows.
-
-    The cache layout is sorted by (series, ts), so a selective query — a
-    few series out of thousands, the TSBS single-groupby shape — touches
-    only its series' contiguous ranges: the host ships an M-row index
-    (M << N), the device gathers from HBM and aggregates. Full scans keep
-    the plain ``cached_scan_agg``; the executor picks by selectivity.
-    """
-    sc = series_codes[row_idx]
-    tr = ts_rel[row_idx]
-    vals = values[:, row_idx]
-    return cached_scan_agg_body(
-        sc, tr, vals, group_of_series, allowed_series, literals,
-        lo_rel, hi_rel, t0_rel, bucket_ms,
-        n_groups=n_groups,
-        n_buckets=n_buckets,
-        n_agg_fields=n_agg_fields,
-        numeric_filters=numeric_filters,
-        need_minmax=need_minmax,
-        segment_impl=segment_impl,
-        hash_slots=hash_slots,
     )
 
 
@@ -693,8 +573,7 @@ def _packed_body(
     n_agg_fields: int,
     numeric_filters: tuple[tuple[int, int], ...],
     need_minmax: bool,
-    segment_impl: str = "auto",
-    hash_slots: int = 0,
+    segment_impl: str,
     selective: bool = False,
     value_layouts: tuple = (),
     ts_layout: tuple = ("raw",),
@@ -723,7 +602,6 @@ def _packed_body(
         numeric_filters=numeric_filters,
         need_minmax=need_minmax,
         segment_impl=segment_impl,
-        hash_slots=hash_slots,
         value_layouts=value_layouts,
         ts_layout=ts_layout,
         series_layout=series_layout,
@@ -745,7 +623,7 @@ def _f32_bits(x):
 
 _PACKED_STATIC = (
     "n_groups", "n_buckets", "n_agg_fields", "numeric_filters",
-    "need_minmax", "segment_impl", "hash_slots", "selective",
+    "need_minmax", "segment_impl", "selective",
     "value_layouts", "ts_layout", "series_layout",
 )
 _packed_programs: dict[str, object] = {}
@@ -755,7 +633,9 @@ def packed_program_name(segment_impl: str, selective: bool) -> str:
     """What the device trace calls the packed cached scan of one concrete
     segment impl: ``XLA Modules`` reads ``jit_cached_scan_mxu(...)``,
     ``jit_cached_scan_scatter_sel(...)`` (``_sel``: the gathered subset)."""
-    return f"cached_scan_{segment_impl}" + ("_sel" if selective else "")
+    return f"cached_scan_{concrete_impl(segment_impl)}" + (
+        "_sel" if selective else ""
+    )
 
 
 def _packed_program(name: str):
@@ -776,26 +656,21 @@ def _packed_program(name: str):
 
 
 def _pick_packed(kwargs: dict):
-    """-> (the named program, kwargs with the segment impl made concrete)."""
-    impl = resolve_segment_impl(
-        kwargs["n_groups"] * kwargs["n_buckets"],
-        kwargs.get("segment_impl", "auto"),
-    )
-    name = packed_program_name(impl, bool(kwargs.get("selective", False)))
-    return _packed_program(name), {**kwargs, "segment_impl": impl}
+    """The program named for the statics' (segment impl, selective)."""
+    return _packed_program(packed_program_name(
+        kwargs["segment_impl"], bool(kwargs.get("selective", False))
+    ))
 
 
 def cached_scan_agg_packed(*args, **kwargs):
     """The packed serving kernel's entry: picks the program named for the
     statics' (segment impl, selective) and calls it. ``.lower`` does the
     same for ahead-of-time compiles and ``cost_analysis``."""
-    program, kwargs = _pick_packed(kwargs)
-    return program(*args, **kwargs)
+    return _pick_packed(kwargs)(*args, **kwargs)
 
 
 def _lower_packed(*args, **kwargs):
-    program, kwargs = _pick_packed(kwargs)
-    return program.lower(*args, **kwargs)
+    return _pick_packed(kwargs).lower(*args, **kwargs)
 
 
 cached_scan_agg_packed.lower = _lower_packed
@@ -813,8 +688,7 @@ def _cohort_body(
     n_agg_fields: int,
     numeric_filters: tuple[tuple[int, int], ...],
     need_minmax: bool,
-    segment_impl: str = "auto",
-    hash_slots: int = 0,
+    segment_impl: str,
     value_layouts: tuple = (),
     ts_layout: tuple = ("raw",),
     series_layout: tuple = ("raw",),
@@ -834,7 +708,6 @@ def _cohort_body(
         numeric_filters=numeric_filters,
         need_minmax=need_minmax,
         segment_impl=segment_impl,
-        hash_slots=hash_slots,
         selective=False,
         value_layouts=value_layouts,
         ts_layout=ts_layout,
@@ -849,7 +722,7 @@ cached_scan_agg_cohort = functools.partial(
     jax.jit,
     static_argnames=(
         "n_groups", "n_buckets", "n_agg_fields", "numeric_filters",
-        "need_minmax", "segment_impl", "hash_slots",
+        "need_minmax", "segment_impl",
         "value_layouts", "ts_layout", "series_layout",
     ),
 )(_cohort_body)
@@ -907,30 +780,10 @@ def scan_aggregate(
     """
     import time as _time
 
+    from ..obs.device import cost_analysis, timed_dispatch
     from ..utils.querystats import note_kernel_dispatch
 
-    # Host-side impl resolution: the CONCRETE kernel name becomes the
-    # static jit arg, so a live flip of HORAEDB_SEGMENT_IMPL /
-    # HORAEDB_MXU_MAX_SEGMENTS re-keys (and re-traces) warm shapes
-    # instead of silently serving the stale compiled branch.
-    impl = resolve_segment_impl(
-        spec.n_groups * spec.n_buckets, spec.segment_impl
-    )
-
-    # Router-chosen hash route, tiny input: a device dispatch costs more
-    # than the aggregation — exact f64 numpy serves it instead. Never
-    # taken under the HORAEDB_SEGMENT_IMPL kill switch (pinning exists
-    # to bisect device lowerings, so it must actually run them).
-    if (
-        impl == "hash"
-        and not pinned_segment_impl()
-        and batch.n_valid <= env_int("HORAEDB_HASH_HOST_MAX_ROWS", 4096)
-    ):
-        from .hash_agg import host_scan_aggregate
-
-        return host_scan_aggregate(batch, spec, filter_literals)
-
-    from ..obs.device import cost_analysis, timed_dispatch
+    impl = concrete_impl(spec.segment_impl)
 
     args = (
         jnp.asarray(batch.group_codes),
@@ -946,7 +799,6 @@ def scan_aggregate(
         numeric_filters=encode_filter_ops(spec.numeric_filters),
         need_minmax=spec.need_minmax,
         segment_impl=impl,
-        hash_slots=spec.hash_slots,
     )
     t0 = _time.perf_counter()
     counts, sums, mins, maxs = timed_dispatch(
@@ -960,8 +812,7 @@ def scan_aggregate(
     # flops/bytes under HORAEDB_DEVICE_COST_ANALYSIS=1).
     note_kernel_dispatch(
         ("fused", batch.values.shape, spec.n_groups, spec.n_buckets,
-         spec.n_agg_fields, spec.numeric_filters, spec.need_minmax,
-         impl, spec.hash_slots),
+         spec.n_agg_fields, spec.numeric_filters, spec.need_minmax, impl),
         _time.perf_counter() - t0,
         kind="fused",
         cost_fn=lambda: cost_analysis(_fused_scan_agg, args, kwargs),

@@ -24,7 +24,7 @@ only ROW INDICES:
 - **bounded selection**: cumsum + ``searchsorted`` compaction of every
   passing row id into a ``HORAEDB_RAW_MAX_ROWS``-bounded buffer (the
   scatter formulation costs ~13x more on XLA-CPU — scatter is the
-  priced primitive, see ops/hash_agg.py). The executor only dispatches
+  priced primitive, see ops/scan_agg.py). The executor only dispatches
   it when the (exact, host-computed) candidate bound fits the buffer,
   so the compaction can never truncate silently.
 

@@ -31,6 +31,7 @@ from ..ops.scan_agg import (
     ScanAggSpec,
     cached_scan_agg_body,
     coerce_literals,
+    concrete_impl,
     encode_filter_ops,
     scan_agg_body,
     state_to_host,
@@ -65,23 +66,6 @@ def _combine(state):
     )
 
 
-def _resolved(spec: ScanAggSpec) -> ScanAggSpec:
-    """Resolve the segment impl ON HOST so the concrete kernel name is
-    what keys the step cache and the jit trace — a live flip of
-    HORAEDB_SEGMENT_IMPL / HORAEDB_MXU_MAX_SEGMENTS re-keys warm shapes
-    instead of silently serving the stale compiled branch."""
-    import dataclasses
-
-    from ..ops.scan_agg import resolve_segment_impl
-
-    impl = resolve_segment_impl(
-        spec.n_groups * spec.n_buckets, spec.segment_impl
-    )
-    if impl == spec.segment_impl:
-        return spec
-    return dataclasses.replace(spec, segment_impl=impl)
-
-
 def cached_step(cache_key, build) -> Callable:
     """THE compiled-step LRU: get-or-build under the lock, bounded at
     PathRouter.MAX_KEYS, dict insertion order = recency. One discipline
@@ -101,8 +85,10 @@ def cached_step(cache_key, build) -> Callable:
 
 
 def _build_step(mesh: Mesh, spec: ScanAggSpec, tag: str, body, in_specs) -> Callable:
-    """shard_map(body)+combine, jitted and cached per (mesh, spec, tag)."""
-    spec = _resolved(spec)
+    """shard_map(body)+combine, jitted and cached per (mesh, spec, tag).
+    ``spec.segment_impl`` is the chooser's concrete name: it keys the step
+    cache and the jit trace."""
+    concrete_impl(spec.segment_impl)
 
     def build():
         static_filters = encode_filter_ops(spec.numeric_filters)
@@ -117,7 +103,6 @@ def _build_step(mesh: Mesh, spec: ScanAggSpec, tag: str, body, in_specs) -> Call
                     numeric_filters=static_filters,
                     need_minmax=spec.need_minmax,
                     segment_impl=spec.segment_impl,
-                    hash_slots=spec.hash_slots,
                 )
             )
 

@@ -34,7 +34,8 @@ from ..ops.encoding import build_padded_batch, time_buckets
 from ..table_engine.predicate import NUMPY_CMP, FilterOp, Predicate
 from ..utils import querystats
 from ..utils.tracectx import span as _span
-from . import ast
+from . import ast, kernel_choice
+from .path_router import plan_shape_key
 from .plan import AggCall, GroupKey, QueryPlan
 
 @dataclass
@@ -479,9 +480,6 @@ class CachedAggPrep:
     # static per-field layout descriptors (ISSUE 19) — jit-key fragments:
     # a column re-encoding between preps must not share a traced kernel
     value_layouts: tuple = ()
-    # the router's cardinality estimate, kept for a re-route should the
-    # device refuse the chosen impl's program
-    est_distinct: int = 1
 
     @property
     def kernel_key(self) -> tuple:
@@ -491,7 +489,7 @@ class CachedAggPrep:
         return (
             spec.n_groups, spec.n_buckets, spec.n_agg_fields,
             spec.numeric_filters, spec.need_minmax,
-            spec.segment_impl, spec.hash_slots,
+            spec.segment_impl,
             self.value_layouts, entry.ts_layout, entry.series_layout,
         )
 
@@ -702,8 +700,6 @@ class Executor:
     def _choose_route(self, plan: QueryPlan, m: dict) -> str:
         """The PathRouter's route for this request, with what
         ``_finish_metrics`` needs to hand the sample back."""
-        from .path_router import plan_shape_key
-
         key = plan_shape_key(plan)
         m["_adaptive_key"] = key
         m["_compiles_before"] = querystats.kernel_compiles()
@@ -852,48 +848,6 @@ class Executor:
             m["partial_stages"] = stage_metrics
             return assemble_result(plan, combined, n_groups, spec)
 
-    # ---- learned kernel routing --------------------------------------------
-    def _route_kernel(self, plan: QueryPlan, spec, n_rows: int,
-                      est_distinct):
-        """Learned segment-impl choice for a padded spec (the "database
-        picks its own data structures" loop): seed from estimated group
-        cardinality + observed query_stats history, then serve the
-        measured winner with periodic re-probes. Returns (spec, token);
-        token is None when routing doesn't apply (n_seg == 1, pinned
-        HORAEDB_SEGMENT_IMPL, or router disabled)."""
-        from .path_router import plan_shape_key
-
-        ledger = querystats.current_ledger()
-        return route_segment_kernel(
-            plan_shape_key(plan), spec, n_rows, est_distinct,
-            sql=ledger.sql if ledger else "",
-        )
-
-    def _route_cached_kernel(self, plan: QueryPlan, spec, n_rows: int,
-                             est_distinct):
-        """``_route_kernel`` for the cached path, with "auto"/a pin
-        resolved to the CONCRETE impl on host: it keys the packed jit
-        call, so flipping the env knobs re-traces warm shapes instead of
-        silently reusing the stale compiled branch. (None, None) when
-        there is no impl to offer (``route_segment_kernel``)."""
-        import dataclasses
-
-        from ..ops.scan_agg import resolve_segment_impl
-
-        spec, krec = self._route_kernel(plan, spec, n_rows, est_distinct)
-        if spec is None:
-            return None, None
-        return dataclasses.replace(
-            spec,
-            segment_impl=resolve_segment_impl(
-                spec.n_groups * spec.n_buckets, spec.segment_impl
-            ),
-        ), krec
-
-    def _finish_kernel(self, krec, spec, m: dict, state,
-                       seconds: float, n_valid=None) -> None:
-        finish_segment_kernel(krec, spec, m, state, seconds, n_valid)
-
     # ---- device path -------------------------------------------------------
     def _agg_device_shape(self, plan: QueryPlan):
         """(tag_keys, bucket_key, agg_cols) when the aggregation shape fits
@@ -1005,13 +959,7 @@ class Executor:
         ).padded()
         literals = [lit for _, _, lit in device_filters]
 
-        # Learned kernel choice. Group codes are dense (np.unique), so
-        # groups x buckets is an exact ceiling on live segments; bucket
-        # sparsity (and router history) can only pull it down.
-        spec, krec = self._route_kernel(
-            plan, spec, n_rows=n,
-            est_distinct=max(enc.num_groups, 1) * n_buckets,
-        )
+        spec, krec = kernel_choice.choose(plan_shape_key(plan), spec, n)
         if spec is None:  # no impl fits the device, or it refused them all
             return self._execute_agg_host(plan, rows)
 
@@ -1035,9 +983,8 @@ class Executor:
         else:
             state = scan_aggregate(batch, spec, literals)
         if m is not None:
-            self._finish_kernel(
-                krec, spec, m, state,
-                _time.perf_counter() - t_kernel, n_valid=batch.n_valid,
+            kernel_choice.finish(
+                krec, spec, m, state, _time.perf_counter() - t_kernel
             )
 
         return self._assemble_agg_result(
@@ -1289,19 +1236,8 @@ class Executor:
             need_minmax=_plan_needs_minmax(plan),
         ).padded()
 
-        # Learned kernel choice. Unlike the direct path, the cached
-        # domain spans EVERY group in the table while the allow-list may
-        # keep a handful of series — exactly the sparse regime where the
-        # hash impl beats full-domain scatter/MXU. Estimate live
-        # segments from the groups the allowed series can actually
-        # reach (exact on the group axis, ceiling on the bucket axis).
-        if scan_allowed.any():
-            active_groups = len(np.unique(series_group[scan_allowed]))
-        else:
-            active_groups = 1
-        est_distinct = max(active_groups, 1) * n_buckets
-        spec, krec = self._route_cached_kernel(
-            plan, spec, entry.n_valid, est_distinct
+        spec, krec = kernel_choice.choose(
+            plan_shape_key(plan), spec, entry.n_valid
         )
         if spec is None:
             # no impl fits the device's free memory, or it has refused
@@ -1357,7 +1293,7 @@ class Executor:
             lo_rel=lo_rel, hi_rel=hi_rel, t0_rel=t0_rel, width_i=width_i,
             tag_keys=tag_keys, key_values=key_values, agg_cols=agg_cols,
             num_groups=num_groups, delta=delta,
-            value_layouts=value_layouts, est_distinct=est_distinct,
+            value_layouts=value_layouts,
         )
 
     def dispatch_cached_agg(self, prep: "CachedAggPrep") -> Optional[ResultSet]:
@@ -1456,7 +1392,6 @@ class Executor:
                     numeric_filters=encode_filter_ops(spec.numeric_filters),
                     need_minmax=spec.need_minmax,
                     segment_impl=spec.segment_impl,
-                    hash_slots=spec.hash_slots,
                     selective=selective,
                     value_layouts=prep.value_layouts,
                     ts_layout=entry.ts_layout,
@@ -1500,7 +1435,7 @@ class Executor:
                     cached_scan_agg_packed, pargs, pkwargs
                 ),
             )
-        self._finish_kernel(
+        kernel_choice.finish(
             prep.krec, spec, m, state, _time.perf_counter() - t_kernel
         )
         return self._fold_and_assemble(prep, state)
@@ -1508,29 +1443,18 @@ class Executor:
     def _reroute_refused(self, prep: "CachedAggPrep", message: str,
                          seconds: float):
         """The device refused ``prep``'s packed program: journal it, take
-        the impl out of the router for this shape and choose again. ->
-        the spec to dispatch next (also set on ``prep``), or None when
-        nothing is left to offer (or the impl was pinned, not routed)."""
-        import dataclasses
-
-        from ..obs.decisions import resolve_decision
+        the impl out of the shape's candidates and choose again. -> the
+        spec to dispatch next (also set on ``prep``), or None when nothing
+        is left to offer (or the impl was the unrouted ``single``)."""
         from ..obs.device import note_refusal
-        from .path_router import KERNEL_ROUTER
 
         spec = prep.spec
         note_refusal("cached_packed", spec.segment_impl, prep.kernel_key, message)
         if prep.krec is None:
             return None
-        key, routed, dec_id = prep.krec
-        KERNEL_ROUTER.refuse(key, routed)
-        resolve_decision(
-            dec_id, actual=seconds, outcome="refused",
-            loop="kernel_router", calibrate=False,
-        )
-        spec, prep.krec = self._route_cached_kernel(
-            prep.plan,
-            dataclasses.replace(spec, segment_impl="auto", hash_slots=0),
-            prep.entry.n_valid, prep.est_distinct,
+        kernel_choice.refused(prep.krec, seconds)
+        spec, prep.krec = kernel_choice.choose(
+            plan_shape_key(prep.plan), spec, prep.entry.n_valid
         )
         if spec is not None:
             prep.spec = spec
@@ -1624,7 +1548,6 @@ class Executor:
                 numeric_filters=encode_filter_ops(spec.numeric_filters),
                 need_minmax=spec.need_minmax,
                 segment_impl=spec.segment_impl,
-                hash_slots=spec.hash_slots,
                 value_layouts=p0.value_layouts,
                 ts_layout=entry.ts_layout,
                 series_layout=entry.series_layout,
@@ -1647,7 +1570,7 @@ class Executor:
                 # router's per-shape EWMA mixes these with solo-dispatch
                 # samples, and a raw B-wide wall time would make the
                 # serving impl look up to Bx slower than it is per query
-                self._finish_kernel(
+                kernel_choice.finish(
                     p.krec if j == 0 else None, spec, p.m, state,
                     elapsed / B,
                 )
@@ -2504,136 +2427,6 @@ def _fetch_behind(result):
     wait (about a third of a millisecond a dispatch on a v5e)."""
     result.copy_to_host_async()
     return result
-
-
-def route_segment_kernel(shape_key, spec, n_rows: int, est_distinct,
-                         sql: str = ""):
-    """Module-level core of the learned segment-impl choice — shared by
-    the executor's direct/cached/dist paths AND the partial-agg
-    push-down (query/partial.py runs on partition owners with no
-    Executor instance in scope). Returns (spec, token); token is None
-    when routing doesn't apply (n_seg == 1, pinned HORAEDB_SEGMENT_IMPL,
-    or router disabled); spec is None when no impl can be offered: none
-    fits the device's free memory (``candidate_kernels``) or the device
-    has refused every one for this key (``KernelRouter.refuse``)."""
-    from ..ops.scan_agg import pinned_segment_impl
-    from .path_router import (
-        KERNEL_ROUTER,
-        bootstrap_observed_segments,
-        candidate_kernels,
-        kernel_routing_enabled,
-        seed_kernel,
-    )
-
-    n_seg = spec.n_groups * spec.n_buckets
-    if n_seg <= 1 or pinned_segment_impl() or not kernel_routing_enabled():
-        return spec, None
-    key = (shape_key, n_seg.bit_length())
-    obs = KERNEL_ROUTER.observed_segments(key)
-    if obs is None and sql:
-        # never-seen key: the query_stats ring may remember how many
-        # live segments this SQL shape produced before (agg_segments)
-        obs = bootstrap_observed_segments(sql)
-        if obs is not None:
-            KERNEL_ROUTER.note_segments(key, obs)
-    est = obs if obs is not None else est_distinct
-    if est is not None:
-        est = max(1, min(int(est), n_seg, max(int(n_rows), 1)))
-    import dataclasses
-
-    import jax
-
-    from ..ops.hash_agg import hash_slots_for
-
-    candidates = candidate_kernels(
-        n_seg, n_rows, est, spec.n_agg_fields, spec.need_minmax
-    )
-    impl = KERNEL_ROUTER.choose(
-        key,
-        seed_kernel(n_seg, est, jax.default_backend()),
-        candidates,
-    )
-    if impl is None:
-        # nothing fits the device's free memory, or it refused them all
-        return None, None
-    spec = dataclasses.replace(
-        spec,
-        segment_impl=impl,
-        hash_slots=hash_slots_for(n_seg, est) if impl == "hash" else 0,
-    )
-    # Decision plane: journal the pick with the EWMA's own prediction of
-    # what this impl costs for this shape (None until the impl has a
-    # clean sample — those picks resolve ungraded). The id rides the
-    # router token to finish_segment_kernel, where the same amortized
-    # dispatch seconds that feed the EWMA also grade the prediction.
-    from ..obs.decisions import record_decision
-
-    predicted = KERNEL_ROUTER.stats(key).get("t", {}).get(impl)
-    dec_id = record_decision(
-        "kernel_router",
-        key=f"{shape_key[0] if shape_key else ''}#b{n_seg.bit_length()}",
-        choice=impl,
-        features={
-            "n_seg": n_seg,
-            "est_segments": est,
-            "candidates": list(candidates),
-        },
-        predicted=predicted,
-    )
-    return spec, (key, impl, dec_id)
-
-
-def finish_segment_kernel(krec, spec, m: dict, state,
-                          seconds: float, n_valid=None) -> None:
-    """Close one aggregation dispatch: feed the router's EWMA and
-    observed-cardinality loop, stamp the metric tree, the ledger
-    ``kernel`` field, and the horaedb_agg_kernel_total family."""
-    from ..ops.scan_agg import (
-        pinned_segment_impl,
-        resolve_segment_impl,
-    )
-    from .path_router import KERNEL_ROUTER
-
-    n_seg = spec.n_groups * spec.n_buckets
-    impl = resolve_segment_impl(n_seg, spec.segment_impl)
-    live = int((state.counts > 0).sum())
-    if krec is not None:
-        from ..obs.decisions import resolve_decision
-
-        key, routed, dec_id = krec
-        if live > 0:
-            # Degenerate dispatches (empty time range, filter matching
-            # nothing) are excluded from BOTH feedback loops: their
-            # near-zero latency would make whichever impl served them
-            # look unbeatable under the min-biased estimator, and a
-            # live count of 0 would EWMA the cardinality estimate toward
-            # a tiny hash table the next real query overflows.
-            # the honest cost of CHOOSING this impl for the shape —
-            # including the tiny-input host fallback when hash took it
-            KERNEL_ROUTER.record(key, routed, seconds)
-            KERNEL_ROUTER.note_segments(key, live)
-            resolve_decision(
-                dec_id, actual=seconds, outcome="served",
-                loop="kernel_router",
-            )
-        else:
-            # degenerate: the decision closes (no leaked pending entry)
-            # but must not grade the EWMA's prediction
-            resolve_decision(
-                dec_id, actual=seconds, outcome="degenerate",
-                loop="kernel_router", calibrate=False,
-            )
-    if (
-        impl == "hash"
-        and n_valid is not None
-        and not pinned_segment_impl()
-    ):
-        from ..utils.env import env_int
-
-        if n_valid <= env_int("HORAEDB_HASH_HOST_MAX_ROWS", 4096):
-            impl = "host"  # scan_aggregate's dispatch-free arm
-    m["kernel"] = impl
-    querystats.note_agg_kernel(impl, segments=live)
 
 
 def _series_could_match(

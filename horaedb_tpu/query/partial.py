@@ -30,6 +30,7 @@ from ..ops import ScanAggSpec, encode_group_codes, scan_aggregate
 from ..ops.encoding import build_padded_batch, time_buckets
 from ..table_engine.predicate import ColumnFilter, FilterOp, Predicate
 from ..remote.codec import predicate_from_dict, predicate_to_dict
+from . import kernel_choice
 from .executor import ResultSet, _plan_needs_minmax
 from .plan import QueryPlan
 
@@ -305,14 +306,8 @@ def _partial_kernel(
         need_minmax=bool(spec.get("need_minmax", True)),
     ).padded()
 
-    # Learned segment-impl choice (ROADMAP item-3 remainder): the
-    # partial path rode the static HORAEDB_MXU_MAX_SEGMENTS heuristic
-    # long after the direct/cached/dist paths got the router. Keyed by
-    # the WIRE spec's shape (what the owner actually executes — the
-    # coordinator's plan never reaches this side of the RPC); group
-    # codes are dense here, so groups x buckets is an exact ceiling.
-    from .executor import finish_segment_kernel, route_segment_kernel
-
+    # Keyed by the WIRE spec's shape (what the owner actually executes —
+    # the coordinator's plan never reaches this side of the RPC).
     shape_key = (
         "partial",
         tuple(group_tags),
@@ -321,12 +316,12 @@ def _partial_kernel(
         tuple((c, op) for c, op, _ in spec["device_filters"]),
         tuple((c, op) for c, op, _ in spec["exact_filters"]),
     )
-    routed, krec = route_segment_kernel(
-        shape_key, kspec, n_rows=batch.n_valid,
-        est_distinct=max(enc.num_groups, 1) * n_buckets,
-    )
-    if routed is not None:  # else: nothing to offer, the static choice stands
-        kspec = routed
+    kspec, krec = kernel_choice.choose(shape_key, kspec, batch.n_valid)
+    if kspec is None:
+        # nothing fits the device, or it refused them all: exact on host
+        if m is not None:
+            m["path"] = "host"
+        return _partial_host(rows, mask, spec, t0)
 
     import time as _time
 
@@ -342,9 +337,9 @@ def _partial_kernel(
         )
     else:
         state = scan_aggregate(batch, kspec, [lit for _, _, lit in spec["device_filters"]])
-    finish_segment_kernel(
+    kernel_choice.finish(
         krec, kspec, m if m is not None else {}, state,
-        _time.perf_counter() - t_kernel, n_valid=batch.n_valid,
+        _time.perf_counter() - t_kernel,
     )
 
     G, B = max(enc.num_groups, 1), n_buckets

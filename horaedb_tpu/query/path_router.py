@@ -11,7 +11,7 @@ re-probing the loser so it adapts when conditions change (scan cache
 finishes building, data grows, dispatch latency shifts).
 
 How a router keeps its estimate of a losing route (``_ProbeSchedule``,
-one rule for ``PathRouter`` and ``KernelRouter``):
+one rule for ``PathRouter`` and ``kernel_choice.KernelRouter``):
 
 * Only a clean sample becomes an estimate. A request during which a
   program compiled or the scan cache was built says nothing of a route's
@@ -227,183 +227,3 @@ def raw_adaptive_enabled() -> bool:
     HORAEDB_ADAPTIVE_PATH=0 still pins routing off (device-first)."""
     v = os.environ.get("HORAEDB_ADAPTIVE_PATH", "auto")
     return v not in ("0", "off", "false")
-
-
-# ---- learned segment-kernel routing ---------------------------------------
-#
-# The device group-by has three segment-reduction impls (ops/scan_agg.py:
-# mxu one-hot matmul, scatter segment_* ops, hash slot table) and the
-# winner flips with group cardinality and skew (arXiv 2411.13245) — a
-# static import-time threshold leaves a regime on the table on every
-# deployment. Same estimates and probe schedule as PathRouter, one
-# level down: keyed by (plan shape, segment-count bucket), choosing the
-# IMPL the jitted kernel branches on instead of the device/host path.
-# The first call of a shape is seeded from estimated group cardinality
-# (sampler/exact group encoding + observed query_stats history), so it
-# already starts near the winner instead of probing blind.
-
-
-def kernel_routing_enabled() -> bool:
-    """Learned impl choice (default on — it matters on every backend;
-    scatter-vs-hash flips on CPU too). HORAEDB_SEGMENT_IMPL pinning
-    bypasses the router entirely regardless of this switch."""
-    return os.environ.get("HORAEDB_KERNEL_ROUTER", "1") not in (
-        "0", "off", "false",
-    )
-
-
-def candidate_kernels(n_seg: int, n_rows: int, est_distinct=None,
-                      n_fields: int = 0, need_minmax: bool = False) -> tuple:
-    """Impls worth PROBING for this shape. Routing must never schedule a
-    probe that is catastrophically wrong by construction: the MXU one-hot
-    is O(N * n_seg) — beyond a bounded extrapolation of the static
-    crossover a single probe could cost seconds — and the hash table
-    cannot beat the direct impls when the domain is already tiny or the
-    live cardinality fills most of it (a near-full table just routes
-    everything through the overflow fallback). Nor one the device would
-    refuse: an impl whose temporaries (``segment_temp_bytes``) exceed the
-    device's free memory is not offered — () when none fits, and the host
-    serves the query."""
-    import jax
-
-    from ..obs.device import device_free_bytes
-    from ..ops.scan_agg import mxu_max_segments, segment_temp_bytes
-
-    cands = ["scatter"]
-    if n_seg <= (
-        # the 4x extrapolation is MXU-calibrated; without a matrix unit
-        # the one-hot's O(N * n_seg) bites orders of magnitude sooner
-        4 * mxu_max_segments() if jax.default_backend() == "tpu" else 256
-    ):
-        cands.append("mxu")
-    if n_seg > 64 and (est_distinct is None or est_distinct * 4 <= n_seg):
-        cands.append("hash")
-    free = device_free_bytes()
-    if free is not None:
-        cands = [
-            k for k in cands
-            if segment_temp_bytes(k, n_rows, n_seg, n_fields, need_minmax) <= free
-        ]
-    return tuple(cands)
-
-
-def seed_kernel(n_seg: int, est_distinct, backend: str) -> str:
-    """Cardinality-seeded starting impl for a never-measured shape."""
-    if (
-        est_distinct is not None
-        and n_seg > 512
-        and est_distinct * 8 <= n_seg
-    ):
-        # Sparse domain: most segments provably empty — hash territory.
-        return "hash"
-    from ..ops.scan_agg import mxu_max_segments
-
-    if backend == "tpu":
-        return "mxu" if n_seg <= mxu_max_segments() else "scatter"
-    return "scatter"
-
-
-class KernelRouter(_ProbeSchedule):
-    """Per-(plan-shape, segment-bucket) EWMA over the segment impls.
-
-    Same discipline as PathRouter: warm each candidate (dropping its
-    compile-tainted first sample), serve the measured winner, re-probe
-    each loser as its own budget of serving time allows, the most overdue
-    first, so the choice adapts when conditions change. Also remembers
-    the observed live segment count per key — the feedback that sizes the
-    hash slot table and corrects a bad seed estimate."""
-
-    router = "kernel"
-
-    def choose(self, key, seed: str, candidates: tuple):
-        """The impl to dispatch this call with; None when there is none to
-        offer: ``candidates`` is empty, or the device has refused every one
-        of them for this key (``refuse``)."""
-        with self._lock:
-            st = self._touch(key)
-            refused = st.get("refused", ())
-            candidates = tuple(k for k in candidates if k not in refused)
-            if not candidates:
-                return None
-            times = st["t"]
-            for k in [seed] + [k for k in candidates if k != seed]:
-                # two samples each: the first pays jit trace+compile and
-                # is dropped by record() — judging needs a clean one
-                if k in candidates and k not in times:
-                    return k
-            winner = min(candidates, key=times.get)
-            losers = [k for k in candidates if k != winner]
-            return self._due_probe(st, losers) or winner
-
-    def record(self, key, kernel: str, seconds: float) -> None:
-        """Fold a dispatch latency in (``_fold``); the first sample of each
-        impl (compile-tainted) only counts, never judges."""
-        with self._lock:
-            st = self._touch(key)
-            warmed = st.setdefault("warmed", set())
-            if kernel not in warmed or kernel in st.get("refused", ()):
-                # compile-tainted; or refused while this one was in flight
-                warmed.add(kernel)
-                return
-            self._fold(st, kernel, seconds)
-
-    def refuse(self, key, kernel: str) -> None:
-        """The device refused ``kernel``'s program for this key (no room in
-        HBM): never offer it for the key again, and drop its estimate —
-        a route that cannot serve is no winner for ``_fold`` to defer to."""
-        with self._lock:
-            st = self._touch(key)
-            st.setdefault("refused", set()).add(kernel)
-            for per_route in (st["t"], st["n"], st["since"]):
-                per_route.pop(kernel, None)
-
-    def note_segments(self, key, live: int) -> None:
-        """Observed live (group x bucket) cells — EWMA'd so the hash
-        slot table is sized from what the shape actually produces."""
-        with self._lock:
-            st = self._touch(key)
-            prev = st.get("segments")
-            st["segments"] = (
-                int(live) if prev is None else int(0.7 * prev + 0.3 * live)
-            )
-
-    def observed_segments(self, key):
-        with self._lock:
-            st = self._stats.get(key)
-            return None if st is None else st.get("segments")
-
-    def stats(self, key) -> dict:
-        with self._lock:
-            st = self._stats.get(key, {})
-            return {
-                k: (type(v)(v) if isinstance(v, (dict, set)) else v)
-                for k, v in st.items()
-            }
-
-    def reset(self) -> None:
-        with self._lock:
-            self._stats.clear()
-
-
-# One process-wide router: kernel latency is a property of the hardware
-# and the shape, not of any particular executor instance — every
-# consumer (direct device path, cached path, dist-agg step) folds into
-# and serves from the same history.
-KERNEL_ROUTER = KernelRouter()
-
-
-def bootstrap_observed_segments(sql: str):
-    """Seed a never-seen router key from query_stats history: the most
-    recent finalized ledger of the same normalized SQL shape carries the
-    live segment count its aggregation produced (``agg_segments``)."""
-    if not sql:
-        return None
-    from ..utils.querystats import STATS_STORE
-    from ..wlm.admission import normalize_shape
-
-    shape = normalize_shape(sql)
-    for row in reversed(STATS_STORE.list()):
-        segs = row.get("agg_segments")
-        if segs and normalize_shape(str(row.get("sql", ""))) == shape:
-            return int(segs)
-    return None

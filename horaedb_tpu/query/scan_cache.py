@@ -11,7 +11,7 @@ in device HBM —
 — and every subsequent aggregate query ships only O(series)+O(1) data:
 a series->group map, a series allow-list (tag filters evaluated per
 series on host), time-range scalars, and filter literals. The fused
-kernel (ops.scan_agg.cached_scan_agg) does the rest on device.
+kernel (ops.scan_agg.cached_scan_agg_packed) does the rest on device.
 
 Invalidation: entries key on the table's BASE fingerprint — schema
 version, flushed sequence, SST file set. Plain ingest (memtable appends)

@@ -60,7 +60,7 @@ What it asserts — from the database's own tables:
 
 The ~30s tier-1 smoke (tests/test_tenantsim.py) runs a small
 configuration with one kill + one latency/error burst; the full scale
-runs under ``@pytest.mark.slow`` and as ``BENCH_CONFIG=tenantsim``.
+runs under ``@pytest.mark.slow``.
 """
 
 from __future__ import annotations

@@ -1,7 +1,7 @@
 """Where the process keeps JAX's persistent compilation cache.
 
 Called from process entry points only (the server's ``main()``,
-``chip_smoke.py``, ``bench.py``'s single-config run) — never from
+``chip_smoke.py``, ``benchmark/run.py``) — never from
 ``connect()`` or at import, so embedding programs and tests keep whatever
 they configured. The directory is part of the cache key, so it is a fixed
 path: never a temporary name, a pid or a time.

@@ -1,6 +1,6 @@
 """Guarded environment-variable parsing.
 
-An operator typo (``HORAEDB_MXU_MAX_SEGMENTS=8k``) must degrade to the
+An operator typo (``HORAEDB_DIST_MIN_ROWS=8k``) must degrade to the
 default, not abort the process — several of these are read at module
 import, where an unguarded ``int()`` kills the whole server before it can
 log anything. The guarded pattern existed ad hoc (merge.py, mesh.py);

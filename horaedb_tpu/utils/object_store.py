@@ -466,9 +466,8 @@ class InjectedFaultError(OSError):
 
 class FaultInjectingStore(ObjectStore):
     """Deterministic fault-injection wrapper over any store — the shared
-    chaos layer for bench (ingest A/B) and the tenant-scale
-    production simulator (tools/tenantsim), promoted from bench.py's
-    ad-hoc latency-injected SST store.
+    chaos layer of the tenant-scale production simulator
+    (tools/tenantsim).
 
     Injection points:
 

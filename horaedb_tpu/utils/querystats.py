@@ -128,10 +128,10 @@ def _route_counter(route: str):
 
 # ---- aggregation-kernel accounting ----------------------------------------
 
-# Which segment-reduction impl served a device aggregation (the learned
-# kernel router's choice, or the static heuristic's). "single" is the
-# n_seg == 1 pure-reduction shape; "host" the tiny-input hash fallback.
-SEGMENT_KERNEL_LABELS = ("mxu", "scatter", "hash", "single", "host")
+# Which segment-reduction impl served a device aggregation, as
+# query/kernel_choice named it. "single" is the n_seg == 1
+# pure-reduction shape.
+SEGMENT_KERNEL_LABELS = ("mxu", "scatter", "single")
 
 # Registry discipline (lint-enforced like the admission/flush families):
 # declared here, registered eagerly, documented in docs/OBSERVABILITY.md,
@@ -193,7 +193,7 @@ def note_raw_scan(path: str, kernel: str = "", rows=None) -> None:
 def note_agg_kernel(kernel: str, segments: int = 0) -> None:
     """Account one aggregation dispatch: bump the per-kernel family,
     stamp the ledger's ``kernel`` field, and record the live segment
-    count the kernel router learns cardinality from."""
+    count (``agg_segments``)."""
     counter = _AGG_KERNEL_COUNTERS.get(kernel)
     if counter is not None:
         counter.inc()

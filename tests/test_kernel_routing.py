@@ -1,10 +1,12 @@
-"""Learned aggregation-kernel routing (PR 6).
+"""Which segment kernel runs (query/kernel_choice.py; PR 6, PR 30).
 
-Covers: the three-segment-impl equivalence property (mxu / scatter /
-hash must be indistinguishable on every input), the KernelRouter's
-probe/serve/re-probe loop and cardinality seeding, the guarded env-int
-satellite, the dist-agg step-cache LRU bound, the scan-cache dtype
-auto-tuning, and the end-to-end kill switch + ledger surfaces.
+Covers: the segment-impl equivalence property (mxu / scatter must be
+indistinguishable on every input), the one chooser — a concrete impl at
+every shape, the static rule at the benchmark cells' shapes, the kernels'
+refusal of anything but a concrete name — the KernelRouter's
+probe/serve/re-probe loop, the guarded env-int satellite, the dist-agg
+step-cache LRU bound, the scan-cache dtype auto-tuning, the ledger
+surfaces and the refused-program guard.
 """
 
 import dataclasses
@@ -13,14 +15,10 @@ import numpy as np
 import pytest
 
 import horaedb_tpu
-from horaedb_tpu.ops.encoding import build_padded_batch, next_pow2
-from horaedb_tpu.ops.scan_agg import (
-    ScanAggSpec,
-    mxu_max_segments,
-    pinned_segment_impl,
-    resolve_segment_impl,
-    scan_aggregate,
-)
+from horaedb_tpu.ops.encoding import build_padded_batch
+from horaedb_tpu.ops.scan_agg import ScanAggSpec, scan_aggregate
+from horaedb_tpu.query import kernel_choice
+from horaedb_tpu.query.kernel_choice import KERNEL_ROUTER, KernelRouter
 
 
 @pytest.fixture()
@@ -32,18 +30,21 @@ def db():
 
 @pytest.fixture(autouse=True)
 def _fresh_router():
-    from horaedb_tpu.query.path_router import KERNEL_ROUTER
-
     KERNEL_ROUTER.reset()
     yield
     KERNEL_ROUTER.reset()
 
 
-def _dispatch(batch, spec, impl, slots=0, literals=()):
+def _dispatch(batch, spec, impl, literals=()):
     return scan_aggregate(
-        batch,
-        dataclasses.replace(spec, segment_impl=impl, hash_slots=slots),
-        list(literals),
+        batch, dataclasses.replace(spec, segment_impl=impl), list(literals)
+    )
+
+
+def _only(monkeypatch, *impls):
+    """Offer the chooser these candidates and no others."""
+    monkeypatch.setattr(
+        kernel_choice, "candidate_kernels", lambda *a, **k: tuple(impls)
     )
 
 
@@ -61,14 +62,10 @@ def _assert_states_equal(a, b, label):
 
 
 class TestKernelEquivalence:
-    """Satellite: all three segment impls return identical
+    """Satellite: both segment impls return identical
     counts/sums/mins/maxs over randomized specs."""
 
-    def test_randomized_specs(self, monkeypatch):
-        # keep the hash arm on-device even for tiny randomized inputs
-        monkeypatch.setenv("HORAEDB_HASH_HOST_MAX_ROWS", "0")
-        from horaedb_tpu.ops.hash_agg import default_hash_slots
-
+    def test_randomized_specs(self):
         rng = np.random.default_rng(42)
         for trial in range(8):
             n = int(rng.integers(5, 1500))
@@ -89,42 +86,14 @@ class TestKernelEquivalence:
                 n_agg_fields=n_fields,
                 need_minmax=bool(trial % 2),
             ).padded()
-            n_seg = spec.n_groups * spec.n_buckets
             ref = _dispatch(batch, spec, "scatter")
             _assert_states_equal(
                 ref, _dispatch(batch, spec, "mxu"), f"trial {trial}: mxu"
             )
-            for slots in (16, default_hash_slots(n_seg)):
-                got = _dispatch(batch, spec, "hash", slots=slots)
-                _assert_states_equal(
-                    ref, got, f"trial {trial}: hash slots={slots}"
-                )
-
-    def test_hash_at_slot_table_boundary(self, monkeypatch):
-        """n_seg == slot-count boundary: every slot needed, load factor
-        1.0 — the probe budget can't place everything and the overflow
-        fallback must make up the difference exactly."""
-        monkeypatch.setenv("HORAEDB_HASH_HOST_MAX_ROWS", "0")
-        rng = np.random.default_rng(7)
-        n_groups = 16  # spec pads to pow2: n_seg == 16 == slots
-        n = 600
-        codes = rng.integers(0, n_groups, n).astype(np.int32)
-        mask = np.ones(n, bool)
-        vals = [rng.normal(size=n).astype(np.float32)]
-        batch = build_padded_batch(codes, np.zeros(n, np.int32), mask, vals)
-        spec = ScanAggSpec(n_groups=n_groups, n_buckets=1, n_agg_fields=1).padded()
-        n_seg = spec.n_groups * spec.n_buckets
-        assert n_seg == next_pow2(n_seg) == 16
-        ref = _dispatch(batch, spec, "scatter")
-        _assert_states_equal(
-            ref, _dispatch(batch, spec, "hash", slots=16), "boundary"
-        )
 
     def test_single_segment_bypasses_routing(self):
-        """n_seg == 1 (global aggregate) resolves to the pure-reduction
-        impl regardless of the requested kernel."""
-        assert resolve_segment_impl(1, "auto") == "single"
-        assert resolve_segment_impl(1, "hash") == "single"
+        """n_seg == 1 (global aggregate): the chooser names the
+        pure-reduction impl, hands out no token and asks no router."""
         rng = np.random.default_rng(3)
         n = 300
         batch = build_padded_batch(
@@ -132,72 +101,13 @@ class TestKernelEquivalence:
             np.ones(n, bool), [rng.normal(size=n).astype(np.float32)],
         )
         spec = ScanAggSpec(n_groups=1, n_buckets=1, n_agg_fields=1).padded()
-        ref = _dispatch(batch, spec, "auto")
-        _assert_states_equal(ref, _dispatch(batch, spec, "hash"), "single")
-
-    def test_hash_host_fallback_is_exact(self, monkeypatch):
-        """Below HORAEDB_HASH_HOST_MAX_ROWS the hash route serves from
-        host numpy — same numbers as the device impls."""
-        monkeypatch.delenv("HORAEDB_SEGMENT_IMPL", raising=False)
-        rng = np.random.default_rng(11)
-        n = 200
-        codes = rng.integers(0, 6, n).astype(np.int32)
-        mask = rng.random(n) < 0.9
-        vals = [rng.normal(size=n).astype(np.float32)]
-        batch = build_padded_batch(codes, np.zeros(n, np.int32), mask, vals)
-        spec = ScanAggSpec(n_groups=8, n_buckets=1, n_agg_fields=1).padded()
-        ref = _dispatch(batch, spec, "scatter")
-        monkeypatch.setenv("HORAEDB_HASH_HOST_MAX_ROWS", "100000")
-        _assert_states_equal(ref, _dispatch(batch, spec, "hash"), "host")
-
-    def test_live_pin_flip_retraces_warm_shapes(self, monkeypatch):
-        """Review regression: the pin used to resolve INSIDE the jitted
-        body — a warm shape kept serving the stale compiled branch after
-        an operator flipped HORAEDB_SEGMENT_IMPL (the bisect tool's whole
-        purpose). Host-side resolution makes the concrete impl the jit
-        key, so the flip must mint a new trace through the new branch."""
-        from horaedb_tpu.ops import scan_agg as sa
-
-        rng = np.random.default_rng(9)
-        n = 100
-        batch = build_padded_batch(
-            rng.integers(0, 8, n).astype(np.int32), np.zeros(n, np.int32),
-            np.ones(n, bool), [rng.normal(size=n).astype(np.float32)],
+        chosen, token = kernel_choice.choose(("t", "shape"), spec, n)
+        assert (chosen.segment_impl, token) == ("single", None)
+        assert not KERNEL_ROUTER._stats
+        _assert_states_equal(
+            scan_aggregate(batch, chosen), _dispatch(batch, spec, "scatter"),
+            "single",
         )
-        spec = ScanAggSpec(n_groups=8, n_buckets=1, n_agg_fields=1).padded()
-        monkeypatch.setenv("HORAEDB_SEGMENT_IMPL", "scatter")
-        ref = _dispatch(batch, spec, "auto")  # warm: compiles scatter
-        traced = []
-        orig = sa._mxu_segment_agg
-
-        def spy(*args, **kwargs):
-            traced.append(1)
-            return orig(*args, **kwargs)
-
-        monkeypatch.setattr(sa, "_mxu_segment_agg", spy)
-        monkeypatch.setenv("HORAEDB_SEGMENT_IMPL", "mxu")
-        got = _dispatch(batch, spec, "auto")
-        assert traced, "pin flip did not re-trace the warm shape"
-        _assert_states_equal(ref, got, "pin flip")
-
-    def test_pin_disables_host_fallback(self, monkeypatch):
-        """HORAEDB_SEGMENT_IMPL exists to bisect device lowerings: a
-        pinned run must actually run them, even on tiny inputs."""
-        monkeypatch.setenv("HORAEDB_SEGMENT_IMPL", "hash")
-        monkeypatch.setenv("HORAEDB_HASH_HOST_MAX_ROWS", "100000")
-        rng = np.random.default_rng(5)
-        n = 50
-        batch = build_padded_batch(
-            rng.integers(0, 4, n).astype(np.int32), np.zeros(n, np.int32),
-            np.ones(n, bool), [rng.normal(size=n).astype(np.float32)],
-        )
-        spec = ScanAggSpec(n_groups=4, n_buckets=1, n_agg_fields=1).padded()
-        assert pinned_segment_impl() == "hash"
-        got = _dispatch(batch, spec, "auto")
-        monkeypatch.setenv("HORAEDB_SEGMENT_IMPL", "scatter")
-        ref = _dispatch(batch, spec, "auto")
-        _assert_states_equal(ref, got, "pinned")
-
 
 class TestEnvInt:
     """Satellite: malformed env ints degrade to defaults, never raise."""
@@ -215,13 +125,6 @@ class TestEnvInt:
         assert env_int("X_LINT_INT", 7) == 7
         monkeypatch.setenv("X_LINT_INT", "nope")
         assert env_float("X_LINT_INT", 1.5) == 1.5
-
-    def test_malformed_mxu_threshold_does_not_abort(self, monkeypatch):
-        """Regression: scan_agg read HORAEDB_MXU_MAX_SEGMENTS with a bare
-        int() at import time — a typo killed the whole server."""
-        monkeypatch.setenv("HORAEDB_MXU_MAX_SEGMENTS", "8k")
-        assert mxu_max_segments() == 8192
-        assert resolve_segment_impl(500, "auto") in ("mxu", "scatter")
 
     def test_other_guarded_readers(self, monkeypatch):
         from horaedb_tpu.engine.compaction import merge_chunk_count
@@ -246,100 +149,282 @@ class TestEnvInt:
 
 class TestKernelRouter:
     def test_probes_then_serves_winner(self):
-        from horaedb_tpu.query.path_router import KernelRouter
-
         r = KernelRouter()
-        cands = ("scatter", "mxu", "hash")
+        cands = ("scatter", "mxu", "third")  # any number of candidates
         seen = []
-        # synthetic latencies: hash fastest; first sample of each impl is
-        # compile-tainted (huge) and must not poison the estimate
-        lat = {"scatter": 0.05, "mxu": 0.03, "hash": 0.01}
+        # synthetic latencies: the third fastest; first sample of each impl
+        # is compile-tainted (huge) and must not poison the estimate
+        lat = {"scatter": 0.05, "mxu": 0.03, "third": 0.01}
         for i in range(2 * len(cands)):
-            k = r.choose("key", "scatter", cands)
+            k, est = r.choose("key", "scatter", cands)
+            assert est is None  # no clean sample of it yet
             seen.append(k)
             r.record("key", k, 5.0 if seen.count(k) == 1 else lat[k])
         assert set(seen) == set(cands)  # every candidate warmed
-        assert r.choose("key", "scatter", cands) == "hash"
-        r.record("key", "hash", lat["hash"])
+        # the winner comes with its estimate: the journal's prediction
+        assert r.choose("key", "scatter", cands) == ("third", lat["third"])
+        r.record("key", "third", lat["third"])
 
     def test_reprobes_losers_on_cadence(self):
-        from horaedb_tpu.query.path_router import PROBE_EVERY, KernelRouter
+        from horaedb_tpu.query.path_router import PROBE_EVERY
 
         r = KernelRouter()
-        cands = ("scatter", "hash")
+        cands = ("scatter", "mxu")
         for i in range(2 * len(cands)):
-            k = r.choose("key", "scatter", cands)
+            k, _ = r.choose("key", "scatter", cands)
             r.record("key", k, 0.01 if k == "scatter" else 0.05)
         def serve(calls):
             picks = []
             for i in range(calls):
-                k = r.choose("key", "scatter", cands)
+                k, _ = r.choose("key", "scatter", cands)
                 picks.append(k)
                 r.record("key", k, 0.01 if k == "scatter" else 0.05)
             return picks
 
-        # hash's one clean sample is confirmed after PROBE_EVERY calls ...
-        assert serve(PROBE_EVERY + 1) == ["scatter"] * PROBE_EVERY + ["hash"]
-        # ... then the cadence is served time, not calls: hash (5 x slower) is
+        # mxu's one clean sample is confirmed after PROBE_EVERY calls ...
+        assert serve(PROBE_EVERY + 1) == ["scatter"] * PROBE_EVERY + ["mxu"]
+        # ... then the cadence is served time, not calls: mxu (5 x slower) is
         # due once scatter has served PROBE_EVERY x 0.05 s, every 81st call
         picks = serve(2 * (5 * PROBE_EVERY + 2))
-        assert picks.count("hash") == 2  # losers still get probed
-        assert "hash" not in picks[:5 * PROBE_EVERY - 1]
+        assert picks.count("mxu") == 2  # losers still get probed
+        assert "mxu" not in picks[:5 * PROBE_EVERY - 1]
         # the probes cost one part in PROBE_EVERY + 1 of the served seconds
-        assert picks.count("hash") * 0.05 <= (
-            picks.count("scatter") * 0.01 + picks.count("hash") * 0.05
+        assert picks.count("mxu") * 0.05 <= (
+            picks.count("scatter") * 0.01 + picks.count("mxu") * 0.05
         ) / (PROBE_EVERY + 1) + 0.05
 
     def test_lru_bound(self):
-        from horaedb_tpu.query.path_router import MAX_KEYS, KernelRouter
+        from horaedb_tpu.query.path_router import MAX_KEYS
 
         r = KernelRouter()
         for i in range(MAX_KEYS + 50):
             r.choose(("k", i), "scatter", ("scatter",))
         assert len(r._stats) <= MAX_KEYS
 
-    def test_observed_segments_feedback(self):
-        from horaedb_tpu.query.path_router import KernelRouter
+    def test_candidate_gating(self, monkeypatch):
+        import jax
 
-        r = KernelRouter()
-        assert r.observed_segments("key") is None
-        r.note_segments("key", 100)
-        assert r.observed_segments("key") == 100
-        r.note_segments("key", 0)  # EWMA decays, doesn't snap
-        assert 0 < r.observed_segments("key") < 100
+        from horaedb_tpu.query.kernel_choice import candidate_kernels
 
-    def test_candidate_gating(self):
-        from horaedb_tpu.query.path_router import candidate_kernels
-
-        # tiny domain: no hash (the table can't beat direct impls)
-        assert "hash" not in candidate_kernels(64, 10_000)
-        # dense estimate: no hash (near-full table = all overflow)
-        assert "hash" not in candidate_kernels(1024, 10_000, est_distinct=1024)
-        # sparse estimate: hash is worth probing
-        assert "hash" in candidate_kernels(65536, 10_000, est_distinct=8)
+        # no matrix unit here: the one-hot is worth a probe to 256 segments
+        assert candidate_kernels(64, 10_000) == ("scatter", "mxu")
+        assert candidate_kernels(256, 10_000) == ("scatter", "mxu")
+        assert candidate_kernels(257, 10_000) == ("scatter",)
+        # on the TPU: to four times the static crossover
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert candidate_kernels(4 * 8192, 10_000) == ("scatter", "mxu")
+        assert candidate_kernels(4 * 8192 + 1, 10_000) == ("scatter",)
         # scatter is always a candidate
         assert "scatter" in candidate_kernels(10**6, 10_000)
 
-    def test_seed_kernel(self):
-        from horaedb_tpu.query.path_router import seed_kernel
+    def test_seed_kernel(self, monkeypatch):
+        """The static rule, which seeds a never-measured shape."""
+        import jax
 
-        assert seed_kernel(65536, 8, "tpu") == "hash"
-        assert seed_kernel(65536, 8, "cpu") == "hash"
-        assert seed_kernel(1024, None, "tpu") == "mxu"
-        assert seed_kernel(10**6, None, "tpu") == "scatter"
-        assert seed_kernel(1024, None, "cpu") == "scatter"
+        from horaedb_tpu.query.kernel_choice import static_kernel
 
-    def test_hash_slots_sizing(self, monkeypatch):
-        from horaedb_tpu.ops.hash_agg import default_hash_slots, hash_slots_for
+        assert static_kernel(1024) == "scatter"  # the CPU has no MXU
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert static_kernel(1) == "single"
+        assert static_kernel(1024) == "mxu"
+        assert static_kernel(8192) == "mxu"
+        assert static_kernel(8193) == "scatter"
+        assert static_kernel(10**6) == "scatter"
+        # a fresh key starts on the seed, then warms the other candidate
+        spec = ScanAggSpec(n_groups=64, n_buckets=16, n_agg_fields=1).padded()
+        picks = []
+        for _ in range(3):
+            chosen, token = kernel_choice.choose(("t", "seed"), spec, 10_000)
+            picks.append(chosen.segment_impl)
+            KERNEL_ROUTER.record(token[0], token[1], 0.01)
+        assert picks == ["mxu", "mxu", "scatter"]
 
-        assert hash_slots_for(65536, 4) == 16  # 4x headroom, pow2
-        assert hash_slots_for(65536, 100) == 512
-        assert hash_slots_for(65536, None) == default_hash_slots(65536)
-        assert hash_slots_for(10**6, 10**6) == 4096  # cap
-        monkeypatch.setenv("HORAEDB_HASH_MAX_SLOTS", "256")
-        assert hash_slots_for(10**6, 10**6) == 256
-        monkeypatch.setenv("HORAEDB_HASH_MAX_SLOTS", "bogus")
-        assert hash_slots_for(10**6, 10**6) == 4096
+
+# The four cells of BENCHMARK.json as the served path hands them to the
+# chooser, and what the chip found there (PERF.md §5, "programs (XLA
+# Modules): ms per execution"): cell -> (n_groups, n_buckets, fields,
+# need_minmax, resident rows, the fastest program's impl, the candidates).
+CELLS = {
+    # cached_scan_single 1.868 ms
+    "high-cpu-count-max": (1, 1, 1, True, 4_320_000, "single", None),
+    # cached_scan_mxu_sel 2.494 ms / cached_scan_scatter_sel 2.667 ms
+    "single-groupby-5-8-1": (
+        1, 64, 5, True, 4_320_000, "mxu", ("scatter", "mxu")),
+    # cached_scan_scatter 158.5 ms / cached_scan_mxu 480.4 ms
+    "double-groupby-all": (
+        1024, 16, 10, False, 4_320_000, "scatter", ("scatter", "mxu")),
+    # cached_scan_scatter 721.7 ms, mxu not offered
+    "cpu-4000x12h.double-groupby-all": (
+        4096, 16, 10, False, 17_280_000, "scatter", ("scatter",)),
+}
+
+
+class TestOneChooser:
+    """PR 30: one module decides, and what it hands on is concrete."""
+
+    @pytest.mark.parametrize("cell", sorted(CELLS))
+    def test_choose_is_concrete_and_the_rule_names_the_chips_fastest(
+        self, monkeypatch, cell
+    ):
+        import jax
+
+        from horaedb_tpu.obs import device
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(
+            device, "device_free_bytes", lambda: int(15.75 * 2**30)
+        )
+        n_groups, n_buckets, fields, minmax, rows, fastest, cands = CELLS[cell]
+        n_seg = n_groups * n_buckets
+        assert n_seg in (1, 64, 16_384, 65_536)
+        spec = ScanAggSpec(
+            n_groups=n_groups, n_buckets=n_buckets, n_agg_fields=fields,
+            need_minmax=minmax,
+        ).padded()
+        assert spec.segment_impl == "auto"  # unchosen until here
+        assert kernel_choice.static_kernel(n_seg) == fastest
+        chosen, token = kernel_choice.choose(("cpu", cell), spec, rows)
+        assert chosen.segment_impl == fastest  # a fresh key starts on the seed
+        assert chosen == dataclasses.replace(spec, segment_impl=fastest)
+        if cands is None:
+            assert token is None
+        else:
+            assert token[1] == fastest
+            assert kernel_choice.candidate_kernels(
+                n_seg, rows, fields, minmax
+            ) == cands
+
+    @staticmethod
+    def _entry_points():
+        """name -> call(segment_impl): every way into a segment kernel."""
+        import jax
+        import jax.numpy as jnp
+
+        from horaedb_tpu.ops import scan_agg
+        from horaedb_tpu.parallel import dist_agg
+        from horaedb_tpu.parallel.mesh import serving_mesh
+
+        n, s = 128, 3
+        batch = build_padded_batch(
+            np.zeros(n, np.int32), np.zeros(n, np.int32), np.ones(n, bool),
+            [np.ones(n, np.float32)],
+        )
+        static = dict(n_groups=8, n_buckets=1, n_agg_fields=1,
+                      numeric_filters=(), need_minmax=True)
+
+        def spec(impl):
+            return ScanAggSpec(n_groups=8, n_buckets=1, n_agg_fields=1,
+                               segment_impl=impl)
+
+        resident = (jnp.zeros(n, jnp.int32), jnp.zeros(n, jnp.int32),
+                    jnp.ones((1, n), jnp.float32))
+        session = jnp.zeros(2 * (s + 1), jnp.int32)
+        dyn = jnp.asarray([0, 10, 0, 1], jnp.int32)
+        layouts = dict(value_layouts=(("raw",),), ts_layout=("raw",),
+                       series_layout=("raw",))
+        packed = ((resident[0],), (resident[1],), resident[2], session, dyn)
+        return {
+            "scan_agg_body": lambda impl: scan_agg.scan_agg_body(
+                jnp.asarray(batch.group_codes), jnp.asarray(batch.bucket_ids),
+                jnp.asarray(batch.mask), jnp.asarray(batch.values),
+                jnp.zeros(0, jnp.float32), segment_impl=impl, **static),
+            "scan_aggregate": lambda impl: scan_aggregate(batch, spec(impl)),
+            "cached_scan_agg_packed": lambda impl: scan_agg.cached_scan_agg_packed(
+                *packed, segment_impl=impl, selective=False, **static, **layouts),
+            "cached_scan_agg_packed.lower": lambda impl: (
+                scan_agg.cached_scan_agg_packed.lower(
+                    *packed, segment_impl=impl, selective=True, **static,
+                    **layouts)),
+            "cached_scan_agg_cohort": lambda impl: scan_agg.cached_scan_agg_cohort(
+                *packed[:3], jnp.stack([session] * 2), jnp.stack([dyn] * 2),
+                segment_impl=impl, **static, **layouts),
+            "dist_scan_aggregate": lambda impl: dist_agg.dist_scan_aggregate(
+                serving_mesh(), batch, spec(impl)),
+            "make_dist_scan_agg": lambda impl: dist_agg.make_dist_scan_agg(
+                serving_mesh(), spec(impl)),
+            "make_cached_dist_scan_agg": lambda impl: (
+                dist_agg.make_cached_dist_scan_agg(serving_mesh(), spec(impl))),
+        }
+
+    @pytest.mark.parametrize("entry", [
+        "scan_agg_body", "scan_aggregate", "cached_scan_agg_packed",
+        "cached_scan_agg_packed.lower", "cached_scan_agg_cohort",
+        "dist_scan_aggregate", "make_dist_scan_agg",
+        "make_cached_dist_scan_agg",
+    ])
+    def test_a_kernel_takes_only_a_concrete_name(self, entry):
+        """No entry point derives an impl: "auto" (an unchosen spec) and
+        "hash" (the kernel that went) raise, a chosen name runs."""
+        call = self._entry_points()[entry]
+        for impl in ("auto", "hash"):
+            with pytest.raises(ValueError, match="segment_impl"):
+                call(impl)
+        call("scatter")
+
+    def test_a_routed_request_takes_the_routers_lock_twice(self):
+        """``choose`` hands the impl's estimate back with the choice and
+        ``finish`` records once: two locks a request (five before)."""
+        from horaedb_tpu.ops.scan_agg import AggState
+
+        class Counting:
+            def __init__(self, lock):
+                self.lock, self.taken = lock, 0
+
+            def __enter__(self):
+                self.taken += 1
+                return self.lock.__enter__()
+
+            def __exit__(self, *exc):
+                return self.lock.__exit__(*exc)
+
+        lock = KERNEL_ROUTER._lock = Counting(KERNEL_ROUTER._lock)
+        try:
+            spec = ScanAggSpec(n_groups=8, n_buckets=4, n_agg_fields=1).padded()
+            chosen, token = kernel_choice.choose(("t", "locks"), spec, 1000)
+            state = AggState(counts=np.ones((8, 4), np.int64), sums=None,
+                             mins=None, maxs=None)
+            m: dict = {}
+            kernel_choice.finish(token, chosen, m, state, 0.01)
+        finally:
+            KERNEL_ROUTER._lock = lock.lock
+        assert lock.taken == 2
+        assert m["kernel"] == chosen.segment_impl != "auto"
+
+    REMOVED = (
+        "HORAEDB_SEGMENT_IMPL", "HORAEDB_KERNEL_ROUTER",
+        "HORAEDB_MXU_MAX_SEGMENTS", "HORAEDB_HASH_HOST_MAX_ROWS",
+        "HORAEDB_HASH_PROBE_ROUNDS", "HORAEDB_HASH_MAX_SLOTS", "hash_slots",
+    )
+
+    @staticmethod
+    def _sources(*parts):
+        import os
+
+        root = os.path.join(os.path.dirname(horaedb_tpu.__file__), *parts)
+        for folder, _, files in os.walk(root):
+            for name in files:
+                if name.endswith(".py"):
+                    path = os.path.join(folder, name)
+                    yield os.path.relpath(path, root), open(path).read()
+
+    def test_the_pins_the_switch_and_the_third_kernel_are_gone(self):
+        found = [
+            (path, name) for path, text in self._sources()
+            for name in self.REMOVED if name in text
+        ]
+        assert not found, found
+
+    def test_ops_imports_nothing_of_query(self):
+        """The arrow points down: query/kernel_choice -> ops, never back."""
+        import re
+
+        back = re.compile(
+            r"^\s*(from\s+(\.\.|horaedb_tpu\.)query\b"
+            r"|import\s+horaedb_tpu\.query\b|from\s+\.\.\s+import\s+query\b)",
+            re.M,
+        )
+        found = [path for path, text in self._sources("ops") if back.search(text)]
+        assert not found, found
 
 
 class TestStepCacheLRU:
@@ -358,17 +443,13 @@ class TestStepCacheLRU:
         dist_agg._STEP_CACHE.clear()
         for i in range(2, 30):
             spec = ScanAggSpec(
-                n_groups=i, n_buckets=1, n_agg_fields=1
+                n_groups=i, n_buckets=1, n_agg_fields=1,
+                segment_impl="scatter",
             ).padded()
             dist_agg.make_cached_dist_scan_agg(mesh, spec)
         assert len(dist_agg._STEP_CACHE) <= 8
-        # LRU: the most recent shape is still resident (cache keys carry
-        # the host-RESOLVED impl, not "auto" — that's what makes a live
-        # env flip re-key warm shapes)
-        spec = dist_agg._resolved(
-            ScanAggSpec(n_groups=29, n_buckets=1, n_agg_fields=1).padded()
-        )
-        assert spec.segment_impl in ("mxu", "scatter")
+        # LRU: the most recent shape is still resident, keyed by the spec
+        # with the chooser's concrete impl
         assert (mesh, spec, "cached") in dist_agg._STEP_CACHE
         dist_agg._STEP_CACHE.clear()
 
@@ -391,18 +472,22 @@ def _seed_groupby(db, n=500, hosts=20):
 class TestRoutingEndToEnd:
     SQL = "SELECT host, count(1) AS c, sum(v) AS s, min(w) AS lo FROM kr GROUP BY host"
 
-    def test_pinned_impls_agree_over_sql(self, db, monkeypatch):
+    @pytest.mark.parametrize("impl", ["scatter", "mxu"])
+    def test_pinned_impls_agree_over_sql(self, db, monkeypatch, impl):
+        """Each impl, the only candidate offered, agrees with the host."""
+        _only(monkeypatch, impl)
+        monkeypatch.setenv("HORAEDB_ADAPTIVE_PATH", "0")
         _seed_groupby(db)
-        results = {}
-        for impl in ("scatter", "mxu", "hash"):
-            monkeypatch.setenv("HORAEDB_SEGMENT_IMPL", impl)
+        want = sorted(
+            (f"h{h}", 25, float(sum(range(h, 500, 20))), float(2 * h))
+            for h in range(20)
+        )
+        for _ in range(3):
             out = db.execute(self.SQL)
-            results[impl] = sorted(
-                tuple(r.values()) for r in out.to_pylist()
-            )
-            if out.metrics.get("path", "").startswith("device"):
-                assert out.metrics.get("kernel") == impl
-        assert results["scatter"] == results["mxu"] == results["hash"]
+            assert sorted(tuple(r.values()) for r in out.to_pylist()) == want
+            assert out.metrics["path"].startswith("device")
+            assert out.metrics["kernel"] == impl
+        assert out.metrics["path"] == "device-cached"
 
     def test_kernel_in_ledger_and_query_stats(self, db):
         # ledgers open per SQL statement at the PROXY (the wire layer's
@@ -415,7 +500,7 @@ class TestRoutingEndToEnd:
             for _ in range(3):
                 out = proxy.handle_sql(self.SQL)
             kernel = out.metrics.get("kernel")
-            assert kernel in ("mxu", "scatter", "hash", "single", "host")
+            assert kernel in ("mxu", "scatter")
             stats = proxy.handle_sql(
                 "SELECT kernel, agg_segments FROM system.public.query_stats"
             ).to_pylist()
@@ -425,22 +510,6 @@ class TestRoutingEndToEnd:
         finally:
             proxy.close()
 
-    def test_router_disabled_matches_static(self, db, monkeypatch):
-        monkeypatch.setenv("HORAEDB_KERNEL_ROUTER", "0")
-        _seed_groupby(db)
-        for _ in range(3):
-            out = db.execute(self.SQL)
-        if out.metrics.get("path", "").startswith("device"):
-            import jax
-
-            n_seg = 32  # 20 hosts padded to pow2, 1 bucket
-            expect = (
-                "mxu"
-                if jax.default_backend() == "tpu" and n_seg <= mxu_max_segments()
-                else "scatter"
-            )
-            assert out.metrics["kernel"] == expect
-
     def test_agg_kernel_counter_moves(self, db):
         from horaedb_tpu.utils.metrics import REGISTRY
 
@@ -449,26 +518,6 @@ class TestRoutingEndToEnd:
         db.execute(self.SQL)
         text = REGISTRY.expose()
         assert "horaedb_agg_kernel_total" in text
-
-    def test_bootstrap_from_query_stats_history(self, db):
-        from horaedb_tpu.proxy import Proxy
-        from horaedb_tpu.query.path_router import bootstrap_observed_segments
-
-        proxy = Proxy(db)
-        try:
-            _seed_groupby(db)
-            for _ in range(3):
-                proxy.handle_sql(self.SQL)
-        finally:
-            proxy.close()
-        # the finalized history carries the live segment count; a fresh
-        # sighting of the same normalized SQL shape seeds from it
-        segs = bootstrap_observed_segments(self.SQL)
-        assert segs is not None and segs > 0
-        # an unrelated shape finds nothing
-        assert bootstrap_observed_segments(
-            "SELECT count(1) FROM never_seen_table"
-        ) is None
 
 
 class TestCacheDtypeAutoTune:
@@ -568,7 +617,6 @@ class TestRefusedKernel:
         from horaedb_tpu.ops import scan_agg
         from horaedb_tpu.proxy import Proxy
 
-        monkeypatch.delenv("HORAEDB_SEGMENT_IMPL", raising=False)
         monkeypatch.setenv("HORAEDB_ADAPTIVE_PATH", "0")
         real = scan_agg.cached_scan_agg_packed
         refuse: set = set()
@@ -607,7 +655,6 @@ class TestRefusedKernel:
     def test_refused_impl_is_an_event_and_the_next_candidate_serves(
         self, db, served
     ):
-        from horaedb_tpu.query.path_router import KERNEL_ROUTER
         from horaedb_tpu.utils.metrics import REGISTRY
 
         run, refuse, calls = served
@@ -637,7 +684,7 @@ class TestRefusedKernel:
     def test_every_impl_refused_is_the_hosts_exact_answer(self, db, served):
         run, refuse, calls = served
         before = len(self._events())
-        refuse.update(("scatter", "mxu", "hash"))
+        refuse.update(("scatter", "mxu"))
         paths = []
         for _ in range(5):
             out = run()  # never raises
@@ -645,17 +692,23 @@ class TestRefusedKernel:
             paths.append(out.metrics["path"])
         assert paths[-1] == "host" and out.metrics["kernel_refused"] is True
         refused = [e["attrs"]["impl"] for e in self._events()[before:]]
-        assert sorted(refused) == ["mxu", "scatter"]  # hash: never a candidate
+        assert sorted(refused) == ["mxu", "scatter"]
         assert sorted(calls) == ["mxu", "scatter"]  # one try each, then none
 
-    def test_a_pinned_impl_refused_goes_to_the_host(self, db, served, monkeypatch):
+    def test_the_only_candidate_refused_goes_to_the_host(
+        self, db, served, monkeypatch
+    ):
         run, refuse, calls = served
-        monkeypatch.setenv("HORAEDB_SEGMENT_IMPL", "scatter")
+        _only(monkeypatch, "scatter")
+        before = len(self._events())
         refuse.add("scatter")
         for _ in range(4):
             out = run()
             assert self._rows(out) == self._want(db)
         assert out.metrics["path"] == "host"
+        assert out.metrics["kernel_refused"] is True
+        assert calls == ["scatter"]  # one try, then never offered again
+        assert [e["attrs"]["impl"] for e in self._events()[before:]] == ["scatter"]
 
     def test_another_failure_is_still_the_requests_error(self, db, monkeypatch):
         from horaedb_tpu.ops import scan_agg

@@ -471,7 +471,7 @@ class TestFullScanNoRowGather:
             n_groups=n_groups, n_buckets=n_buckets,
             n_agg_fields=1 if packed_streams else 2,
             numeric_filters=((1 if packed_streams else 0, 4),),
-            need_minmax=True, segment_impl=impl, hash_slots=0,
+            need_minmax=True, segment_impl=impl,
             selective=selective, value_layouts=value_layouts,
             ts_layout=ts_layout, series_layout=("delta", 1),
         )
@@ -567,8 +567,14 @@ class TestBlockLookupKernelEquivalence:
     def test_encoded_series_equal_raw(self, width, run, step, impl, allow_kind):
         import jax.numpy as jnp
 
-        from horaedb_tpu.ops.scan_agg import cached_scan_agg
+        import jax
 
+        from horaedb_tpu.ops.scan_agg import cached_scan_agg_body
+
+        cached_scan_agg = jax.jit(cached_scan_agg_body, static_argnames=(
+            "n_groups", "n_buckets", "n_agg_fields", "numeric_filters",
+            "need_minmax", "segment_impl", "value_layouts", "series_layout",
+        ))
         n, n_valid = 4096, 29 * FOR_BLOCK + 87  # block 29 straddles the pad
         rng = np.random.default_rng(width * 100 + len(allow_kind))
         codes = _sorted_codes(n, run, step)

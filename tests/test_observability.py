@@ -881,11 +881,8 @@ class TestRouterProbeCounter:
             assert f"{line} 0.0" in out.stdout.splitlines()
 
     def test_metrics_counts_one_per_hand_out(self):
-        from horaedb_tpu.query.path_router import (
-            PROBE_EVERY,
-            KernelRouter,
-            PathRouter,
-        )
+        from horaedb_tpu.query.kernel_choice import KernelRouter
+        from horaedb_tpu.query.path_router import PROBE_EVERY, PathRouter
 
         def read(text):
             return {
@@ -900,18 +897,18 @@ class TestRouterProbeCounter:
             path, kernel = PathRouter(), KernelRouter()
             for kind in ("device", "device", "host"):
                 path.record("k", kind, 1.0 if kind == "device" else 2.0)
-            for impl in ("scatter", "scatter", "hash", "hash"):
+            for impl in ("scatter", "scatter", "mxu", "mxu"):
                 kernel.record("k", impl, 1.0 if impl == "scatter" else 2.0)
             handed = {"path": [], "kernel": []}
             for _ in range(2 * PROBE_EVERY + 1):
                 handed["path"].append(path.choose("k"))
                 path.record("k", handed["path"][-1], 1.0)
                 handed["kernel"].append(
-                    kernel.choose("k", "scatter", ("scatter", "hash"))
+                    kernel.choose("k", "scatter", ("scatter", "mxu"))[0]
                 )
                 kernel.record("k", handed["kernel"][-1], 1.0)
             assert handed["path"].count("host") == 1
-            assert handed["kernel"].count("hash") == 1
+            assert handed["kernel"].count("mxu") == 1
             after = read(await (await client.get("/metrics")).text())
             assert {r: after[r] - before[r] for r in after} == {
                 "path": 1.0, "kernel": 1.0,
@@ -1208,14 +1205,8 @@ class TestMetricsNameLint:
             missing.append("kernel: no query_stats column")
         if "`kernel`" not in docs:
             missing.append("kernel: undocumented in docs/OBSERVABILITY.md")
-        for knob in (
-            "HORAEDB_SEGMENT_IMPL", "HORAEDB_KERNEL_ROUTER",
-            "HORAEDB_MXU_MAX_SEGMENTS", "HORAEDB_HASH_MAX_SLOTS",
-            "HORAEDB_HASH_PROBE_ROUNDS", "HORAEDB_HASH_HOST_MAX_ROWS",
-            "HORAEDB_CACHE_DTYPE",
-        ):
-            if f"`{knob}`" not in wdocs:
-                missing.append(f"{knob}: undocumented in docs/WORKLOAD.md")
+        if "`HORAEDB_CACHE_DTYPE`" not in wdocs:
+            missing.append("HORAEDB_CACHE_DTYPE: undocumented in docs/WORKLOAD.md")
         assert not missing, missing
 
     def test_raw_scan_family_declared_and_documented(self):

@@ -129,7 +129,9 @@ class TestScanAggregate:
         vals = [rng.normal(size=n).astype(np.float32)]
 
         batch = build_padded_batch(codes, buckets, mask, vals)
-        spec = ScanAggSpec(n_groups=g, n_buckets=b, n_agg_fields=1).padded()
+        spec = ScanAggSpec(
+            n_groups=g, n_buckets=b, n_agg_fields=1, segment_impl="scatter"
+        ).padded()
         out = scan_aggregate(batch, spec)
 
         rc, rs, rmin, rmax = numpy_reference_agg(
@@ -149,7 +151,7 @@ class TestScanAggregate:
         batch = build_padded_batch(codes, buckets, mask, vals)
         spec = ScanAggSpec(
             n_groups=1, n_buckets=1, n_agg_fields=1,
-            numeric_filters=((0, ">"),),
+            numeric_filters=((0, ">"),), segment_impl="single",
         ).padded()
         out = scan_aggregate(batch, spec, filter_literals=[4000.0])
         assert out.counts[0, 0] == n - 4001
@@ -166,7 +168,8 @@ class TestScanAggregate:
             [np.arange(n, dtype=np.float32)],
         )
         spec = ScanAggSpec(
-            n_groups=1, n_buckets=1, n_agg_fields=1, numeric_filters=((0, "<"),)
+            n_groups=1, n_buckets=1, n_agg_fields=1, numeric_filters=((0, "<"),),
+            segment_impl="single",
         ).padded()
         scan_aggregate(batch, spec, [10.0])
         from horaedb_tpu.ops.scan_agg import _fused_scan_agg
@@ -179,7 +182,9 @@ class TestScanAggregate:
     def test_partial_combine_associative(self):
         rng = np.random.default_rng(1)
         n, g, b = 4096, 4, 2
-        spec = ScanAggSpec(n_groups=g, n_buckets=b, n_agg_fields=1).padded()
+        spec = ScanAggSpec(
+            n_groups=g, n_buckets=b, n_agg_fields=1, segment_impl="scatter"
+        ).padded()
 
         def batch():
             return build_padded_batch(
@@ -209,7 +214,9 @@ class TestScanAggregate:
             np.zeros(n, dtype=np.int32), np.zeros(n, dtype=np.int32),
             np.ones(n, dtype=bool), [],
         )
-        spec = ScanAggSpec(n_groups=1, n_buckets=1, n_agg_fields=0).padded()
+        spec = ScanAggSpec(
+            n_groups=1, n_buckets=1, n_agg_fields=0, segment_impl="single"
+        ).padded()
         out = scan_aggregate(batch, spec)
         assert out.counts[0, 0] == n and out.sums.shape[0] == 0
 
@@ -377,7 +384,7 @@ class TestCohortKernels:
             solo = cached_scan_agg_packed(
                 jnp.asarray(codes), jnp.asarray(ts_rel),
                 jnp.asarray(values), jnp.asarray(sess), jnp.asarray(dyn),
-                selective=False, hash_slots=0, **statics
+                selective=False, **statics
             )
             a = unpack_packed_state(batched[j], spec)
             b = unpack_packed_state(solo, spec)

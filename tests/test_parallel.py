@@ -29,7 +29,9 @@ class TestDistScanAgg:
 
     def test_matches_single_device(self, mesh):
         batch = self.make_batch()
-        spec = ScanAggSpec(n_groups=5, n_buckets=3, n_agg_fields=1).padded()
+        spec = ScanAggSpec(
+            n_groups=5, n_buckets=3, n_agg_fields=1, segment_impl="scatter"
+        ).padded()
         single = scan_aggregate(batch, spec)
         dist = dist_scan_aggregate(mesh, batch, spec)
         np.testing.assert_array_equal(single.counts, dist.counts)
@@ -50,7 +52,8 @@ class TestDistScanAgg:
             [rng.integers(-2, 3, n).astype(np.float32)],
         )
         spec = ScanAggSpec(
-            n_groups=5, n_buckets=3, n_agg_fields=1, numeric_filters=((0, op),)
+            n_groups=5, n_buckets=3, n_agg_fields=1, numeric_filters=((0, op),),
+            segment_impl="scatter",
         ).padded()
         single = scan_aggregate(batch, spec, [0.0])
         dist = dist_scan_aggregate(mesh, batch, spec, [0.0])
@@ -62,7 +65,9 @@ class TestDistScanAgg:
         import jax.numpy as jnp
 
         batch = self.make_batch(n=4096)
-        spec = ScanAggSpec(n_groups=5, n_buckets=3, n_agg_fields=1).padded()
+        spec = ScanAggSpec(
+            n_groups=5, n_buckets=3, n_agg_fields=1, segment_impl="scatter"
+        ).padded()
         step = make_dist_scan_agg(mesh, spec)
         counts, *_ = step(
             jnp.asarray(batch.group_codes),
@@ -163,7 +168,9 @@ class TestServingPathMesh:
             np.ones(n, dtype=bool),
             [rng.normal(size=n).astype(np.float32)],
         )
-        spec = ScanAggSpec(n_groups=5, n_buckets=3, n_agg_fields=1).padded()
+        spec = ScanAggSpec(
+            n_groups=5, n_buckets=3, n_agg_fields=1, segment_impl="scatter"
+        ).padded()
         single = scan_aggregate(batch, spec)
         dist = dist_scan_aggregate(m6, batch, spec)
         np.testing.assert_array_equal(single.counts, dist.counts)

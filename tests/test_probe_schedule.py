@@ -15,10 +15,10 @@ another.
 
 import pytest
 
+from horaedb_tpu.query.kernel_choice import KernelRouter
 from horaedb_tpu.query.path_router import (
     _PROBES,
     PROBE_EVERY,
-    KernelRouter,
     PathRouter,
     plan_shape_key,
 )
@@ -48,14 +48,14 @@ class _Path:
 
 class _Kernel(_Path):
     label = "kernel"
-    win, lose = "scatter", "hash"
+    win, lose = "scatter", "mxu"
     first_samples = 4  # two of each impl, the first compile-tainted
 
     def __init__(self):
         self.r = KernelRouter()
 
     def choose(self):
-        return self.r.choose("key", self.win, (self.win, self.lose))
+        return self.r.choose("key", self.win, (self.win, self.lose))[0]
 
 
 @pytest.fixture(params=[_Path, _Kernel], ids=["path", "kernel"])
@@ -243,13 +243,14 @@ class TestProbeSchedule:
 
 
 class TestKernelRouterLosers:
-    CANDS = ("scatter", "mxu", "hash")
-    LAT = {"scatter": 1.0, "mxu": 2.0, "hash": 8.0}
+    # the schedule takes any number of losers: "third" is only a label
+    CANDS = ("scatter", "mxu", "third")
+    LAT = {"scatter": 1.0, "mxu": 2.0, "third": 8.0}
 
     def _run(self, r, calls):
         picks = []
         for _ in range(calls):
-            k = r.choose("key", "scatter", self.CANDS)
+            k, _ = r.choose("key", "scatter", self.CANDS)
             picks.append(k)
             r.record("key", k, self.LAT[k])
         return picks
@@ -259,7 +260,7 @@ class TestKernelRouterLosers:
         confirmations: each estimate rests on two samples."""
         r = KernelRouter()
         picks = self._run(r, 2 * len(self.CANDS) + PROBE_EVERY + 2)
-        assert picks[-2:] == ["mxu", "hash"]
+        assert picks[-2:] == ["mxu", "third"]
         return r
 
     def test_each_loser_has_its_own_budget(self):
@@ -267,33 +268,34 @@ class TestKernelRouterLosers:
         the nearer one four times as often, each within its share."""
         picks = self._run(self._warmed(), 10 * (PROBE_EVERY * 8 + 1))
         served = picks.count("scatter") * self.LAT["scatter"]
-        for k in ("mxu", "hash"):
+        for k in ("mxu", "third"):
             n = picks.count(k)
             assert n >= 1
             assert (n - 1) * self.LAT[k] <= served / PROBE_EVERY
-        assert 3.5 <= picks.count("mxu") / picks.count("hash") <= 4.5
+        assert 3.5 <= picks.count("mxu") / picks.count("third") <= 4.5
 
     def test_most_overdue_loser_goes_first(self):
         r = self._warmed()
         for _ in range(200):  # served with no choose(): both fall due
             r.record("key", "scatter", self.LAT["scatter"])
-        # mxu is 200 / 2 = 100 of its times behind, hash 200 / 8 = 25
-        assert r.choose("key", "scatter", self.CANDS) == "mxu"
-        assert r.choose("key", "scatter", self.CANDS) == "hash"
-        assert r.choose("key", "scatter", self.CANDS) == "scatter"
+        # mxu is 200 / 2 = 100 of its times behind, the third 200 / 8 = 25
+        # (the impl, its estimate: what the decision journal predicts from)
+        assert r.choose("key", "scatter", self.CANDS) == ("mxu", 2.0)
+        assert r.choose("key", "scatter", self.CANDS) == ("third", 8.0)
+        assert r.choose("key", "scatter", self.CANDS) == ("scatter", 1.0)
 
     def test_refused_kernel_is_never_a_probe(self):
         r = self._warmed()
-        r.refuse("key", "hash")
+        r.refuse("key", "third")
         picks = self._run(r, 4 * (PROBE_EVERY * 8 + 1))
-        assert "hash" not in picks
+        assert "third" not in picks
         assert "mxu" in picks  # the other loser keeps its schedule
         # a refused WINNER takes its estimate with it, also when a dispatch
         # of it that was in flight reports after the refusal
         r.refuse("key", "scatter")
         r.record("key", "scatter", self.LAT["scatter"])
         assert "scatter" not in r.stats("key")["t"]
-        picks = [r.choose("key", "scatter", self.CANDS) for _ in range(3)]
+        picks = [r.choose("key", "scatter", self.CANDS)[0] for _ in range(3)]
         assert set(picks) == {"mxu"}
 
 

@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 import horaedb_tpu
+from horaedb_tpu.query.kernel_choice import KERNEL_ROUTER
 
 
 @pytest.fixture()
@@ -28,8 +29,6 @@ def _deterministic_raw(monkeypatch):
     assert on. Eligibility, budget, and kill-switch fallbacks still
     apply; dedicated tests re-enable routing explicitly."""
     monkeypatch.setenv("HORAEDB_ADAPTIVE_PATH", "0")
-    from horaedb_tpu.query.path_router import KERNEL_ROUTER
-
     KERNEL_ROUTER.reset()
     yield
     KERNEL_ROUTER.reset()
@@ -485,8 +484,6 @@ class TestPartialKernelRouting:
         assert sorted(tuple(r.values()) for r in out.to_pylist()) == sorted(
             tuple(r.values()) for r in expect
         )
-        from horaedb_tpu.query.path_router import KERNEL_ROUTER
-
         partial_keys = [
             k for k in KERNEL_ROUTER._stats
             if isinstance(k, tuple) and k and isinstance(k[0], tuple)
@@ -494,17 +491,24 @@ class TestPartialKernelRouting:
         ]
         assert partial_keys, "partial path never consulted the KernelRouter"
 
-    def test_partial_respects_pin(self, db, monkeypatch):
-        monkeypatch.setenv("HORAEDB_SEGMENT_IMPL", "scatter")
-        monkeypatch.setenv("HORAEDB_AGG_MEMORY_MB", "0.0001")
+    def test_partial_runs_the_one_candidate_offered(self, db, monkeypatch):
+        from horaedb_tpu.query import kernel_choice
+
+        monkeypatch.setattr(
+            kernel_choice, "candidate_kernels", lambda *a, **k: ("mxu",)
+        )
         _seed(db, n=300, hosts=10)
         sql = "SELECT host, count(1) AS c FROM rd GROUP BY host"
+        expect = db.execute(sql).to_pylist()
+        monkeypatch.setenv("HORAEDB_AGG_MEMORY_MB", "0.0001")
         out = db.execute(sql)
         assert out.metrics.get("path") == "device-partial"
-        from horaedb_tpu.query.path_router import KERNEL_ROUTER
-
-        assert not [
-            k for k in KERNEL_ROUTER._stats
+        assert sorted(tuple(r.values()) for r in out.to_pylist()) == sorted(
+            tuple(r.values()) for r in expect
+        )
+        (stats,) = [
+            KERNEL_ROUTER.stats(k) for k in list(KERNEL_ROUTER._stats)
             if isinstance(k, tuple) and k and isinstance(k[0], tuple)
             and k[0] and k[0][0] == "partial"
         ]
+        assert stats["warmed"] == {"mxu"}

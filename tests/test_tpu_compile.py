@@ -109,7 +109,7 @@ def _packed_static(n_groups, n_buckets, n_fields, need_minmax, impl,
     return dict(
         n_groups=n_groups, n_buckets=n_buckets, n_agg_fields=n_fields,
         numeric_filters=filters, need_minmax=need_minmax, segment_impl=impl,
-        hash_slots=0, selective=selective,
+        selective=selective,
         value_layouts=(("raw",),) * n_fields, **LAYOUTS,
     )
 
@@ -144,9 +144,8 @@ PACKED = {
 # double-groupby-all's 4000 hosts x 12 h -> 4096 x 16 = 65,536 segments, 48,000
 # of them live. Before PR 27 the compiler refused the scatter impl at 2^25
 # rows whatever the segment count ("Used 18.28G of 15.75G hbm": its (N, F)
-# update tile, F padded to 128 lanes) and, through its fallback, the hash impl.
+# update tile, F padded to 128 lanes).
 N_4000X12H = 1 << 25
-EST_4000X12H = 48_000
 AT_4000X12H = {
     "avg-10-fields": (10, False),
     "minmax-5-fields": (5, True),
@@ -164,18 +163,15 @@ def test_cached_packed_compiles_at_4000x12h(monkeypatch, compile_for, case):
         cached_scan_agg_packed,
         segment_temp_bytes,
     )
-    from horaedb_tpu.query.path_router import candidate_kernels, seed_kernel
+    from horaedb_tpu.query.kernel_choice import candidate_kernels, static_kernel
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.delenv("HORAEDB_MXU_MAX_SEGMENTS", raising=False)
     n_fields, need_minmax = AT_4000X12H[case]
     n, n_groups, n_buckets = N_4000X12H, 4096, 16
     n_seg = n_groups * n_buckets
-    impl = seed_kernel(n_seg, EST_4000X12H, "tpu")
+    impl = static_kernel(n_seg)
     assert impl == "scatter"
-    assert candidate_kernels(
-        n_seg, n, EST_4000X12H, n_fields, need_minmax
-    ) == (impl,)
+    assert candidate_kernels(n_seg, n, n_fields, need_minmax) == (impl,)
     args = (
         (((n // 32 + 1,), "uint32"), ((n // 128,), "int32")),  # ("delta", 1)
         (((n,), "int32"),),
@@ -295,13 +291,11 @@ PROGRAM_NAMES = {
     ("mxu", True): "cached_scan_mxu_sel",
     ("scatter", False): "cached_scan_scatter",
     ("scatter", True): "cached_scan_scatter_sel",
-    ("hash", False): "cached_scan_hash",
-    ("hash", True): "cached_scan_hash_sel",
 }
 
 
 @pytest.mark.parametrize("impl,selective", sorted(PROGRAM_NAMES))
-def test_named_program_lowers_under_its_name(monkeypatch, impl, selective):
+def test_named_program_lowers_under_its_name(impl, selective):
     """Each (segment impl, selective) is a jitted program of its own,
     named as documented, and its stages carry their ``named_scope`` into
     the HLO metadata (what a device trace shows for ``fusion.N``)."""
@@ -310,7 +304,6 @@ def test_named_program_lowers_under_its_name(monkeypatch, impl, selective):
 
     from horaedb_tpu.ops import scan_agg
 
-    monkeypatch.delenv("HORAEDB_SEGMENT_IMPL", raising=False)
     n, s, m = 1024, 7, 64
     n_groups, n_buckets = (1, 1) if impl == "single" else (8, 16)
     series = (jax.ShapeDtypeStruct((n // 16 + 1,), jnp.uint32),
@@ -325,7 +318,7 @@ def test_named_program_lowers_under_its_name(monkeypatch, impl, selective):
         jax.ShapeDtypeStruct((1 + 4 + (m if selective else 0),), jnp.int32),
         n_groups=n_groups, n_buckets=n_buckets, n_agg_fields=2,
         numeric_filters=((0, 4),), need_minmax=True, segment_impl=impl,
-        hash_slots=0, selective=selective,
+        selective=selective,
         value_layouts=(("dict", 7, True),) * 2,
         ts_layout=("dict", 9), series_layout=("delta", 1),
     )
@@ -346,9 +339,9 @@ def test_named_program_lowers_under_its_name(monkeypatch, impl, selective):
 # (impl, n_seg): True compiled for the described v5e, False refused.
 # Before PR 23 ("mxu", 8192) and ("mxu", 32768) were False.
 STEP2_AT_2M_ROWS = {
-    ("mxu", 64): True, ("scatter", 64): True, ("hash", 64): True,
-    ("mxu", 4096): True, ("scatter", 4096): True, ("hash", 4096): True,
-    ("mxu", 8192): True, ("scatter", 8192): True, ("hash", 8192): True,
+    ("mxu", 64): True, ("scatter", 64): True,
+    ("mxu", 4096): True, ("scatter", 4096): True,
+    ("mxu", 8192): True, ("scatter", 8192): True,
     ("mxu", 32768): True,
 }
 
@@ -356,11 +349,11 @@ STEP2_AT_2M_ROWS = {
 # The same at N = 2^25 (cpu-4000x12h), 10 fields avg-only and 5 fields with
 # min/max, per (impl, n_seg), on this tree (scratch compiles for the described
 # v5e, PR 27; temporaries 0.17-0.94 GB at 65,536 segments). On the parent (81d0b0f) every
-# ("scatter", *) and ("hash", *) was False at this row count, 64 segments
-# included: "Used 17.25G-18.69G of 15.75G hbm".
+# ("scatter", *) was False at this row count, 64 segments included: "Used
+# 17.25G-18.69G of 15.75G hbm".
 STEP2_AT_32M_ROWS = {
     (impl, n_seg): True
-    for impl in ("mxu", "scatter", "hash")
+    for impl in ("mxu", "scatter")
     for n_seg in (64, 4096, 8192, 32768, 65536)
 }
 
@@ -369,24 +362,19 @@ STEP2_AT_32M_ROWS = {
     (N, STEP2_AT_2M_ROWS), (N_4000X12H, STEP2_AT_32M_ROWS),
 ], ids=["2M-rows", "32M-rows"])
 def test_tpu_policy_offers_no_refused_impl(monkeypatch, n_rows, table):
-    """Told the backend is a TPU, the three places that choose a segment
-    impl never offer one whose program the chip's compiler refused at
-    that (rows, segments)."""
+    """Told the backend is a TPU, the one place that chooses a segment impl
+    (its seed and its candidates) never offers one whose program the chip's
+    compiler refused at that (rows, segments)."""
     import jax
 
-    from horaedb_tpu.ops.scan_agg import resolve_segment_impl
-    from horaedb_tpu.query.path_router import candidate_kernels, seed_kernel
+    from horaedb_tpu.query.kernel_choice import candidate_kernels, static_kernel
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.delenv("HORAEDB_SEGMENT_IMPL", raising=False)
-    monkeypatch.delenv("HORAEDB_MXU_MAX_SEGMENTS", raising=False)
-    assert resolve_segment_impl(64) == "mxu"  # the TPU branch is live
+    assert static_kernel(64) == "mxu"  # the TPU branch is live
     for n_seg in sorted({n for _, n in table}):
-        offered = {resolve_segment_impl(n_seg)}
-        for est in (None, 8, n_seg):
-            offered.add(seed_kernel(n_seg, est, "tpu"))
-            offered.update(candidate_kernels(n_seg, n_rows, est, 10, False))
-            offered.update(candidate_kernels(n_seg, n_rows, est, 5, True))
+        offered = {static_kernel(n_seg)}
+        offered.update(candidate_kernels(n_seg, n_rows, 10, False))
+        offered.update(candidate_kernels(n_seg, n_rows, 5, True))
         for impl in offered:
             assert table.get((impl, n_seg), True), (impl, n_seg)
 
@@ -398,21 +386,19 @@ def test_policy_offers_only_what_fits_the_device(monkeypatch):
     accumulators alone outgrow the chip is offered nothing (the host serves
     it), and so is anything once the device is nearly full."""
     from horaedb_tpu.obs import device
-    from horaedb_tpu.query.path_router import candidate_kernels
+    from horaedb_tpu.query.kernel_choice import candidate_kernels
 
     free = [int(15.75 * 2**30)]
     monkeypatch.setattr(device, "device_free_bytes", lambda: free[0])
-    shape = (65_536, N_4000X12H, EST_4000X12H)
+    shape = (65_536, N_4000X12H)
     assert candidate_kernels(*shape, 10, False) == ("scatter",)
     assert candidate_kernels(*shape, 5, True) == ("scatter",)
     # 17.28M rows by host and 10 s tick: 2^25 segments x 3 x 512 B x 2
-    assert candidate_kernels(1 << 25, N_4000X12H, None, 5, True) == ()
+    assert candidate_kernels(1 << 25, N_4000X12H, 5, True) == ()
     free[0] = 1 << 30
     assert candidate_kernels(*shape, 10, False) == ()
     monkeypatch.setattr(device, "device_free_bytes", lambda: None)  # the CPU
-    assert candidate_kernels(1 << 25, N_4000X12H, None, 5, True) == (
-        "scatter", "hash",
-    )
+    assert candidate_kernels(1 << 25, N_4000X12H, 5, True) == ("scatter",)
 
 
 def _numpy_group_by(seg, mask, vals, n_seg):
@@ -526,7 +512,7 @@ def test_chunked_scatter_through_the_packed_program(monkeypatch):
             jnp.asarray(pack_dyn([30.0], 100, 700, 0, 200)))
     static = dict(
         n_groups=8, n_buckets=4, n_agg_fields=2, numeric_filters=((1, 4),),
-        need_minmax=True, segment_impl="scatter", hash_slots=0,
+        need_minmax=True, segment_impl="scatter",
         selective=False, value_layouts=(("raw",),) * 2,
         ts_layout=("raw",), series_layout=("raw",),
     )
