@@ -37,6 +37,7 @@ from ..utils.tracectx import span as _span
 from . import ast, kernel_choice
 from .path_router import plan_shape_key
 from .plan import AggCall, GroupKey, QueryPlan
+from .result_json import rows_as_dicts
 
 @dataclass
 class ResultSet:
@@ -53,19 +54,7 @@ class ResultSet:
         return len(self.columns[0]) if self.columns else 0
 
     def to_pylist(self) -> list[dict[str, Any]]:
-        out = []
-        nulls = self.nulls or {}
-        for i in range(self.num_rows):
-            row = {}
-            for name, col in zip(self.names, self.columns):
-                m = nulls.get(name)
-                if m is not None and m[i]:
-                    row[name] = None
-                else:
-                    v = col[i]
-                    row[name] = v.item() if isinstance(v, np.generic) else v
-            out.append(row)
-        return out
+        return rows_as_dicts(self.names, self.columns, self.nulls)
 
     def column(self, name: str) -> np.ndarray:
         return self.columns[self.names.index(name)]

@@ -3,8 +3,10 @@
 Routes (default port 5440, matching the reference's http default,
 config.rs:176):
 
-    POST /sql            {"query": "..."}            -> {"rows": [...]}
-                         or {"affected_rows": N} for writes/DDL
+    POST /sql            {"query": "..."}            -> {"rows": [...],
+                         "names": [...]}, encoded from the result's columns
+                         (query/result_json), or {"affected_rows": N} for
+                         writes/DDL
     POST /write          {"table": t, "rows": [{...}]} JSON bulk write
     GET  /metrics        Prometheus text
     GET  /route/{table}  routing info (standalone: self)
@@ -33,15 +35,15 @@ import asyncio
 import functools
 import json
 import logging
-from typing import Any, Optional
+from typing import Optional
 
-import numpy as np
 from aiohttp import web
 
 from ..db import Connection, connect
 from ..proxy import BlockedError, OverloadedError, Proxy, QuotaExceededError
 from ..query.executor import ResultSet
 from ..query.interpreters import AffectedRows
+from ..query.result_json import SqlAnswer, dumps as _dumps
 from ..utils.metrics import REGISTRY
 
 logger = logging.getLogger("horaedb_tpu.server")
@@ -90,20 +92,22 @@ def _table_of_statement(stmt) -> Optional[str]:
     return getattr(stmt, "table", None)
 
 
-def _json_default(v: Any):
-    if isinstance(v, (np.integer,)):
-        return int(v)
-    if isinstance(v, (np.floating,)):
-        return float(v)
-    if isinstance(v, np.bool_):
-        return bool(v)
-    if isinstance(v, bytes):
-        return v.decode("utf-8", "replace")
-    raise TypeError(f"not JSON serializable: {type(v)}")
+def _answer_of(out, wire: str):
+    """A statement's result as the gateway hands it on, called on the worker
+    thread that ran the statement. Rows become a ``SqlAnswer``; where the
+    wire is HTTP the per-value part of its body is made here, under span
+    ``rows``, so the event loop never stalls for the length of an encode and
+    the handler's ``encode`` span is left the body's assembly."""
+    from ..utils.tracectx import span
 
-
-def _dumps(obj: Any) -> str:
-    return json.dumps(obj, default=_json_default)
+    if isinstance(out, AffectedRows):
+        return out
+    answer = SqlAnswer(out.names, out.columns, out.nulls)
+    if wire == "http":
+        with span("rows") as sp:
+            answer.prepare()
+            sp.set(rows=answer.num_rows, route=answer.route)
+    return answer
 
 
 class Served(tuple):
@@ -253,7 +257,13 @@ class SqlGateway:
 
     ``execute`` returns one of:
         ("affected", n)
-        ("rows", (names, rows_as_dicts))
+        ("rows", answer)    a ``query/result_json.SqlAnswer``: ``.names``
+                            and either face, made once each — ``.body()``,
+                            the /sql answer's bytes (encoded from the
+                            result's columns on the worker thread that ran
+                            the statement when the wire is HTTP), and
+                            ``.rows()``, dict rows (MySQL, PostgreSQL);
+                            ``names, rows = answer`` still unpacks
         ("error", (http_status, message, extra))
 
     ``extra`` classifies shed/blocked/quota errors for protocol-correct
@@ -399,7 +409,7 @@ class SqlGateway:
                     ):
                         served = await self._try_replica_local(
                             query, tenant, table, replica_read,
-                            staleness_ms, replica_epoch, deadline,
+                            staleness_ms, replica_epoch, deadline, wire,
                         )
                         if served is not None:
                             return served
@@ -451,7 +461,7 @@ class SqlGateway:
             # outlive a cancelled leader request so followers still get
             # their result
             task = asyncio.ensure_future(
-                self._run_local(proxy, query, tenant, deadline)
+                self._run_local(proxy, query, tenant, deadline, wire)
             )
             self._inflight[key] = task
 
@@ -471,7 +481,7 @@ class SqlGateway:
         # would let a post-commit SELECT join a pre-write flight that
         # became leader under the already-advanced epoch.
         try:
-            return await self._run_local(proxy, query, tenant, deadline)
+            return await self._run_local(proxy, query, tenant, deadline, wire)
         finally:
             self._write_epoch += 1
 
@@ -541,7 +551,8 @@ class SqlGateway:
                 task._hdb_followers = getattr(task, "_hdb_followers", 1) - 1
 
     async def _run_local(
-        self, proxy, query: str, tenant: str = "default", deadline=None
+        self, proxy, query: str, tenant: str = "default", deadline=None,
+        wire: str = "http",
     ):
         """Run the statement on this node. -> ``Served``: ``(kind,
         payload)`` plus the id of the statement's ``sql`` trace, which
@@ -549,23 +560,30 @@ class SqlGateway:
         handler's ``handle``; no handler trace, no id)."""
         from ..utils.tracectx import current_span
 
-        served = Served(await self._serve_local(proxy, query, tenant, deadline))
+        served = Served(
+            await self._serve_local(proxy, query, tenant, deadline, wire)
+        )
         waiting = current_span()
         if waiting is not None:
             served.request_id = waiting.attrs.get("request_id")
         return served
 
-    async def _serve_local(self, proxy, query: str, tenant: str, deadline):
+    async def _serve_local(
+        self, proxy, query: str, tenant: str, deadline, wire: str
+    ):
         from ..utils.deadline import DeadlineExceeded, QueryCancelled, bind
-        from ..utils.tracectx import span
 
         loop = asyncio.get_running_loop()
-        if tenant == "default":
+
+        def run():
             # positional call keeps handle_sql wrappers/monkeypatches with
             # the historical (sql) signature working
-            run = functools.partial(proxy.handle_sql, query)
-        else:
-            run = functools.partial(proxy.handle_sql, query, tenant=tenant)
+            if tenant == "default":
+                out = proxy.handle_sql(query)
+            else:
+                out = proxy.handle_sql(query, tenant=tenant)
+            return _answer_of(out, wire)
+
         # the request deadline rides a context COPY into the worker
         # thread (handle_sql picks it up via current_deadline()) so the
         # historical signature stays intact for wrappers/monkeypatches
@@ -598,10 +616,7 @@ class SqlGateway:
             return "error", (422, str(e), {})
         if isinstance(out, AffectedRows):
             return "affected", out.count
-        with span("rows") as sp:
-            rows = out.to_pylist()
-            sp.set(rows=len(rows))
-        return "rows", (list(out.names), rows)
+        return "rows", out
 
     async def _try_replica_local(
         self,
@@ -612,6 +627,7 @@ class SqlGateway:
         staleness_ms: Optional[int],
         replica_epoch: Optional[int],
         deadline=None,
+        wire: str = "http",
     ):
         """Serve an eligible SELECT from THIS node's read-only follower
         handle. Returns a gateway result, or None meaning "not servable
@@ -688,7 +704,7 @@ class SqlGateway:
                     out = proxy.handle_sql(query)
                 else:
                     out = proxy.handle_sql(query, tenant=tenant)
-            return out, epoch, lag_ms
+            return _answer_of(out, wire), epoch, lag_ms
 
         loop = asyncio.get_running_loop()
         from ..utils.deadline import bind
@@ -745,7 +761,7 @@ class SqlGateway:
         REPLICA_RESPONSE.set({"epoch": epoch, "lag_ms": lag_ms})
         if isinstance(out, AffectedRows):  # defensive: SELECTs only
             return "affected", out.count
-        return "rows", (list(out.names), out.to_pylist())
+        return "rows", out
 
     async def _forward_replica(
         self, route, query: str, staleness_ms: Optional[int], deadline=None
@@ -803,7 +819,7 @@ class SqlGateway:
             return "affected", body["affected_rows"]
         rows = body.get("rows", [])
         names = body.get("names") or (list(rows[0].keys()) if rows else [])
-        return "rows", (names, rows)
+        return "rows", SqlAnswer(names, rows=rows)
 
     async def _maybe_shed_to_follower(
         self, out, local_route, query: str,
@@ -879,7 +895,7 @@ class SqlGateway:
             return "affected", body["affected_rows"]
         rows = body.get("rows", [])
         names = body.get("names") or (list(rows[0].keys()) if rows else [])
-        return "rows", (names, rows)
+        return "rows", SqlAnswer(names, rows=rows)
 
 
 @web.middleware
@@ -1237,12 +1253,12 @@ def create_app(
             return web.json_response(
                 {"affected_rows": payload}, headers=headers
             )
-        names, rows = payload
         with span("encode") as sp:
-            text = _dumps({"rows": rows, "names": names})
-            sp.set(bytes=len(text))
+            body = payload.body()
+            sp.set(bytes=len(body))
         return web.Response(
-            text=text, content_type="application/json", headers=headers,
+            body=body, content_type="application/json", charset="utf-8",
+            headers=headers,
         )
 
     async def write(request: web.Request) -> web.Response:

@@ -256,7 +256,7 @@ class _Conn:
                 probe = _substitute(sql, [None] * max(n, 0))
                 kind, payload = await self.gateway.execute(probe.strip().rstrip(";"))
                 if kind == "rows":
-                    self._row_description(payload[0])
+                    self._row_description(payload.names)
                     return
             self.writer.write(_msg(b"n", b""))  # NoData
             return
@@ -264,7 +264,7 @@ class _Conn:
             raise _ExtError(f"portal {name!r} does not exist")
         kind, payload, _sql, _pos = self._portals[name]
         if kind == "rows":
-            self._row_description(payload[0])
+            self._row_description(payload.names)
         else:
             self.writer.write(_msg(b"n", b""))  # NoData
 
