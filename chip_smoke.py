@@ -333,13 +333,29 @@ def residency(server, conn, checks, devices) -> None:
 
 
 def mesh_residency(ex, checks, devices) -> None:
-    """Bytes of the cache's row arrays on each device: every device holds
-    a share, none holds an array whole."""
+    """Bytes of the cache's row arrays on each device, and the valid rows
+    among them (the gauge ``horaedb_scan_cache_shard_rows``: bytes alone
+    pass on a layout whose last blocks are padding): every device holds its
+    share of the rows, none holds an array whole."""
+    from horaedb_tpu.utils.metrics import REGISTRY
+
     per_device = {str(d): 0 for d in devices}
     whole = []
     with ex.scan_cache._lock:
         entries = list(ex.scan_cache._entries.values())
     for e in entries:
+        rows = [
+            int(REGISTRY.gauge(
+                "horaedb_scan_cache_shard_rows",
+                labels={"table": e.table_name, "shard": str(i)},
+            ).value)
+            for i in range(len(devices))
+        ]
+        emit(phase="mesh_residency", table=e.table_name, valid_rows=rows)
+        checks.require(
+            sum(rows) == e.n_valid and max(rows) - min(rows) <= 1,
+            f"{e.table_name}: {e.n_valid} rows lie {rows} over the devices",
+        )
         arrays = [e.series_codes_dev, e.ts_rel_dev, *e.value_cols_dev.values()]
         for a in arrays:  # a sharded entry keeps every column raw
             for sh in a.addressable_shards:
@@ -463,7 +479,7 @@ def main() -> int:
         ex = conn.interpreters.executor
         s1 = tsbs.single_groupby(5, 8, 1)
         if args.mesh:
-            statements = [(s1.name, s1.sql, "device-cached"),
+            statements = [(s1.name, s1.sql, "device-dist"),
                           ("raw-topk-host_7", S4_SQL, "raw_device")]
         else:
             s2, s3 = tsbs.double_groupby_all(1), tsbs.high_cpu_all(1)

@@ -104,11 +104,7 @@ class Connection:
         # process-wide device-residency inventory NOW, not whenever GC
         # collects it (system.public.device merges live sources only).
         try:
-            from .obs.device import unregister_occupancy_provider
-
-            unregister_occupancy_provider(
-                self.interpreters.executor.scan_cache
-            )
+            self.interpreters.executor.scan_cache.close()
         except Exception:
             pass
         # Catalog close flushes every table, and those flushes may

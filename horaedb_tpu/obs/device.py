@@ -129,6 +129,13 @@ _M_COMPILE = {
     for k in DEVICE_KERNEL_KINDS
     for outcome in ("compile", "hit")
 }
+# What the sharded programs' final aggregate moves between the chips: set by
+# the static shapes (parallel/dist_agg.combine_bytes), counted per dispatch.
+_M_COMBINE_BYTES = REGISTRY.counter(
+    "horaedb_dist_combine_bytes_total",
+    "bytes each device handed the mesh collectives that combine the "
+    "per-device partial aggregates of sharded dispatches",
+)
 _M_RESIDENT = {
     c: REGISTRY.gauge(
         "horaedb_device_resident_bytes",
@@ -289,6 +296,12 @@ def note_refusal(kind: str, impl: str, key, message: str) -> None:
         "kernel_refused", kernel=kind, impl=impl, shape=_shape_of(key),
         message=message,
     )
+
+
+def note_dist_combine(nbytes: int) -> None:
+    """One sharded dispatch handed each device's ``nbytes`` of partial
+    aggregates to the collectives."""
+    _M_COMBINE_BYTES.inc(nbytes)
 
 
 def note_compile_cache_hit(kind: str) -> None:

@@ -55,15 +55,35 @@ def _step_cache_max() -> int:
     return MAX_KEYS
 
 
-def _combine(state):
-    """The aggregation monoid as mesh collectives (final aggregate)."""
+def _combine(state, need_minmax: bool):
+    """The aggregation monoid as mesh collectives (final aggregate). Without
+    ``need_minmax`` the body's mins and maxs are zeros on every device and
+    stay as they are."""
     counts, sums, mins, maxs = state
-    return (
-        jax.lax.psum(counts, SHARD_AXIS),
-        jax.lax.psum(sums, SHARD_AXIS),
-        jax.lax.pmin(mins, SHARD_AXIS),
-        jax.lax.pmax(maxs, SHARD_AXIS),
-    )
+    with jax.named_scope("dist_combine"):
+        counts = jax.lax.psum(counts, SHARD_AXIS)
+        sums = jax.lax.psum(sums, SHARD_AXIS)
+        if need_minmax:
+            mins = jax.lax.pmin(mins, SHARD_AXIS)
+            maxs = jax.lax.pmax(maxs, SHARD_AXIS)
+    return counts, sums, mins, maxs
+
+
+def combine_bytes(spec: ScanAggSpec) -> int:
+    """Bytes each device hands ``_combine``'s collectives in one dispatch of
+    ``spec``, from the static shapes: int32 counts per segment and an f32
+    per segment and field for the sums (and for mins and maxs, if wanted)."""
+    n_seg = spec.n_groups * spec.n_buckets
+    reductions = 3 if spec.need_minmax else 1
+    return 4 * n_seg * (1 + reductions * spec.n_agg_fields)
+
+
+def dist_program_name(tag: str, segment_impl: str) -> str:
+    """What the device trace calls a sharded step: ``XLA Modules`` reads
+    ``jit_cached_dist_scatter(...)`` (``tag`` ``cached``: over the resident
+    columns; ``fused``: over an uploaded batch), as ``packed_program_name``
+    names the one-device programs."""
+    return f"{tag}_dist_{concrete_impl(segment_impl)}"
 
 
 def cached_step(cache_key, build) -> Callable:
@@ -85,10 +105,10 @@ def cached_step(cache_key, build) -> Callable:
 
 
 def _build_step(mesh: Mesh, spec: ScanAggSpec, tag: str, body, in_specs) -> Callable:
-    """shard_map(body)+combine, jitted and cached per (mesh, spec, tag).
-    ``spec.segment_impl`` is the chooser's concrete name: it keys the step
-    cache and the jit trace."""
-    concrete_impl(spec.segment_impl)
+    """shard_map(body)+combine, jitted under ``dist_program_name`` and cached
+    per (mesh, spec, tag). ``spec.segment_impl`` is the chooser's concrete
+    name: it keys the step cache and the jit trace."""
+    name = dist_program_name(tag, spec.segment_impl)
 
     def build():
         static_filters = encode_filter_ops(spec.numeric_filters)
@@ -103,15 +123,25 @@ def _build_step(mesh: Mesh, spec: ScanAggSpec, tag: str, body, in_specs) -> Call
                     numeric_filters=static_filters,
                     need_minmax=spec.need_minmax,
                     segment_impl=spec.segment_impl,
-                )
+                ),
+                spec.need_minmax,
             )
 
-        return jax.jit(
-            shard_map(
-                per_shard, mesh=mesh, in_specs=in_specs,
-                out_specs=(P(), P(), P(), P()),
-            )
+        sharded = shard_map(
+            per_shard, mesh=mesh, in_specs=in_specs,
+            out_specs=(P(), P(), P(), P()),
+            # the scatter's row chunks run in a lax.scan whose carried
+            # accumulators start replicated and come back varying over the
+            # mesh axis: no replication rule; _combine replicates every
+            # output itself, so the check adds nothing
+            check_vma=False,
         )
+
+        def step(*args):
+            return sharded(*args)
+
+        step.__name__ = step.__qualname__ = name
+        return jax.jit(step)
 
     return cached_step((mesh, spec, tag), build)
 
@@ -126,7 +156,7 @@ def make_dist_scan_agg(mesh: Mesh, spec: ScanAggSpec) -> Callable:
     return _build_step(
         mesh,
         spec,
-        "scan",
+        "fused",
         scan_agg_body,
         in_specs=(
             P(SHARD_AXIS),  # group codes (rows)
@@ -191,7 +221,7 @@ def dist_scan_aggregate(
     step = make_dist_scan_agg(mesh, spec)
     import time as _time
 
-    from ..obs.device import timed_dispatch
+    from ..obs.device import note_dist_combine, timed_dispatch
     from ..utils.querystats import note_kernel_dispatch
 
     t0 = _time.perf_counter()
@@ -205,6 +235,7 @@ def dist_scan_aggregate(
             coerce_literals(filter_literals),
         ),
     )
+    note_dist_combine(combine_bytes(spec))
     state = state_to_host(counts, sums, mins, maxs)
     # Compile accounting for the sharded fused path — a first-sighting
     # shard_map compile is a MULTI-SECOND stall on real chips and must

@@ -569,8 +569,7 @@ class Executor:
         ):
             cached = self._try_cached_agg(plan, table, m)
             if cached is not None:
-                path = "device-cached"
-                return self._finish_metrics(m, t_start, path, cached)
+                return self._finish_metrics(m, t_start, _cached_route(m), cached)
             if m.get("kernel_refused"):
                 # the device refused the aggregate's program and no other
                 # impl is left for it: the host scan answers, exactly
@@ -1290,7 +1289,7 @@ class Executor:
         call (mesh shard_map or the RTT-minimized packed path), delta
         fold, result assembly — exactly the pre-split cached path.
 
-        A packed program the device refuses for memory is a typed
+        A program of either arm that the device refuses for memory is a typed
         ``kernel_refused`` event, never the request's error: the impl is
         marked unusable for the shape and the next candidate runs; with
         none left this returns None and the caller serves from the host
@@ -1304,7 +1303,12 @@ class Executor:
         import jax
         import jax.numpy as jnp
 
-        from ..ops.scan_agg import coerce_literals, encode_filter_ops, state_to_host
+        from ..ops.scan_agg import (
+            coerce_literals,
+            encode_filter_ops,
+            segment_row_chunks,
+            state_to_host,
+        )
 
         plan, m, entry, spec = prep.plan, prep.m, prep.entry, prep.spec
         value_names, literals = prep.value_names, prep.literals
@@ -1317,14 +1321,22 @@ class Executor:
         from ..obs.device import cost_analysis, refusal_of, timed_dispatch
 
         t_kernel = _time.perf_counter()
+        n_seg = spec.n_groups * spec.n_buckets
         if entry.mesh is not None:
             # Sharded entry: the big arrays live split across the mesh —
             # run the shard_map cached kernel (the DEFAULT multi-device
             # serving path; single-device deployments take the packed arm).
-            from ..parallel.dist_agg import make_cached_dist_scan_agg
+            from ..obs.device import note_dist_combine
+            from ..parallel.dist_agg import (
+                combine_bytes,
+                dist_program_name,
+                make_cached_dist_scan_agg,
+            )
 
+            kind = "cached_dist"
+            n_dev = int(entry.mesh.devices.size)
+            key_head = ("cached-dist", n_dev, entry.shards.shard_len)
             with _span("upload", selective=False):
-                step = make_cached_dist_scan_agg(entry.mesh, spec)
                 args = (
                     entry.series_codes_dev,
                     entry.ts_rel_dev,
@@ -1337,17 +1349,26 @@ class Executor:
                     np.int32(t0_rel),
                     np.int32(width_i),
                 )
-            out = timed_dispatch(
-                "cached_dist", lambda: step(*args), impl=spec.segment_impl
-            )
-            m["mesh_devices"] = int(entry.mesh.devices.size)
-            with _span("fetch", bytes=sum(int(o.nbytes) for o in out)):
-                state = state_to_host(*out)
-            querystats.note_kernel_dispatch(
-                ("cached-dist", int(entry.mesh.devices.size), *prep.kernel_key),
-                _time.perf_counter() - t_kernel,
-                kind="cached_dist",
-            )
+            valid = entry.shards.valid_rows
+
+            def launch(spec):
+                step = make_cached_dist_scan_agg(entry.mesh, spec)
+                out = timed_dispatch(
+                    kind, lambda: step(*args), impl=spec.segment_impl,
+                    program=dist_program_name("cached", spec.segment_impl),
+                    mesh_devices=n_dev,
+                    shard_rows=[int(valid.max()), int(valid.min())],
+                    chunks=segment_row_chunks(
+                        spec.segment_impl, entry.shards.shard_len, n_seg,
+                        spec.n_agg_fields, spec.need_minmax,
+                    ),
+                )
+                note_dist_combine(combine_bytes(spec))
+                with _span("fetch", bytes=sum(int(o.nbytes) for o in out)):
+                    return state_to_host(*out)
+
+            cost_fn = None
+            m["mesh_devices"] = n_dev
         else:
             # Single-device serving: the RTT-minimized packed path — one
             # content-cached session upload, one dyn upload, one execute,
@@ -1356,11 +1377,12 @@ class Executor:
                 cached_scan_agg_packed,
                 pack_dyn,
                 packed_program_name,
-                segment_row_chunks,
                 unpack_packed_state,
             )
 
+            kind = "cached_packed"
             selective = row_idx is not None
+            key_head = ("cached-packed", selective)
             with _span("upload", selective=selective):
                 values_dev = entry.values_for(value_names)
                 session_dev = entry.session_for(gos, allow_scan)
@@ -1373,8 +1395,10 @@ class Executor:
                     jnp.asarray(dyn),
                 )
             n_rows = len(row_idx) if selective else entry.padded_rows
-            while True:
-                pkwargs = dict(
+            pkwargs = {}
+
+            def launch(spec):
+                pkwargs.update(
                     n_groups=spec.n_groups,
                     n_buckets=spec.n_buckets,
                     n_agg_fields=spec.n_agg_fields,
@@ -1386,59 +1410,62 @@ class Executor:
                     ts_layout=entry.ts_layout,
                     series_layout=entry.series_layout,
                 )
-                try:
-                    packed = timed_dispatch(
-                        "cached_packed",
-                        lambda: _fetch_behind(
-                            cached_scan_agg_packed(*pargs, **pkwargs)
-                        ),
-                        impl=spec.segment_impl,
-                        program=packed_program_name(spec.segment_impl, selective),
-                        chunks=segment_row_chunks(
-                            spec.segment_impl, n_rows,
-                            spec.n_groups * spec.n_buckets,
-                            spec.n_agg_fields, spec.need_minmax,
-                        ),
-                    )
-                    with _span("fetch", bytes=int(packed.nbytes)):
-                        state = unpack_packed_state(packed, spec)
-                    break
-                except jax.errors.JaxRuntimeError as e:
-                    # a program the device has no room for (the compiler's
-                    # refusal raises at the call, a failed execution where
-                    # its result is fetched): the next impl, else the host
-                    message = refusal_of(e)
-                    if message is None:
-                        raise
-                    spec = self._reroute_refused(
-                        prep, message, _time.perf_counter() - t_kernel
-                    )
-                    if spec is None:
-                        m["kernel_refused"] = True
-                        return None
-            querystats.note_kernel_dispatch(
-                ("cached-packed", selective, *prep.kernel_key),
-                _time.perf_counter() - t_kernel,
-                kind="cached_packed",
-                cost_fn=lambda: cost_analysis(
-                    cached_scan_agg_packed, pargs, pkwargs
-                ),
-            )
+                packed = timed_dispatch(
+                    kind,
+                    lambda: _fetch_behind(
+                        cached_scan_agg_packed(*pargs, **pkwargs)
+                    ),
+                    impl=spec.segment_impl,
+                    program=packed_program_name(spec.segment_impl, selective),
+                    chunks=segment_row_chunks(
+                        spec.segment_impl, n_rows, n_seg,
+                        spec.n_agg_fields, spec.need_minmax,
+                    ),
+                )
+                with _span("fetch", bytes=int(packed.nbytes)):
+                    return unpack_packed_state(packed, spec)
+
+            def cost_fn():
+                return cost_analysis(cached_scan_agg_packed, pargs, pkwargs)
+
+        while True:
+            try:
+                state = launch(spec)
+                break
+            except jax.errors.JaxRuntimeError as e:
+                # a program the device has no room for (the compiler's
+                # refusal raises at the call, a failed execution where
+                # its result is fetched): the next impl, else the host
+                message = refusal_of(e)
+                if message is None:
+                    raise
+                spec = self._reroute_refused(
+                    prep, kind, message, _time.perf_counter() - t_kernel
+                )
+                if spec is None:
+                    m["kernel_refused"] = True
+                    m.pop("mesh_devices", None)
+                    return None
+        querystats.note_kernel_dispatch(
+            (*key_head, *prep.kernel_key), _time.perf_counter() - t_kernel,
+            kind=kind, cost_fn=cost_fn,
+        )
         kernel_choice.finish(
             prep.krec, spec, m, state, _time.perf_counter() - t_kernel
         )
         return self._fold_and_assemble(prep, state)
 
-    def _reroute_refused(self, prep: "CachedAggPrep", message: str,
-                         seconds: float):
-        """The device refused ``prep``'s packed program: journal it, take
-        the impl out of the shape's candidates and choose again. -> the
-        spec to dispatch next (also set on ``prep``), or None when nothing
-        is left to offer (or the impl was the unrouted ``single``)."""
+    def _reroute_refused(self, prep: "CachedAggPrep", kind: str,
+                         message: str, seconds: float):
+        """The device refused ``prep``'s program of dispatch ``kind`` (the
+        packed or the sharded one): journal it, take the impl out of the
+        shape's candidates and choose again. -> the spec to dispatch next
+        (also set on ``prep``), or None when nothing is left to offer (or
+        the impl was the unrouted ``single``)."""
         from ..obs.device import note_refusal
 
         spec = prep.spec
-        note_refusal("cached_packed", spec.segment_impl, prep.kernel_key, message)
+        note_refusal(kind, spec.segment_impl, prep.kernel_key, message)
         if prep.krec is None:
             return None
         kernel_choice.refused(prep.krec, seconds)
@@ -1644,7 +1671,7 @@ class Executor:
                         outcomes[i] = self.execute(plans[i], table)
                         continue
                     outcomes[i] = self._finish_metrics(
-                        prep.m, t_start, "device-cached", out
+                        prep.m, t_start, _cached_route(prep.m), out
                     )
                 except BaseException as e:
                     outcomes[i] = e
@@ -2117,6 +2144,8 @@ class Executor:
                 kernel_key, _time.perf_counter() - t_kernel, kind=dkind
             )
 
+        if entry.shards is not None:
+            idx = entry.shards.host_rows(idx)
         base = (
             entry.rows.take(np.asarray(idx, dtype=np.int64))
             if len(idx)
@@ -2407,6 +2436,13 @@ class Executor:
         if (stmt.distinct or has_window) and (stmt.limit is not None or stmt.offset):
             result = _slice_result(result, stmt.offset, stmt.limit)
         return result
+
+
+def _cached_route(m: dict) -> str:
+    """The ledger's route of a serve from the scan cache: ``device-dist``
+    where the sharded program over the mesh ran it (as for an uncached
+    sharded aggregate), else ``device-cached``."""
+    return "device-dist" if "mesh_devices" in m else "device-cached"
 
 
 def _fetch_behind(result):

@@ -55,6 +55,12 @@ _M_BUILD_SECONDS = REGISTRY.counter(
     "wall seconds spent building scan-cache entries",
 )
 
+_SHARD_ROWS = "horaedb_scan_cache_shard_rows"
+_SHARD_ROWS_HELP = (
+    "valid rows of a sharded scan-cache entry on each device of the mesh, "
+    "set when the entry is built"
+)
+
 _I32_MAX = 2**31 - 1
 
 
@@ -169,6 +175,10 @@ class CachedTableScan:
     # the mesh the big arrays are sharded over (None = single device);
     # queries on a sharded entry MUST use the shard_map cached kernel.
     mesh: object = None
+    # how the rows lie on that mesh (parallel/mesh.ShardLayout): equal
+    # blocks of the valid rows, each padded at its own tail. None = single
+    # device, where device row i is host row i
+    shards: object = None
     # owning table name — keys the cache's per-column usage map (dtype
     # auto-tuning) from extend paths that only hold the entry.
     table_name: str = ""
@@ -610,13 +620,34 @@ class ScanCache:
                 entry.pending_promotions.add(c)
 
     @staticmethod
+    def _drop_shard_gauges(entry: CachedTableScan) -> None:
+        if entry.shards is not None:
+            for i in range(entry.shards.n_shards):
+                REGISTRY.remove(
+                    _SHARD_ROWS, {"table": entry.table_name, "shard": str(i)}
+                )
+
+    def close(self) -> None:
+        """The owning database closed: the cache leaves the process-wide
+        device inventory now (not whenever GC collects it), and its sharded
+        entries' gauges leave ``/metrics``."""
+        from ..obs.device import unregister_occupancy_provider
+
+        unregister_occupancy_provider(self)
+        with self._lock:
+            entries = list(self._entries.values())
+        for entry in entries:
+            self._drop_shard_gauges(entry)
+
+    @staticmethod
     def _resolve_pending_evicted(entry: CachedTableScan) -> None:
         """Resolve still-pending promotion decisions of a dying entry as
         ``outcome=evicted`` — the re-upload they predicted will never
         happen, so without this they sit pending until TTL expiry and
         the tenantsim accounting shows them as leaks. No calibration:
         there is no realized-bytes ground truth for an upload that never
-        ran."""
+        ran. A sharded entry's rows-per-device gauges go with it."""
+        ScanCache._drop_shard_gauges(entry)
         pending = entry.pending_promotions
         if not pending:
             return
@@ -745,6 +776,11 @@ class ScanCache:
                     series_layout="/".join(map(str, entry.series_layout)),
                     ts_layout="/".join(map(str, entry.ts_layout)),
                 )
+                if entry.shards is not None:
+                    sp.set(
+                        mesh_devices=entry.shards.n_shards,
+                        shard_rows=entry.shards.valid_rows.tolist(),
+                    )
         if entry is None:
             return None, False, None
         _M_BUILDS.inc()
@@ -835,33 +871,25 @@ class ScanCache:
         offsets = np.zeros(n_series + 1, dtype=np.int64)
         np.cumsum(counts, out=offsets[1:])
         first_idx = offsets[:-1].copy()
-        # One explicit pad row at index n (series code n_series, allow
-        # masked): selective gathers point their padding here even when n
-        # itself is a power of two.
-        codes = pad_to_bucket(
-            np.append(inverse.astype(np.int32), np.int32(n_series)), n + 1,
-            fill=n_series,
-        )
-        ts_rel = pad_to_bucket(
-            np.append((rows.timestamps - min_ts).astype(np.int32), np.int32(-1)),
-            n + 1,
-            fill=np.int32(-1),
-        )
         # Multi-device: the big row arrays live SHARDED across the mesh so
         # steady-state serving is itself distributed (each chip holds and
         # scans 1/Nth of the table; combine rides the collectives). Small
         # tables stay single-device — same threshold as the uncached path
         # (collective dispatch would dominate).
-        from ..parallel.mesh import dist_min_rows, serving_mesh
+        from ..parallel.mesh import ShardLayout, dist_min_rows, serving_mesh
 
         mesh = serving_mesh() if n >= dist_min_rows() else None
-        place = None
+        shards = None
         if mesh is not None:
-            n_dev = int(mesh.devices.size)
-            if len(codes) % n_dev:
-                extra = n_dev - len(codes) % n_dev
-                codes = np.pad(codes, (0, extra), constant_values=n_series)
-                ts_rel = np.pad(ts_rel, (0, extra), constant_values=-1)
+            # Every device gets its share of the VALID rows (a power-of-two
+            # bucket cut into equal blocks would leave the last devices
+            # padding only); a block's pad rows carry the pad series code,
+            # which every kernel masks.
+            shards = ShardLayout.of(n, int(mesh.devices.size))
+            codes = shards.place(inverse.astype(np.int32), fill=n_series)
+            ts_rel = shards.place(
+                (rows.timestamps - min_ts).astype(np.int32), fill=-1
+            )
             import jax
             from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -870,7 +898,26 @@ class ScanCache:
             ts_dev = jax.device_put(ts_rel, place)
             series_parts, ts_parts = (codes_dev,), (ts_dev,)
             series_layout = ts_layout = ("raw",)
+            for i, valid in enumerate(shards.valid_rows):
+                REGISTRY.gauge(
+                    _SHARD_ROWS, _SHARD_ROWS_HELP,
+                    labels={"table": table_name, "shard": str(i)},
+                ).set(int(valid))
         else:
+            # One explicit pad row at index n (series code n_series, allow
+            # masked): selective gathers point their padding here even when
+            # n itself is a power of two.
+            codes = pad_to_bucket(
+                np.append(inverse.astype(np.int32), np.int32(n_series)), n + 1,
+                fill=n_series,
+            )
+            ts_rel = pad_to_bucket(
+                np.append(
+                    (rows.timestamps - min_ts).astype(np.int32), np.int32(-1)
+                ),
+                n + 1,
+                fill=np.int32(-1),
+            )
             # Compressed layouts (ISSUE 19) — single-device entries only
             # (the shard_map kernels scan raw streams). Both codecs are
             # lossless and roundtrip-verified; any rejection falls back
@@ -963,6 +1010,7 @@ class ScanCache:
             ts_rel_dev=ts_dev,
             value_cols_dev={},
             mesh=mesh,
+            shards=shards,
             table_name=table_name,
             series_tsids=uniq,
             series_offsets=offsets,
@@ -1079,9 +1127,11 @@ class ScanCache:
                 # device_put transfers straight to each shard (no staging
                 # of the full column on one device)
                 arr = as_values(entry.rows.column(c)).astype(np.float32, copy=False)
-                padded = np.pad(arr, (0, target - len(arr))).astype(
-                    np.dtype(dtype), copy=False
-                )
+                padded = (
+                    np.pad(arr, (0, target - len(arr)))
+                    if entry.shards is None
+                    else entry.shards.place(arr, fill=0)
+                ).astype(np.dtype(dtype), copy=False)
                 # Layout tuner (ISSUE 19): a low-cardinality exact column
                 # stores as bit-packed dictionary codes + a small sorted
                 # f32 dictionary — lossless (bit-verified in dict_encode)
@@ -1166,7 +1216,7 @@ class ScanCache:
                 if entry.series_value_stats is None:
                     entry.series_value_stats = {}
                 seg = entry.series_offsets[:-1]
-                stat_src = padded[: len(arr)].astype(np.float64)
+                stat_src = arr.astype(padded.dtype, copy=False).astype(np.float64)
                 entry.series_value_stats[c] = (
                     np.fmin.reduceat(stat_src, seg),
                     np.fmax.reduceat(stat_src, seg),

@@ -273,7 +273,7 @@ class TestShardedCache:
             "max(v) AS hi FROM t GROUP BY host"
         )
         out = warm(db, sql)
-        assert ex.last_path == "device-cached"
+        assert ex.last_path == "device-dist"  # the cache's sharded program
         assert ex.last_metrics.get("mesh_devices") == 8
         entry = ex.scan_cache._entries["t"]
         assert entry.mesh is not None
@@ -303,7 +303,7 @@ class TestShardedCache:
             "WHERE v > 100 AND host = 'h1' GROUP BY host"
         )
         out = warm(db, sql)
-        assert ex.last_path == "device-cached"
+        assert ex.last_path == "device-dist"  # the cache's sharded program
         assert ex.last_metrics.get("mesh_devices") == 8
         rows = out.to_pylist()
         # h1 rows: i % 5 == 1 and v=i > 100 -> i in {101..499}: 80 rows

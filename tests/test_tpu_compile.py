@@ -280,6 +280,43 @@ def test_sharded_steps_compile_on_four_chips(mesh4):
     ).compile()
 
 
+def test_sharded_scatter_compiles_at_4000x12h_on_four_chips(mesh4):
+    """cpu-4000x12h-dist4's program: 17,280,000 rows in four equal blocks
+    (``ShardLayout``), each chip's chunked scatter into 65,536 segments and
+    the collectives over them, under the name the trace shows (one case: a
+    compile of it takes 40 s)."""
+    from horaedb_tpu.ops.scan_agg import ScanAggSpec, segment_temp_bytes
+    from horaedb_tpu.parallel.dist_agg import make_cached_dist_scan_agg
+    from horaedb_tpu.parallel.mesh import ShardLayout
+
+    mesh, spec = mesh4
+    n_fields, need_minmax = AT_4000X12H["avg-10-fields"]
+    shards = ShardLayout.of(17_280_000, 4)
+    assert shards.valid_rows.tolist() == [4_320_000] * 4
+    assert shards.shard_len == 66 * 65_536  # whole scatter chunks
+    n = shards.padded_rows
+    step = make_cached_dist_scan_agg(
+        mesh, ScanAggSpec(n_groups=4096, n_buckets=16, n_agg_fields=n_fields,
+                          need_minmax=need_minmax, segment_impl="scatter"),
+    )
+    compiled = step.lower(
+        spec((n,), "int32", "shard"), spec((n,), "int32", "shard"),
+        spec((n_fields, n), "float32", None, "shard"),
+        spec((S + 1,), "int32"), spec((S + 1,), "bool"),
+        spec((0,), "float32"), *[spec((), "int32")] * 4,
+    ).compile()
+    text = compiled.as_text()
+    assert "HloModule jit_cached_dist_scatter," in text
+    # counts and sums; mins and maxs only where a statement asks for them
+    assert text.count(" all-reduce(") + text.count(" all-reduce-start(") == 2, [
+        ln for ln in text.splitlines() if "all-reduce" in ln
+    ][:8]
+    mem = compiled.memory_analysis()  # per chip
+    assert mem.temp_size_in_bytes <= segment_temp_bytes(
+        "scatter", shards.shard_len, 65_536, n_fields, need_minmax
+    ), mem
+
+
 # ---- CPU tests: the policy and the repair the compiles above rest on ------
 
 # (segment impl, selective) -> the program's documented name
