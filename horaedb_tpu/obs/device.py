@@ -136,6 +136,13 @@ _M_COMBINE_BYTES = REGISTRY.counter(
     "bytes each device handed the mesh collectives that combine the "
     "per-device partial aggregates of sharded dispatches",
 )
+# How many scatter steps reduced each run of equal segment ids to one update
+# row (ops/scan_agg._fits), summed over a mesh's chips on the device.
+_M_FOLDED_CHUNKS = REGISTRY.counter(
+    "horaedb_scan_folded_chunks_total",
+    "row chunks whose segment scatter ran one update row per run of equal "
+    "segment ids, summed over chips",
+)
 _M_RESIDENT = {
     c: REGISTRY.gauge(
         "horaedb_device_resident_bytes",
@@ -302,6 +309,12 @@ def note_dist_combine(nbytes: int) -> None:
     """One sharded dispatch handed each device's ``nbytes`` of partial
     aggregates to the collectives."""
     _M_COMBINE_BYTES.inc(nbytes)
+
+
+def note_folded_chunks(n: int) -> None:
+    """A fetched aggregate's scatter folded ``n`` of its row chunks."""
+    if n:
+        _M_FOLDED_CHUNKS.inc(n)
 
 
 def note_compile_cache_hit(kind: str) -> None:

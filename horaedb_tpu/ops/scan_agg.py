@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.device import note_folded_chunks
 from .encoding import (
     PaddedBatch,
     decode_layouts as _decode_layouts,
@@ -67,6 +68,25 @@ _MINMAX_MASK_BYTES = 1 << 28
 # Bytes of the row-major (rows, fields) update tile one step of the scatter
 # impl may hold in HBM (``scatter_chunk_rows``).
 _SCATTER_TILE_BYTES = 1 << 25
+# A scatter step of C rows folds each run of equal segment ids into one update
+# row when it holds at most C / _FOLD_SHARE runs (``fold_cap``), and scatters
+# its rows as they are otherwise. The scatter costs 7-11 ns an update row on
+# the v5e whatever the row holds (PERF.md §5); resident rows lie in series and
+# time order, so a host x hour group is a run of 360 rows (~182 runs in a
+# 2^16-row step) where short runs (6 rows: ~11,000) fold nothing. The fold's
+# own time grows with the cap (a run slot reads a lane row per field at each
+# of its ends, ``_run_totals``): at 2^25 rows 29 ms at C/256, 104 at C/64.
+_FOLD_SHARE = 256
+# Rows of one block of the fold's run reduction: one lane row of the chip.
+_FOLD_BLOCK = 128
+# Rows one iteration of the scatter impl's loop takes at most (``fold_group``):
+# when all its steps fold, one fold and one scatter take all their runs. A
+# step at a time the fold is ~40 device operations a step, 20,000 a query at
+# 2^25 rows.
+_FOLD_GROUP_ROWS = 1 << 21
+# The mapped axis of the cohort program (``_cohort_body``): its members agree
+# on which steps fold, so each choice runs one branch and not both.
+_COHORT_AXIS = "cohort"
 
 
 def concrete_impl(segment_impl: str) -> str:
@@ -271,9 +291,13 @@ def segment_temp_bytes(impl: str, n_rows: int, n_seg: int, n_fields: int,
     offers the impl (``query/kernel_choice.candidate_kernels``). Every impl
     keeps a handful of row-length vectors (segment ids, mask, a scatter's
     sort keys and permutation); the scatter holds one update tile and one
-    accumulator (in and out) per reduction, each padded to 128 lanes; the MXU
-    impl its min/max match mask. ``tests/test_tpu_compile.py`` holds it
-    against the v5e compiler's own accounting at 2^21 and 2^25 rows."""
+    accumulator (in and out) per reduction, each padded to 128 lanes, and a
+    group of steps' fold (``fold_group``) per step its run-end flags as
+    int32 blocks, its values cut into blocks (F padded to 8 sublanes) and,
+    per reduction, its run tile (``fold_cap`` rows of 512 B) and the two
+    lane rows it reads a run; the MXU impl its min/max match mask.
+    ``tests/test_tpu_compile.py`` holds it against the v5e compiler's own
+    accounting at 2^21 and 2^25 rows."""
     row_vectors = 8 * 4 * n_rows
     if impl == "single":
         return row_vectors
@@ -283,55 +307,95 @@ def segment_temp_bytes(impl: str, n_rows: int, n_seg: int, n_fields: int,
         )
     reductions = (3 if need_minmax else 1) if n_fields else 0
     row_bytes = _tile_row_bytes(n_fields)
-    tile = min(n_rows, scatter_chunk_rows(n_fields)) * row_bytes
+    step = min(n_rows, scatter_chunk_rows(n_fields))
+    tile = step * row_bytes
+    sublane_bytes = 4 * 8 * -(-max(n_fields, 1) // 8)
+    slots = fold_cap(step) + 1
+    fold = fold_group(-(-n_rows // step), step) * (
+        4 * step + sublane_bytes * step
+        + reductions * slots * (row_bytes + 2 * _FOLD_BLOCK * sublane_bytes)
+    )
     accumulators = 2 * (n_seg + 1) * (4 + reductions * row_bytes)
-    return row_vectors + reductions * tile + accumulators
+    return row_vectors + reductions * tile + fold + accumulators
 
 
-def _scatter_segment_agg(seg_raw, m, agg_vals, n_seg: int, need_minmax: bool):
-    """(counts, sums, mins, maxs) via scatter ops (CPU/GPU, or large segment
-    counts where O(N*n_seg) matmul work loses to O(N)).
+def fold_cap(rows: int) -> int:
+    """Runs a scatter step of ``rows`` rows folds at most (``_FOLD_SHARE``)."""
+    return max(rows // _FOLD_SHARE, 1)
+
+
+def fold_group(n_chunks: int, chunk: int) -> int:
+    """Steps of ``chunk`` rows the scatter impl takes at once: ``n_chunks``
+    cut evenly into the fewest groups of at most ``_FOLD_GROUP_ROWS`` rows
+    (the loop pads the last group with steps of dump rows)."""
+    groups = -(-n_chunks // max(_FOLD_GROUP_ROWS // chunk, 1))
+    return -(-n_chunks // groups)
+
+
+def _scatter_segment_agg(seg_raw, m, agg_vals, n_seg: int, need_minmax: bool,
+                         cohort_axis: str | None = None):
+    """(counts, sums, mins, maxs, folded) via scatter ops (CPU/GPU, or large
+    segment counts where O(N*n_seg) matmul work loses to O(N)); ``folded`` is
+    the int32 number of steps that scattered one row per run (``_fits``).
 
     The scatters take their updates row-major, ``(rows, F)``, so the value
     columns transpose first, and that tile is what the program holds beside
     the resident columns: 4.3 GB at 2^23 rows, refused by the v5e's compiler
     at 2^25 (17.2 GB). Above ``scatter_chunk_rows`` rows the scatters
     therefore run over row chunks in a ``lax.scan`` INTO carried
-    accumulators: the same updates in the same row order as one scatter over
-    all rows, so counts are exact and sums round as before."""
+    accumulators, ``fold_group`` steps an iteration: a run cut by a chunk
+    boundary is two updates of its segment, so counts are exact and sums
+    round as f32 sums do. Under the cohort's ``vmap``, ``cohort_axis`` names
+    its axis: a step folds where it folds for every member."""
     seg = jnp.where(m, seg_raw, n_seg)  # masked rows land in a dump slot
     n = seg.shape[0]
     n_fields = 0 if agg_vals is None else agg_vals.shape[0]
-    chunk = scatter_chunk_rows(n_fields)
     acc = _scatter_zero(n_seg, n_fields, need_minmax,
                         None if agg_vals is None else agg_vals.dtype)
-    if n <= chunk:
-        acc = _scatter_rows(acc, seg, m, agg_vals, need_minmax)
-    else:
+    folded = jnp.int32(0)
+    if n:  # a selective program's pick may be empty
+        chunk = min(n, scatter_chunk_rows(n_fields))
         n_chunks = -(-n // chunk)
-        pad = n_chunks * chunk - n
-        seg = jnp.pad(seg, (0, pad), constant_values=n_seg)
-        m = jnp.pad(m, (0, pad))
-        if agg_vals is not None:
-            agg_vals = jnp.pad(agg_vals, ((0, 0), (0, pad)))
+        group = fold_group(n_chunks, chunk)
+        n_iter = -(-n_chunks // group)
+        rows = group * chunk
+        pad = n_iter * rows - n
+        if pad:
+            seg = jnp.pad(seg, (0, pad), constant_values=n_seg)
+            m = jnp.pad(m, (0, pad))
+            if agg_vals is not None:
+                agg_vals = jnp.pad(agg_vals, ((0, 0), (0, pad)))
 
-        def step(acc, i):
+        def step(carry, i):
+            acc, folded = carry
             with jax.named_scope("slice"):
-                s = jax.lax.dynamic_slice_in_dim(seg, i * chunk, chunk)
-                mm = jax.lax.dynamic_slice_in_dim(m, i * chunk, chunk)
+                s = jax.lax.dynamic_slice_in_dim(seg, i * rows, rows)
+                mm = jax.lax.dynamic_slice_in_dim(m, i * rows, rows)
                 v = None if agg_vals is None else jax.lax.dynamic_slice_in_dim(
-                    agg_vals, i * chunk, chunk, axis=1
-                )
-            return _scatter_rows(acc, s, mm, v, need_minmax), None
+                    agg_vals, i * rows, rows, axis=1
+                ).reshape(n_fields, group, chunk)
+                s, mm = s.reshape(group, chunk), mm.reshape(group, chunk)
+            with jax.named_scope("runs"):
+                fits = jax.vmap(_fits)(s)
+                if cohort_axis is not None:
+                    fits = jax.lax.pmin(fits.astype(jnp.int32), cohort_axis) > 0
+            acc = _scatter_group(acc, s, mm, v, fits, n_seg, need_minmax)
+            real = i * group + jax.lax.iota(jnp.int32, group) < n_chunks
+            return (acc, folded + (fits & real).sum(dtype=jnp.int32)), None
 
-        acc, _ = jax.lax.scan(step, acc, jnp.arange(n_chunks, dtype=jnp.int32))
+        if n_iter == 1:
+            (acc, folded), _ = step((acc, folded), 0)
+        else:
+            (acc, folded), _ = jax.lax.scan(
+                step, (acc, folded), jnp.arange(n_iter, dtype=jnp.int32)
+            )
     counts = acc[0][:n_seg]
     if agg_vals is None:
-        return counts, None, None, None
+        return counts, None, None, None, folded
     sums = acc[1][:n_seg].T
     if need_minmax:
-        return counts, sums, acc[2][:n_seg].T, acc[3][:n_seg].T
-    return counts, sums, jnp.zeros_like(sums), jnp.zeros_like(sums)
+        return counts, sums, acc[2][:n_seg].T, acc[3][:n_seg].T, folded
+    return counts, sums, jnp.zeros_like(sums), jnp.zeros_like(sums), folded
 
 
 def _scatter_zero(n_seg: int, n_fields: int, need_minmax: bool, dtype):
@@ -364,6 +428,147 @@ def _scatter_rows(acc, seg, m, agg_vals, need_minmax: bool):
     return tuple(out)
 
 
+def _scatter_group(acc, seg, m, agg_vals, fits, n_seg: int, need_minmax: bool):
+    """Steps ``seg``, ``m`` (steps, rows) and values (F, steps, rows) into the
+    accumulators, ``fits`` saying which steps fold. When every one does, one
+    fold and one scatter take all their runs (``_fold_rows``); else each step
+    folds or scatters its rows (``_scatter_rows``) on its own."""
+    def each(acc):
+        def one(acc, j):
+            s, mm = seg[j], m[j]
+            v = None if agg_vals is None else agg_vals[:, j]
+            return jax.lax.cond(
+                fits[j],
+                lambda acc: _fold_rows(
+                    acc, s[None], None if v is None else v[:, None], n_seg,
+                    need_minmax,
+                ),
+                lambda acc: _scatter_rows(acc, s, mm, v, need_minmax),
+                acc,
+            ), None
+
+        if seg.shape[0] == 1:
+            v = None if agg_vals is None else agg_vals[:, 0]
+            return _scatter_rows(acc, seg[0], m[0], v, need_minmax)
+        return jax.lax.scan(
+            one, acc, jnp.arange(seg.shape[0], dtype=jnp.int32)
+        )[0]
+
+    return jax.lax.cond(
+        fits.all(),
+        lambda acc: _fold_rows(acc, seg, agg_vals, n_seg, need_minmax),
+        each, acc,
+    )
+
+
+def _fits(seg):
+    """Whether a step's rows hold at most ``fold_cap`` runs."""
+    changes = (seg[1:] != seg[:-1]).sum(dtype=jnp.int32)
+    return changes < fold_cap(seg.shape[0])  # runs = changes + 1
+
+
+def _fold_rows(acc, seg, agg_vals, n_seg: int, need_minmax: bool):
+    """Scatter one update row per run of steps of ``<= fold_cap`` runs each,
+    ``seg`` (steps, rows), values (F, steps, rows): one scatter for all.
+
+    The rows are cut into blocks of ``_FOLD_BLOCK``; a step that is not a
+    whole number of blocks is padded with dump rows, one run more. A run's
+    count is its length (every row of a run into a live segment is
+    unmasked); its sum, min and max come from ``_run_totals``. Slots past a
+    step's runs hold its last row alone, into the dump slot."""
+    n = seg.shape[1]
+    pad = -n % _FOLD_BLOCK
+    slots = fold_cap(n) + (pad > 0)
+    if pad:
+        seg = jnp.pad(seg, ((0, 0), (0, pad)), constant_values=n_seg)
+        if agg_vals is not None:
+            agg_vals = jnp.pad(agg_vals, ((0, 0), (0, 0), (0, pad)))
+    n += pad
+
+    def runs_of(seg):
+        end = jnp.concatenate([seg[1:] != seg[:-1], jnp.ones((1,), bool)])
+        last = _run_ends(end, slots)
+        live = last < n
+        last = jnp.minimum(last, n - 1)
+        first = jnp.minimum(
+            jnp.concatenate([jnp.zeros((1,), jnp.int32), last[:-1] + 1]), last
+        )
+        return first, last, jnp.where(live, seg[last], n_seg), jnp.where(
+            live, last - first + 1, 0
+        )
+
+    with jax.named_scope("fold_ends"):
+        first, last, ids, counts = jax.vmap(runs_of)(seg)
+        ids = ids.reshape(-1)
+    with jax.named_scope("counts"):
+        out = [acc[0].at[ids].add(counts.reshape(-1))]
+    if agg_vals is None:
+        return tuple(out)
+    with jax.named_scope("fold_totals"):
+        tiles = jax.vmap(
+            lambda v, f, l: _run_totals(v, f, l, need_minmax), in_axes=(1, 0, 0)
+        )(agg_vals, first, last)
+        tiles = [t.reshape(-1, t.shape[-1]) for t in tiles]
+    with jax.named_scope("sums"):
+        out.append(acc[1].at[ids].add(tiles[0]))
+    if need_minmax:
+        with jax.named_scope("mins"):
+            out.append(acc[2].at[ids].min(tiles[1]))
+        with jax.named_scope("maxs"):
+            out.append(acc[3].at[ids].max(tiles[2]))
+    return tuple(out)
+
+
+def _run_ends(end, slots: int):
+    """The last row of each run, in order, ``end`` marking them; past the
+    runs, the row count. No sort and no per-row gather or scatter (a v5e
+    runs either at 7-11 ns a row): a slot finds its block by the blocks'
+    counts of run ends, then its row by the running count of ends along
+    that block's one lane row."""
+    n = end.shape[0]
+    n_blocks = n // _FOLD_BLOCK
+    blocks = end.reshape(n_blocks, _FOLD_BLOCK).astype(jnp.int32)
+    upto = jnp.cumsum(blocks.sum(axis=1))  # ends in blocks 0..b
+    k = jax.lax.iota(jnp.int32, slots)
+    before = upto[None, :] <= k[:, None]  # (slots, blocks): wholly before end k
+    blk = before.sum(axis=1, dtype=jnp.int32)
+    rank = k - jnp.max(jnp.where(before, upto[None, :], 0), axis=1)
+    row = jnp.cumsum(jnp.take(blocks, jnp.minimum(blk, n_blocks - 1), axis=0), axis=1)
+    pos = (row <= rank[:, None]).sum(axis=1, dtype=jnp.int32)
+    return jnp.where(blk < n_blocks, blk * _FOLD_BLOCK + pos, n)
+
+
+def _run_totals(vals, first, last, need_minmax: bool):
+    """Each run's sum (and min, max) of ``vals`` (F, rows), run k being rows
+    ``first[k]..last[k]``, as ``(slots, F)`` tiles. The blocks wholly inside
+    a run count by their block totals; its first and last block's pieces by
+    a masked reduction over those two lane rows. Sums are f32 added in a
+    fixed order (piece, blocks, piece): no difference of prefix sums."""
+    n_fields, n = vals.shape
+    x = vals.reshape(n_fields, n // _FOLD_BLOCK, _FOLD_BLOCK)
+    b_first, b_last = first // _FOLD_BLOCK, last // _FOLD_BLOCK
+    lane = jax.lax.iota(jnp.int32, _FOLD_BLOCK)[None, :]
+    one = (b_first == b_last)[:, None]
+    upto_last = lane <= (last % _FOLD_BLOCK)[:, None]
+    head = (lane >= (first % _FOLD_BLOCK)[:, None]) & (~one | upto_last)
+    tail = ~one & upto_last
+    blk = jax.lax.iota(jnp.int32, x.shape[1])[None, :]
+    inner = (blk > b_first[:, None]) & (blk < b_last[:, None])  # (slots, blocks)
+    head_rows = jnp.take(x, b_first, axis=1)  # (F, slots, block)
+    tail_rows = jnp.take(x, b_last, axis=1)
+    big = jnp.asarray(jnp.inf, vals.dtype)
+    reductions = [(jnp.sum, jnp.add, 0)]
+    if need_minmax:
+        reductions += [(jnp.min, jnp.minimum, big), (jnp.max, jnp.maximum, -big)]
+    tiles = []
+    for reduce, combine, ident in reductions:
+        h = reduce(jnp.where(head[None], head_rows, ident), axis=2)
+        mid = reduce(jnp.where(inner[None], reduce(x, axis=2)[:, None, :], ident), axis=2)
+        t = reduce(jnp.where(tail[None], tail_rows, ident), axis=2)
+        tiles.append(combine(combine(h, mid), t).T)
+    return tiles
+
+
 def scan_agg_body(
     group_codes,
     bucket_ids,
@@ -377,9 +582,12 @@ def scan_agg_body(
     numeric_filters: tuple[tuple[int, int], ...] = (),
     need_minmax: bool = True,
     segment_impl: str,
+    cohort_axis: str | None = None,
 ):
     """Pure kernel body — also the per-shard program inside shard_map
-    (parallel/dist_agg.py wraps it with psum/pmin/pmax collectives)."""
+    (parallel/dist_agg.py wraps it with psum/pmin/pmax collectives).
+    -> (counts, sums, mins, maxs, folded): ``folded`` is the int32 count of
+    scatter steps that scattered one row per run (0 for the other impls)."""
     m = mask
     with jax.named_scope("filter"):
         for i, (field_idx, op_code) in enumerate(numeric_filters):
@@ -407,12 +615,18 @@ def scan_agg_body(
         agg_vals = jnp.stack(values[:n_agg_fields]) if n_agg_fields else None
     else:
         agg_vals = values[:n_agg_fields] if n_agg_fields else None
+    folded = jnp.int32(0)
     with jax.named_scope("segment_" + concrete_impl(segment_impl)):
         if segment_impl == "single":
             counts, sums, mins, maxs = _single_segment_agg(m, agg_vals, need_minmax)
+        elif segment_impl == "mxu":
+            counts, sums, mins, maxs = _mxu_segment_agg(
+                seg_raw, m, agg_vals, n_seg, need_minmax
+            )
         else:
-            impl = _mxu_segment_agg if segment_impl == "mxu" else _scatter_segment_agg
-            counts, sums, mins, maxs = impl(seg_raw, m, agg_vals, n_seg, need_minmax)
+            counts, sums, mins, maxs, folded = _scatter_segment_agg(
+                seg_raw, m, agg_vals, n_seg, need_minmax, cohort_axis
+            )
 
     counts = counts.reshape(n_groups, n_buckets)
     if n_agg_fields:
@@ -426,7 +640,7 @@ def scan_agg_body(
         )
         zero = jnp.zeros((0, n_groups, n_buckets), dtype=vdtype)
         sums = mins = maxs = zero
-    return counts, sums, mins, maxs
+    return counts, sums, mins, maxs, folded
 
 
 _fused_scan_agg = functools.partial(
@@ -459,6 +673,7 @@ def cached_scan_agg_body(
     value_layouts: tuple = (),
     ts_layout: tuple = ("raw",),
     series_layout: tuple = ("raw",),
+    cohort_axis: str | None = None,
 ):
     """The steady-state serving kernel over HBM-resident columns.
 
@@ -510,6 +725,7 @@ def cached_scan_agg_body(
         numeric_filters=numeric_filters,
         need_minmax=need_minmax,
         segment_impl=segment_impl,
+        cohort_axis=cohort_axis,
     )
 
 
@@ -578,6 +794,7 @@ def _packed_body(
     value_layouts: tuple = (),
     ts_layout: tuple = ("raw",),
     series_layout: tuple = ("raw",),
+    cohort_axis: str | None = None,
 ):
     s1 = session.shape[0] // 2
     gos = session[:s1]
@@ -594,7 +811,7 @@ def _packed_body(
             value_layouts, idx=idx,
         )
         value_layouts, ts_layout, series_layout = (), ("raw",), ("raw",)
-    counts, sums, mins, maxs = cached_scan_agg_body(
+    counts, sums, mins, maxs, folded = cached_scan_agg_body(
         series_codes, ts_rel, values, gos, allow, literals, lo, hi, t0, width,
         n_groups=n_groups,
         n_buckets=n_buckets,
@@ -605,13 +822,15 @@ def _packed_body(
         value_layouts=value_layouts,
         ts_layout=ts_layout,
         series_layout=series_layout,
+        cohort_axis=cohort_axis,
     )
     # One INT32 result buffer: the f32 partials travel bitcast beside the
     # counts, never the counts as f32 — small int bit patterns are f32
     # denormals, and the TPU flushes those to zero when it fuses the
-    # concatenate (measured on a v5e: every count came back 0).
+    # concatenate (measured on a v5e: every count came back 0). The folded
+    # steps' count rides beside the counts.
     with jax.named_scope("pack"):
-        parts = [counts.reshape(-1), _f32_bits(sums)]
+        parts = [counts.reshape(-1), folded[None], _f32_bits(sums)]
         if need_minmax:
             parts.extend([_f32_bits(mins), _f32_bits(maxs)])
         return jnp.concatenate(parts)
@@ -699,7 +918,9 @@ def _cohort_body(
     broadcast across the batch — HBM is read by one compiled program
     serving B logical queries, instead of B dispatches each paying its
     own device RTT. Selective row-gather is per-query-variable-length and
-    therefore excluded: cohort members always run the full-scan kernel."""
+    therefore excluded: cohort members always run the full-scan kernel.
+    The scatter impl's steps fold where they fold for every member
+    (``_COHORT_AXIS``)."""
     one = functools.partial(
         _packed_body,
         n_groups=n_groups,
@@ -712,9 +933,11 @@ def _cohort_body(
         value_layouts=value_layouts,
         ts_layout=ts_layout,
         series_layout=series_layout,
+        cohort_axis=_COHORT_AXIS,
     )
     return jax.vmap(
-        lambda s, d: one(series_codes, ts_rel, values, s, d)
+        lambda s, d: one(series_codes, ts_rel, values, s, d),
+        axis_name=_COHORT_AXIS,
     )(sessions, dyns)
 
 
@@ -739,7 +962,8 @@ def unpack_packed_state(packed, spec: "ScanAggSpec") -> "AggState":
     G, B, F = spec.n_groups, spec.n_buckets, spec.n_agg_fields
     gb = G * B
     counts = arr[:gb].reshape(G, B).copy()
-    f32 = arr[gb:].view(np.float32)
+    folded = int(arr[gb])
+    f32 = arr[gb + 1:].view(np.float32)
     sums = f32[: F * gb].astype(np.float64).reshape(F, G, B)
     if spec.need_minmax and F:
         mins = f32[F * gb : 2 * F * gb].astype(np.float64).reshape(F, G, B)
@@ -747,6 +971,7 @@ def unpack_packed_state(packed, spec: "ScanAggSpec") -> "AggState":
     else:
         mins = np.zeros((F, G, B))
         maxs = np.zeros((F, G, B))
+    note_folded_chunks(folded)
     return AggState(counts=counts, sums=sums, mins=mins, maxs=maxs)
 
 
@@ -801,10 +1026,8 @@ def scan_aggregate(
         segment_impl=impl,
     )
     t0 = _time.perf_counter()
-    counts, sums, mins, maxs = timed_dispatch(
-        "fused", lambda: _fused_scan_agg(*args, **kwargs)
-    )
-    state = state_to_host(counts, sums, mins, maxs)
+    out = timed_dispatch("fused", lambda: _fused_scan_agg(*args, **kwargs))
+    state = state_to_host(*out)
     # Per-query compile accounting: a never-seen static shape's first
     # dispatch pays the XLA compile — its wall time is the honest cost a
     # latency cliff needs attributed (ledger jit_* fields + the device
@@ -831,10 +1054,13 @@ def coerce_literals(filter_literals: Sequence[float]):
     return jnp.asarray(np.asarray(filter_literals, dtype=np.float32))
 
 
-def state_to_host(counts, sums, mins, maxs) -> AggState:
-    # One device_get over the pytree = one host<->device round trip; four
-    # separate np.asarray fetches cost four round trips to the device.
-    counts, sums, mins, maxs = jax.device_get((counts, sums, mins, maxs))
+def state_to_host(counts, sums, mins, maxs, folded) -> AggState:
+    # One device_get over the pytree = one host<->device round trip; five
+    # separate np.asarray fetches cost five round trips to the device.
+    counts, sums, mins, maxs, folded = jax.device_get(
+        (counts, sums, mins, maxs, folded)
+    )
+    note_folded_chunks(int(folded))
     return AggState(
         counts=np.asarray(counts),
         sums=np.asarray(sums, dtype=np.float64),

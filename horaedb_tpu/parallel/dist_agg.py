@@ -58,21 +58,26 @@ def _step_cache_max() -> int:
 def _combine(state, need_minmax: bool):
     """The aggregation monoid as mesh collectives (final aggregate). Without
     ``need_minmax`` the body's mins and maxs are zeros on every device and
-    stay as they are."""
-    counts, sums, mins, maxs = state
+    stay as they are. The count of folded scatter steps rides the counts'
+    all-reduce, one int32 more."""
+    counts, sums, mins, maxs, folded = state
     with jax.named_scope("dist_combine"):
-        counts = jax.lax.psum(counts, SHARD_AXIS)
+        both = jax.lax.psum(
+            jnp.concatenate([counts.reshape(-1), folded[None]]), SHARD_AXIS
+        )
+        counts, folded = both[:-1].reshape(counts.shape), both[-1]
         sums = jax.lax.psum(sums, SHARD_AXIS)
         if need_minmax:
             mins = jax.lax.pmin(mins, SHARD_AXIS)
             maxs = jax.lax.pmax(maxs, SHARD_AXIS)
-    return counts, sums, mins, maxs
+    return counts, sums, mins, maxs, folded
 
 
 def combine_bytes(spec: ScanAggSpec) -> int:
     """Bytes each device hands ``_combine``'s collectives in one dispatch of
     ``spec``, from the static shapes: int32 counts per segment and an f32
-    per segment and field for the sums (and for mins and maxs, if wanted)."""
+    per segment and field for the sums (and for mins and maxs, if wanted);
+    not the one int32 of folded scatter steps beside the counts."""
     n_seg = spec.n_groups * spec.n_buckets
     reductions = 3 if spec.need_minmax else 1
     return 4 * n_seg * (1 + reductions * spec.n_agg_fields)
@@ -129,7 +134,7 @@ def _build_step(mesh: Mesh, spec: ScanAggSpec, tag: str, body, in_specs) -> Call
 
         sharded = shard_map(
             per_shard, mesh=mesh, in_specs=in_specs,
-            out_specs=(P(), P(), P(), P()),
+            out_specs=(P(), P(), P(), P(), P()),
             # the scatter's row chunks run in a lax.scan whose carried
             # accumulators start replicated and come back varying over the
             # mesh axis: no replication rule; _combine replicates every
@@ -225,7 +230,7 @@ def dist_scan_aggregate(
     from ..utils.querystats import note_kernel_dispatch
 
     t0 = _time.perf_counter()
-    counts, sums, mins, maxs = timed_dispatch(
+    out = timed_dispatch(
         "fused_dist",
         lambda: step(
             jnp.asarray(group_codes),
@@ -236,7 +241,7 @@ def dist_scan_aggregate(
         ),
     )
     note_dist_combine(combine_bytes(spec))
-    state = state_to_host(counts, sums, mins, maxs)
+    state = state_to_host(*out)
     # Compile accounting for the sharded fused path — a first-sighting
     # shard_map compile is a MULTI-SECOND stall on real chips and must
     # journal/mark compile_hit like every other dispatch point (the

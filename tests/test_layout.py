@@ -439,6 +439,21 @@ def _stablehlo_gathers(lowered):
     return [math.prod(int(d) for d in s.split("x") if d) for s in shapes]
 
 
+def _fold_gathers(impl, rows, n_fields):
+    """What the scatter impl's fold gathers (``_fold_rows``), per run slot
+    (``fold_cap``, one more for a step padded to whole blocks): a segment
+    id, a lane row of run-end flags (``_run_ends``), and each field's lane
+    row of the run's first and of its last block (``_run_totals``: one
+    ``jnp.take``, lowered once for both)."""
+    from horaedb_tpu.ops.scan_agg import _FOLD_BLOCK, fold_cap
+
+    if impl != "scatter":
+        return []
+    slots = fold_cap(rows) + (rows % _FOLD_BLOCK > 0)
+    lane_rows = slots * _FOLD_BLOCK
+    return [slots, lane_rows, n_fields * lane_rows]
+
+
 class TestFullScanNoRowGather:
     """PR 26: a full scan reads its packed streams by their static
     structure and the per-series tables through the FOR blocks. On a v5e
@@ -480,8 +495,12 @@ class TestFullScanNoRowGather:
     def test_full_scan_lowers_without_row_sized_gather(self, impl):
         gathers = _stablehlo_gathers(self._lower(impl, selective=False))
         # the allow-list (and the group map, where groups exist) through
-        # two candidate series a block; nothing of N elements
-        assert gathers == [self.N // FOR_BLOCK * 2] * (1 if impl == "single" else 2)
+        # two candidate series a block, and the fold's lane rows; no gather
+        # of one element a row
+        assert sorted(gathers) == sorted(
+            [self.N // FOR_BLOCK * 2] * (1 if impl == "single" else 2)
+            + _fold_gathers(impl, self.N, 2)
+        )
 
     @pytest.mark.parametrize("impl", ["single", "scatter", "mxu"])
     def test_full_scan_of_packed_streams_gathers_only_dictionaries(self, impl):
@@ -492,7 +511,8 @@ class TestFullScanNoRowGather:
             self._lower(impl, selective=False, packed_streams=True)
         )
         assert sorted(g for g in gathers if g >= self.N) == [self.N] * 2
-        assert len(gathers) == 2 + (1 if impl == "single" else 2)
+        fold = _fold_gathers(impl, self.N, 1)
+        assert len(gathers) == 2 + (1 if impl == "single" else 2) + len(fold)
 
     @pytest.mark.parametrize("packed_streams", [False, True], ids=["raw", "packed"])
     @pytest.mark.parametrize("impl", ["single", "scatter", "mxu"])
@@ -502,6 +522,8 @@ class TestFullScanNoRowGather:
         gathers = _stablehlo_gathers(
             self._lower(impl, selective=True, packed_streams=packed_streams)
         )
+        for g in _fold_gathers(impl, self.M, 1 if packed_streams else 2):
+            gathers.remove(g)
         assert set(gathers) == {self.M}
         tables = 1 if impl == "single" else 2  # allow-list (+ group map)
         # series words x2 + base, then ts and two values: raw 1 gather

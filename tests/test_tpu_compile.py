@@ -365,9 +365,12 @@ def test_named_program_lowers_under_its_name(impl, selective):
     assert f"module @jit_{name} " in text
     scopes = ["decode_series", "decode_ts", "decode_values", "filter",
               "segment_" + impl, "pack"]
-    if impl == "scatter":  # its stages, one scatter each
-        scopes += [f"segment_scatter/{stage}" for stage in
-                   ("counts", "sums", "mins", "maxs")]
+    if impl == "scatter":  # its stages, one scatter each, in both branches
+        scopes += ["segment_scatter/runs"]
+        scopes += [f"segment_scatter/cond/branch_{b}_fun/{stage}"
+                   for b in (0, 1) for stage in ("counts", "sums", "mins", "maxs")]
+        scopes += [f"segment_scatter/cond/branch_1_fun/{stage}"  # the fold
+                   for stage in ("fold_ends", "fold_totals")]
     for scope in scopes:
         assert f"jit({name})/{scope}/" in text, scope
 
@@ -497,7 +500,7 @@ def test_chunked_scatter_equals_numpy_group_by(monkeypatch, cut, need_minmax):
         np.asarray(a) for a in scan_agg._scatter_segment_agg(
             jnp.asarray(seg), jnp.asarray(mask), jnp.asarray(vals), n_seg,
             need_minmax,
-        )
+        )[:4]
     )
     np.testing.assert_array_equal(counts, want[0])
     assert counts.dtype == np.int32 and counts[11] == 0 and counts[37:].sum() == 0
@@ -517,7 +520,7 @@ def test_chunked_scatter_equals_numpy_group_by(monkeypatch, cut, need_minmax):
         jnp.asarray(seg), jnp.asarray(mask), None, n_seg, need_minmax
     )
     np.testing.assert_array_equal(np.asarray(only[0]), want[0])
-    assert only[1:] == (None, None, None)
+    assert only[1:4] == (None, None, None)
 
 
 def test_chunked_scatter_through_the_packed_program(monkeypatch):
