@@ -143,6 +143,13 @@ _M_FOLDED_CHUNKS = REGISTRY.counter(
     "row chunks whose segment scatter ran one update row per run of equal "
     "segment ids, summed over chips",
 )
+# Dispatches whose program read the per-series tables through the series
+# codes' 128-row blocks (ops/encoding.reads_by_block), not one row at a time.
+_M_BLOCK_LOOKUPS = REGISTRY.counter(
+    "horaedb_scan_block_lookups_total",
+    "full-scan dispatches that looked the per-series tables up through each "
+    "128-row block's few candidate series instead of per row",
+)
 _M_RESIDENT = {
     c: REGISTRY.gauge(
         "horaedb_device_resident_bytes",
@@ -315,6 +322,11 @@ def note_folded_chunks(n: int) -> None:
     """A fetched aggregate's scatter folded ``n`` of its row chunks."""
     if n:
         _M_FOLDED_CHUNKS.inc(n)
+
+
+def note_block_lookups() -> None:
+    """A dispatch's program read the per-series tables by block."""
+    _M_BLOCK_LOOKUPS.inc()
 
 
 def note_compile_cache_hit(kind: str) -> None:

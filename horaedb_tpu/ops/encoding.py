@@ -438,11 +438,15 @@ def _delta_decode(words, base, width: int, n_rows: int, idx):
 class BlockedSeries(NamedTuple):
     """A full scan's series codes left as their FOR parts: the code of row
     ``r`` is ``base[r >> 7] + offsets[r]``, and nothing adds them up —
-    ``lookup_series`` reads per-series tables through the blocks."""
+    ``lookup_series`` reads per-series tables through the blocks.
+    ``pad_past_width``: an offset of ``2**width`` or more is a pad row in a
+    block it shares with valid rows (``block_series``), and reads the pad
+    series' entry."""
 
-    offsets: jax.Array  # uint32[N], each < 2**width
+    offsets: jax.Array  # uint32[N], each < 2**width unless pad_past_width
     base: jax.Array  # int32[N / FOR_BLOCK]
     width: int
+    pad_past_width: bool = False
 
 
 # The widest series layout that ``lookup_series`` reads per block. A block
@@ -453,6 +457,46 @@ class BlockedSeries(NamedTuple):
 # over 2^23 rows is ~0.1 ms on a v5e); gathered elements cost 7-9 ns each.
 # Past 1/8 the gain is small and the select chain long: the row gather stays.
 BLOCK_LOOKUP_MAX_WIDTH = 4
+
+
+def reads_by_block(series_layout: tuple) -> bool:
+    """Whether a full scan over ``series_layout`` reads the per-series
+    tables through the 128-row blocks (``lookup_series`` of a
+    ``BlockedSeries``) rather than one row at a time."""
+    kind = series_layout[0]
+    return kind == "blocked" or (
+        kind == "delta" and series_layout[1] <= BLOCK_LOOKUP_MAX_WIDTH
+    )
+
+
+def series_block_width(pieces) -> Optional[int]:
+    """The width raw series codes are read per block with (the ``("blocked",
+    w)`` layout), or None where a block spans too many series for it.
+    ``pieces``: runs of valid codes, each laid from the start of a 128-row
+    block (a shard's valid rows); only valid codes count, since
+    ``block_series`` sends a pad row past the width to the pad series."""
+    span = 0
+    for codes in pieces:
+        if len(codes):
+            starts = np.arange(0, len(codes), FOR_BLOCK)
+            span = max(span, int((np.maximum.reduceat(codes, starts)
+                                  - np.minimum.reduceat(codes, starts)).max()))
+    width = _bit_width(span)
+    return width if width <= BLOCK_LOOKUP_MAX_WIDTH else None
+
+
+def block_series(codes, width: int) -> BlockedSeries:
+    """Raw int32 codes of a full scan as a ``BlockedSeries``: each block's
+    base is its least code and an offset is a row's distance from it — one
+    reduction and one subtraction, no gather. Every valid row lies within
+    ``2**width`` of its block's least code (``series_block_width``); a row
+    past it is a pad row (the pad code is the largest) in the block it
+    shares with a shard's last valid rows."""
+    with jax.named_scope("decode_series"):
+        blocks = codes.reshape(-1, FOR_BLOCK)
+        base = blocks.min(axis=1)
+        offsets = (blocks - base[:, None]).astype(jnp.uint32).reshape(-1)
+    return BlockedSeries(offsets, base, width, pad_past_width=True)
 
 
 def lookup_series(table, series):
@@ -470,21 +514,27 @@ def lookup_series(table, series):
     out = picked[:, :1]
     for k in range(1, 1 << series.width):
         out = jnp.where(offsets == k, picked[:, k : k + 1], out)
+    if series.pad_past_width:
+        out = jnp.where(offsets >= (1 << series.width), table[-1], out)
     return out.reshape(-1)  # width >= 1: the selects broadcast it over the block
 
 
 def decode_series(parts, layout, n_rows: int, idx=None, blocked: bool = False):
     """int32 series codes under ``layout`` — all rows (idx=None) or a gather.
 
-    ``parts`` is the device part tuple: ("raw",) -> (codes,);
-    ("delta", w) -> (words, base). ``blocked`` lets a full scan of a
-    narrow delta layout come back as a ``BlockedSeries`` (for callers that
-    only look tables up by series: ``lookup_series``).
+    ``parts`` is the device part tuple: ("raw",) and ("blocked", w) ->
+    (codes,); ("delta", w) -> (words, base). ``blocked`` lets a full scan of
+    a layout that ``reads_by_block`` come back as a ``BlockedSeries`` (for
+    callers that only look tables up by series: ``lookup_series``).
+    ``("blocked", w)`` is raw codes whose 128-row blocks each span under
+    ``2**w`` series (a sharded entry's, ``series_block_width``).
     """
-    if layout[0] == "raw":
-        return parts[0] if idx is None else parts[0][idx]
+    full_by_block = blocked and idx is None and reads_by_block(layout)
+    if layout[0] in ("raw", "blocked"):
+        codes = parts[0] if idx is None else parts[0][idx]
+        return block_series(codes, layout[1]) if full_by_block else codes
     words, base = parts
-    if blocked and idx is None and layout[1] <= BLOCK_LOOKUP_MAX_WIDTH:
+    if full_by_block:
         return BlockedSeries(unpack_bits_all(words, layout[1], n_rows), base, layout[1])
     return _delta_decode(words, base, layout[1], n_rows, idx)
 
@@ -539,24 +589,25 @@ def decode_layouts(
     rows, not N); without, every stream unpacks by its static structure
     and no row-sized gather is issued for it. Raw inputs pass through
     untouched — legacy callers (dist paths, direct tests) never pay for
-    the generality. Encoded values come back as a LIST of per-field rows;
+    the generality; ``("blocked", w)`` series codes are raw too, and only
+    a full scan's ``blocked_series`` reads them by block. Encoded values
+    come back as a LIST of per-field rows;
     the kernels stack only what they aggregate. ``blocked_series``: the
     caller reads the series codes through ``lookup_series`` alone, so a
     full scan may hand them over as a ``BlockedSeries``.
     """
     if (
-        series_layout[0] == "raw"
+        series_layout[0] in ("raw", "blocked")
         and ts_layout[0] == "raw"
         and not any(l[0] not in ("raw", "bf16") for l in value_layouts)
         and not isinstance(values, tuple)
     ):
-        if idx is None:
-            return _as_parts(series_codes)[0], _as_parts(ts_rel)[0], values
-        return (
-            _as_parts(series_codes)[0][idx],
-            _as_parts(ts_rel)[0][idx],
-            values[:, idx],
+        sc = decode_series(
+            _as_parts(series_codes), series_layout, 0, idx, blocked_series
         )
+        if idx is None:
+            return sc, _as_parts(ts_rel)[0], values
+        return sc, _as_parts(ts_rel)[0][idx], values[:, idx]
     sc_parts = _as_parts(series_codes)
     ts_parts = _as_parts(ts_rel)
     n_rows = layout_rows(sc_parts, series_layout)

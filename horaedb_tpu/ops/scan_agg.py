@@ -698,7 +698,12 @@ def cached_scan_agg_body(
 
     Pure body: also the per-shard program when the cache is sharded over a
     mesh (parallel/dist_agg.make_cached_dist_scan_agg wraps it with
-    psum/pmin/pmax collectives — that path always runs the raw layout).
+    psum/pmin/pmax collectives). That path keeps its streams raw; its
+    series codes come as ``("blocked", w)`` where every 128-row block of a
+    shard's valid rows spans under ``2**w`` series, and the tables are then
+    read through the blocks as here, each block's base and offsets computed
+    from the raw codes in the program (``encoding.block_series``); else
+    per row.
     """
     series, ts_rel, values = _decode_layouts(
         series_codes, ts_rel, values, series_layout, ts_layout, value_layouts,

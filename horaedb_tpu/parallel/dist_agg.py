@@ -109,10 +109,13 @@ def cached_step(cache_key, build) -> Callable:
     return step
 
 
-def _build_step(mesh: Mesh, spec: ScanAggSpec, tag: str, body, in_specs) -> Callable:
+def _build_step(mesh: Mesh, spec: ScanAggSpec, tag: str, body, in_specs,
+                **layouts) -> Callable:
     """shard_map(body)+combine, jitted under ``dist_program_name`` and cached
-    per (mesh, spec, tag). ``spec.segment_impl`` is the chooser's concrete
-    name: it keys the step cache and the jit trace."""
+    per (mesh, spec, tag, layouts). ``spec.segment_impl`` is the chooser's
+    concrete name: it keys the step cache and the jit trace. ``layouts``:
+    static layout descriptors other than ``body``'s defaults, handed to it
+    as they are."""
     name = dist_program_name(tag, spec.segment_impl)
 
     def build():
@@ -128,6 +131,7 @@ def _build_step(mesh: Mesh, spec: ScanAggSpec, tag: str, body, in_specs) -> Call
                     numeric_filters=static_filters,
                     need_minmax=spec.need_minmax,
                     segment_impl=spec.segment_impl,
+                    **layouts,
                 ),
                 spec.need_minmax,
             )
@@ -148,7 +152,7 @@ def _build_step(mesh: Mesh, spec: ScanAggSpec, tag: str, body, in_specs) -> Call
         step.__name__ = step.__qualname__ = name
         return jax.jit(step)
 
-    return cached_step((mesh, spec, tag), build)
+    return cached_step((mesh, spec, tag, *sorted(layouts.items())), build)
 
 
 def make_dist_scan_agg(mesh: Mesh, spec: ScanAggSpec) -> Callable:
@@ -173,7 +177,9 @@ def make_dist_scan_agg(mesh: Mesh, spec: ScanAggSpec) -> Callable:
     )
 
 
-def make_cached_dist_scan_agg(mesh: Mesh, spec: ScanAggSpec) -> Callable:
+def make_cached_dist_scan_agg(
+    mesh: Mesh, spec: ScanAggSpec, block_width: int | None = None
+) -> Callable:
     """Sharded version of the HBM-resident cached kernel.
 
     The cache's big per-row arrays (series codes, relative timestamps,
@@ -182,7 +188,15 @@ def make_cached_dist_scan_agg(mesh: Mesh, spec: ScanAggSpec) -> Callable:
     list, literals, time scalars) are replicated. Each device aggregates
     its row shard, then the monoid combines via collectives — the default
     serving path on a multi-chip mesh, not a demo path.
+
+    ``block_width``: the entry's ``series_block_width``. With it each
+    device reads the series→group map and the allow list through its
+    codes' 128-row blocks (``encoding.block_series``); None gathers both
+    tables per row.
     """
+    layouts = {} if block_width is None else {
+        "series_layout": ("blocked", block_width)
+    }
     return _build_step(
         mesh,
         spec,
@@ -197,6 +211,7 @@ def make_cached_dist_scan_agg(mesh: Mesh, spec: ScanAggSpec) -> Callable:
             P(None),  # filter literals
             P(), P(), P(), P(),  # time-range / bucket scalars
         ),
+        **layouts,
     )
 
 

@@ -1318,7 +1318,12 @@ class Executor:
         row_idx = prep.row_idx
         import time as _time
 
-        from ..obs.device import cost_analysis, refusal_of, timed_dispatch
+        from ..obs.device import (
+            cost_analysis,
+            note_block_lookups,
+            refusal_of,
+            timed_dispatch,
+        )
 
         t_kernel = _time.perf_counter()
         n_seg = spec.n_groups * spec.n_buckets
@@ -1351,8 +1356,10 @@ class Executor:
                 )
             valid = entry.shards.valid_rows
 
+            block_width = entry.series_block_width
+
             def launch(spec):
-                step = make_cached_dist_scan_agg(entry.mesh, spec)
+                step = make_cached_dist_scan_agg(entry.mesh, spec, block_width)
                 out = timed_dispatch(
                     kind, lambda: step(*args), impl=spec.segment_impl,
                     program=dist_program_name("cached", spec.segment_impl),
@@ -1364,6 +1371,8 @@ class Executor:
                     ),
                 )
                 note_dist_combine(combine_bytes(spec))
+                if block_width is not None:
+                    note_block_lookups()
                 with _span("fetch", bytes=sum(int(o.nbytes) for o in out)):
                     return state_to_host(*out)
 
@@ -1379,9 +1388,11 @@ class Executor:
                 packed_program_name,
                 unpack_packed_state,
             )
+            from ..ops.encoding import reads_by_block
 
             kind = "cached_packed"
             selective = row_idx is not None
+            by_block = not selective and reads_by_block(entry.series_layout)
             key_head = ("cached-packed", selective)
             with _span("upload", selective=selective):
                 values_dev = entry.values_for(value_names)
@@ -1422,6 +1433,8 @@ class Executor:
                         spec.n_agg_fields, spec.need_minmax,
                     ),
                 )
+                if by_block:
+                    note_block_lookups()
                 with _span("fetch", bytes=int(packed.nbytes)):
                     return unpack_packed_state(packed, spec)
 
@@ -1547,7 +1560,8 @@ class Executor:
                 )
             values_dev = entry.values_for(p0.value_names)
             sessions_dev, dyns_dev = jnp.asarray(sessions), jnp.asarray(dyns)
-        from ..obs.device import timed_dispatch
+        from ..obs.device import note_block_lookups, timed_dispatch
+        from ..ops.encoding import reads_by_block
 
         t_kernel = _time.perf_counter()
         packed = timed_dispatch(
@@ -1570,6 +1584,8 @@ class Executor:
             )),
             impl=spec.segment_impl,
         )
+        if reads_by_block(entry.series_layout):
+            note_block_lookups()
         with _span("fetch", bytes=int(packed.nbytes)):
             rows = np.asarray(jax.device_get(packed))
         elapsed = _time.perf_counter() - t_kernel

@@ -38,7 +38,7 @@ import numpy as np
 
 from ..common_types.dict_column import as_values
 from ..common_types.row_group import RowGroup
-from ..ops.encoding import pad_to_bucket, shape_bucket
+from ..ops.encoding import pad_to_bucket, series_block_width, shape_bucket
 from ..table_engine.predicate import Predicate
 from ..utils.metrics import REGISTRY
 from ..utils.tracectx import span
@@ -179,6 +179,10 @@ class CachedTableScan:
     # blocks of the valid rows, each padded at its own tail. None = single
     # device, where device row i is host row i
     shards: object = None
+    # a sharded entry's raw series codes span under 2**w series in every
+    # 128-row block: the sharded aggregate reads its per-series tables
+    # through the blocks (ops/encoding.series_block_width). None: per row
+    series_block_width: Optional[int] = None
     # owning table name — keys the cache's per-column usage map (dtype
     # auto-tuning) from extend paths that only hold the entry.
     table_name: str = ""
@@ -780,6 +784,7 @@ class ScanCache:
                     sp.set(
                         mesh_devices=entry.shards.n_shards,
                         shard_rows=entry.shards.valid_rows.tolist(),
+                        series_block_width=entry.series_block_width,
                     )
         if entry is None:
             return None, False, None
@@ -879,7 +884,7 @@ class ScanCache:
         from ..parallel.mesh import ShardLayout, dist_min_rows, serving_mesh
 
         mesh = serving_mesh() if n >= dist_min_rows() else None
-        shards = None
+        shards = block_width = None
         if mesh is not None:
             # Every device gets its share of the VALID rows (a power-of-two
             # bucket cut into equal blocks would leave the last devices
@@ -887,6 +892,13 @@ class ScanCache:
             # which every kernel masks.
             shards = ShardLayout.of(n, int(mesh.devices.size))
             codes = shards.place(inverse.astype(np.int32), fill=n_series)
+            # The codes stay raw (the sharded raw reads scan them as they
+            # are); the aggregate reads its tables through each 128-row
+            # block's few series where every block of valid rows has few
+            # (a shard's length is whole blocks: shard_bucket).
+            block_width = series_block_width(
+                inverse[a:b] for a, b in zip(shards.starts[:-1], shards.starts[1:])
+            )
             ts_rel = shards.place(
                 (rows.timestamps - min_ts).astype(np.int32), fill=-1
             )
@@ -919,7 +931,8 @@ class ScanCache:
                 fill=np.int32(-1),
             )
             # Compressed layouts (ISSUE 19) — single-device entries only
-            # (the shard_map kernels scan raw streams). Both codecs are
+            # (a sharded entry keeps raw streams, its series codes read by
+            # block where series_block_width allows). Both codecs are
             # lossless and roundtrip-verified; any rejection falls back
             # to the dense array, bit-identical to the pre-layout path.
             series_layout = ts_layout = ("raw",)
@@ -1011,6 +1024,7 @@ class ScanCache:
             value_cols_dev={},
             mesh=mesh,
             shards=shards,
+            series_block_width=block_width,
             table_name=table_name,
             series_tsids=uniq,
             series_offsets=offsets,

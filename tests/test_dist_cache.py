@@ -15,6 +15,7 @@ from jax.sharding import Mesh
 import horaedb_tpu
 from horaedb_tpu.common_types import RowGroup
 from horaedb_tpu.common_types.schema import compute_tsid
+from horaedb_tpu.ops.encoding import FOR_BLOCK
 from horaedb_tpu.parallel import mesh as mesh_mod
 from horaedb_tpu.parallel.mesh import ShardLayout, shard_bucket
 from horaedb_tpu.utils.metrics import REGISTRY
@@ -298,13 +299,13 @@ class TestRefusedOnTheMesh:
         refuse: set = set()
         calls: list = []
 
-        def make(mesh, spec):
+        def make(mesh, spec, *layout):
             calls.append(spec.segment_impl)
             if spec.segment_impl in refuse:
                 def step(*args):
                     raise jax.errors.JaxRuntimeError(self.REFUSAL)
                 return step
-            return real(mesh, spec)
+            return real(mesh, spec, *layout)
 
         monkeypatch.setattr(dist_agg, "make_cached_dist_scan_agg", make)
         conn = horaedb_tpu.connect(None)
@@ -434,6 +435,151 @@ def test_the_mesh_arms_spans_and_counters(monkeypatch):
         assert combined() - before == 4 * 32 * 3
     finally:
         proxy.close()
+        conn.close()
+
+
+# ---- the per-series tables read through 128-row blocks ----------------------
+
+BLOCK_LOOKUPS = "horaedb_scan_block_lookups_total"
+WHERE = {
+    "all": ("", None),
+    "tag": ("WHERE host IN ('h003', 'h011', 'h017')",
+            lambda r: np.isin(r["host"], ["h003", "h011", "h017"])),
+    "time": ("WHERE ts >= 1800000 AND ts < 7200000",
+             lambda r: (r["ts"] >= 1_800_000) & (r["ts"] < 7_200_000)),
+    "tag-and-time": ("WHERE host IN ('h003', 'h011', 'h017') AND ts >= 1800000 "
+                     "AND ts < 7200000",
+                     lambda r: np.isin(r["host"], ["h003", "h011", "h017"])
+                     & (r["ts"] >= 1_800_000) & (r["ts"] < 7_200_000)),
+}
+
+
+def block_lookups() -> float:
+    return float([ln for ln in REGISTRY.expose().splitlines()
+                  if ln.startswith(BLOCK_LOOKUPS + " ")][0].rpartition(" ")[2])
+
+
+def by_row(out) -> list:
+    return sorted(out.to_pylist(), key=lambda r: (r["host"], r["hour"]))
+
+
+@pytest.fixture(scope="class")
+def by_block():
+    """-> (conn, rows): 20 series of about 1000 rows on four devices, 5001 or
+    5000 valid rows a device (no shard is whole 128-row blocks), the entry
+    built. Class-scoped: its mesh and threshold end with the class."""
+    with pytest.MonkeyPatch.context() as mp:
+        use_mesh(mp, 4)
+        mp.setenv("HORAEDB_DIST_MIN_ROWS", "1")
+        conn = horaedb_tpu.connect(None)
+        conn.execute(DDL)
+        rows = make_rows(20_003, seed=38, hosts=20)
+        write(conn, rows)
+        conn.flush_all()
+        for _ in range(2):  # the candidate, the build
+            conn.execute(sql())
+        yield conn, rows
+        conn.close()
+
+
+class TestTablesReadByBlock:
+    """A sharded entry whose 128-row blocks span few series reads the allow
+    list and the series -> group map through each block's candidates
+    (``series_block_width``), not one row at a time: the same rows reach the
+    same segments, so the answers are the per-row program's bit for bit."""
+
+    @staticmethod
+    def served(conn, statement: str):
+        out = conn.execute(statement)
+        ex = conn.interpreters.executor
+        assert ex.last_path == "device-dist", ex.last_metrics
+        return out, ex.scan_cache._entries["t"]
+
+    @pytest.mark.parametrize("where", sorted(WHERE))
+    def test_by_block_is_the_per_row_answer_bit_for_bit(self, by_block, where,
+                                                        monkeypatch):
+        conn, rows = by_block
+        clause, keep = WHERE[where]
+        statement = sql(minmax=True, where=clause)
+        out, entry = self.served(conn, statement)
+        assert entry.series_block_width == 1
+        hold(out, reference(rows, keep), minmax=True)
+        monkeypatch.setattr(entry, "series_block_width", None)  # per row
+        per_row, _ = self.served(conn, statement)
+        assert by_row(per_row) == by_row(out)
+
+    def test_pad_rows_count_nowhere(self, by_block):
+        """Each device's last block holds its last valid rows and pad rows
+        (the pad series' code, the largest); on the first devices that code
+        lies past the width from the block's least code and is read as the
+        pad series all the same."""
+        conn, rows = by_block
+        out, entry = self.served(conn, sql(minmax=True))
+        valid, n_len = entry.shards.valid_rows, entry.shards.shard_len
+        assert valid.tolist() == [5001, 5001, 5001, 5000]
+        assert all(v % FOR_BLOCK for v in valid)
+        codes = np.asarray(entry.series_codes_dev).reshape(4, n_len)
+        past = [
+            entry.n_series - int(c[v // FOR_BLOCK * FOR_BLOCK])
+            >= 1 << entry.series_block_width
+            for c, v in zip(codes, valid)
+        ]
+        assert any(past) and not all(past), past
+        hold(out, reference(rows), minmax=True)
+        assert sum(r["c"] for r in out.to_pylist()) == len(rows["ts"])
+
+    def test_each_dispatch_by_block_counts_once(self, by_block):
+        conn, _ = by_block
+        before = block_lookups()
+        for _ in range(3):
+            self.served(conn, sql())
+        assert block_lookups() - before == 3
+
+    def test_one_row_series_fall_back_to_the_row_lookup(self, monkeypatch):
+        """A block of one-row series spans 128 of them, past
+        ``BLOCK_LOOKUP_MAX_WIDTH``: the entry keeps the per-row program,
+        answers exactly and counts no block lookup."""
+        use_mesh(monkeypatch, 4)
+        monkeypatch.setenv("HORAEDB_DIST_MIN_ROWS", "1")
+        conn = horaedb_tpu.connect(None)
+        try:
+            conn.execute(DDL)
+            rows = make_rows(6000, seed=39, hosts=6000)
+            write(conn, rows)
+            conn.flush_all()
+            for _ in range(2):
+                conn.execute(sql())
+            before = block_lookups()
+            out, entry = self.served(conn, sql(minmax=True))
+            assert entry.series_block_width is None
+            assert block_lookups() == before
+            hold(out, reference(rows), minmax=True)
+        finally:
+            conn.close()
+
+
+def test_the_one_device_full_scan_counts_its_block_lookups(monkeypatch):
+    """On one device the full scan over ``("delta", w <= 4)`` series codes
+    reads the tables by block and counts; the ``_sel`` program gathers its
+    picked rows and does not."""
+    monkeypatch.setenv("HORAEDB_ADAPTIVE_PATH", "0")
+    conn = horaedb_tpu.connect(None)
+    try:
+        conn.execute(DDL)
+        write(conn, make_rows(5000, seed=40, hosts=13))
+        conn.flush_all()
+        for _ in range(2):
+            conn.execute(sql())
+        ex = conn.interpreters.executor
+        assert ex.scan_cache._entries["t"].series_layout == ("delta", 1)
+        before = block_lookups()
+        conn.execute(sql())
+        assert ex.last_path == "device-cached" and "cache_rows" not in ex.last_metrics
+        assert block_lookups() == before + 1
+        conn.execute(sql(where="WHERE host = 'h003'"))
+        assert ex.last_path == "device-cached" and "cache_rows" in ex.last_metrics
+        assert block_lookups() == before + 1
+    finally:
         conn.close()
 
 

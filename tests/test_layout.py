@@ -22,6 +22,7 @@ from horaedb_tpu.ops.encoding import (
     dict_encode,
     lookup_series,
     pack_bits,
+    series_block_width,
     unpack_bits,
     unpack_bits_all,
     unpack_bits_host,
@@ -566,6 +567,46 @@ class TestFullScanNoRowGather:
         assert np.array_equal(np.asarray(series), codes)
         table = jnp.arange(int(codes.max()) + 1, dtype=jnp.int32) * 3
         assert np.array_equal(np.asarray(lookup_series(table, series)), codes * 3)
+
+
+    @pytest.mark.parametrize("width,run", [(1, 300), (3, 19), (4, 9)])
+    def test_raw_codes_read_by_block(self, width, run):
+        """A sharded entry's raw codes under ``("blocked", w)``: each block's
+        base and offsets come from the codes in the program; pad rows past
+        the width of the block they share with the last valid rows read the
+        pad series' entry, and a gather of picked rows stays raw."""
+        import jax.numpy as jnp
+
+        n, n_valid = 4096, 29 * FOR_BLOCK + 87  # block 29 straddles the pad
+        rng = np.random.default_rng(width)
+        codes = _sorted_codes(n, run)
+        n_series = int(codes[n_valid - 1]) + 40  # past the width from block 29
+        codes[n_valid:] = n_series
+        assert series_block_width([codes[:n_valid]]) == width
+        parts = (jnp.asarray(codes),)
+        series = decode_series(parts, ("blocked", width), n, blocked=True)
+        assert isinstance(series, BlockedSeries) and series.pad_past_width
+        for table in (
+            rng.integers(0, 1 << 20, n_series + 1).astype(np.int32),
+            np.append(rng.random(n_series) > 0.5, False),
+        ):
+            got = lookup_series(jnp.asarray(table), series)
+            assert np.array_equal(np.asarray(got), table[codes])
+        idx = jnp.asarray(np.arange(n_valid - 5, n_valid + 5, dtype=np.int32))
+        picked = decode_series(parts, ("blocked", width), n, idx=idx, blocked=True)
+        assert np.array_equal(np.asarray(picked), codes[n_valid - 5 : n_valid + 5])
+
+    def test_block_width_counts_each_piece_from_its_own_blocks(self):
+        """Pieces are laid from a block's start each (a shard's valid rows),
+        so a block never spans two pieces; past ``BLOCK_LOOKUP_MAX_WIDTH``
+        there is no width."""
+        a = np.repeat(np.arange(3, dtype=np.int32), 100)  # 300 rows: 3 blocks
+        b = np.repeat(np.arange(40, 43, dtype=np.int32), 100)
+        assert series_block_width([a, b]) == 1  # [a | b] would span 0..40
+        assert series_block_width([a[:1], b[:1]]) == 1  # one code a block
+        assert series_block_width([np.arange(300, dtype=np.int32) // 9]) == 4
+        assert series_block_width([np.arange(300, dtype=np.int32) // 5]) is None
+        assert series_block_width([]) == 1
 
 
 ALLOW_LISTS = {

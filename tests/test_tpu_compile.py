@@ -13,6 +13,8 @@ worker collects the same tests and only the worker that runs this file
 loads the TPU library. Keep all such tests in THIS file.
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -284,7 +286,9 @@ def test_sharded_scatter_compiles_at_4000x12h_on_four_chips(mesh4):
     """cpu-4000x12h-dist4's program: 17,280,000 rows in four equal blocks
     (``ShardLayout``), each chip's chunked scatter into 65,536 segments and
     the collectives over them, under the name the trace shows (one case: a
-    compile of it takes 40 s)."""
+    compile of it takes 40 s). Its 1000 series of 4320 rows a chip span at
+    most two series a 128-row block, so the per-series tables are read by
+    block (``series_block_width`` 1)."""
     from horaedb_tpu.ops.scan_agg import ScanAggSpec, segment_temp_bytes
     from horaedb_tpu.parallel.dist_agg import make_cached_dist_scan_agg
     from horaedb_tpu.parallel.mesh import ShardLayout
@@ -298,6 +302,7 @@ def test_sharded_scatter_compiles_at_4000x12h_on_four_chips(mesh4):
     step = make_cached_dist_scan_agg(
         mesh, ScanAggSpec(n_groups=4096, n_buckets=16, n_agg_fields=n_fields,
                           need_minmax=need_minmax, segment_impl="scatter"),
+        block_width=1,
     )
     compiled = step.lower(
         spec((n,), "int32", "shard"), spec((n,), "int32", "shard"),
@@ -311,6 +316,12 @@ def test_sharded_scatter_compiles_at_4000x12h_on_four_chips(mesh4):
     assert text.count(" all-reduce(") + text.count(" all-reduce-start(") == 2, [
         ln for ln in text.splitlines() if "all-reduce" in ln
     ][:8]
+    # the allow list and the group map through each block's two candidate
+    # series (33,792 blocks a chip); no gather of one element a row
+    gathers = re.findall(r"= (\w+)\[([\d,]+)\]\S* gather\(", text)
+    blocks = shards.shard_len // 128
+    assert ("pred", f"{blocks},2") in gathers and ("s32", f"{blocks},2") in gathers
+    assert not [g for g in gathers if g[1] == str(shards.shard_len)], gathers
     mem = compiled.memory_analysis()  # per chip
     assert mem.temp_size_in_bytes <= segment_temp_bytes(
         "scatter", shards.shard_len, 65_536, n_fields, need_minmax
